@@ -18,6 +18,7 @@ import json
 import os
 import sys
 import time
+import traceback
 
 
 def _roofline_section():
@@ -74,6 +75,7 @@ def main():
         "hierarchy": lambda: throughput.run_hierarchy(quick=args.quick),
         "roofline": _roofline_section,
     }
+    failed = []
     for name, fn in sections.items():
         if args.only and name != args.only:
             continue
@@ -82,10 +84,17 @@ def main():
         try:
             fn()
             print(f"### {name} done in {time.time()-t0:.1f}s ###", flush=True)
-        except Exception as e:  # noqa: BLE001 — one section must not kill the run
+        except Exception as e:  # noqa: BLE001 — run the other sections, then fail
+            traceback.print_exc()
             print(f"### {name} FAILED: {type(e).__name__}: {e} ###", flush=True)
+            failed.append(name)
+    if failed:
+        print(f"### failed sections: {', '.join(failed)} ###", flush=True)
+        return 1
     return 0
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+    compile_cache.enable()
     sys.exit(main())

@@ -332,8 +332,31 @@ class PallasBackend(CacheBackend):
                 f"per set); got {cfg.ways}")
         super().__init__(cfg)
 
+    def probe_fits(self) -> bool:
+        """True when the probe kernels' whole-lane VMEM footprint
+        (``kway_probe.probe_vmem_bytes``) fits the scoped-VMEM limit they
+        compile under — the limit the TPU compiler enforces."""
+        from repro.kernels import kway_probe as _kp
+        return (_kp.probe_vmem_bytes(self.cfg.num_sets)
+                <= _kp.VMEM_LIMIT)
+
+    def _require_probe_fits(self):
+        """Refuse, with the numbers, a cache the probe kernels cannot hold:
+        they map every state lane whole into VMEM, so past the limit the
+        kernel would not compile on a TPU."""
+        if not self.probe_fits():
+            from repro.kernels import kway_probe as _kp
+            raise ValueError(
+                f"pallas probe kernels hold the whole cache in VMEM: "
+                f"num_sets={self.cfg.num_sets} needs "
+                f"{_kp.probe_vmem_bytes(self.cfg.num_sets)} B, over the "
+                f"{_kp.VMEM_LIMIT} B limit; use the jnp backend, or "
+                f"replay with a hierarchy (HierarchyConfig(l1_sets>0)) that "
+                f"keeps the L2 in HBM")
+
     def get(self, state, qkeys, enabled=None):
         from repro.kernels import ops
+        self._require_probe_fits()
         # need_victims=False kernel variant: the read path skips the
         # victim-selection rounds entirely
         _, sets, hit, way = ops.probe_hits(
@@ -350,6 +373,7 @@ class PallasBackend(CacheBackend):
         # the probe launch (exactly where the jnp path scrubs), so the
         # kernel itself needs no expiry awareness.
         from repro.kernels import ops
+        self._require_probe_fits()
         if state.expiry is not None:
             b = jnp.asarray(qkeys).shape[0]
             state = kway.scrub_expired(state,
@@ -364,6 +388,7 @@ class PallasBackend(CacheBackend):
     def put(self, state, qkeys, qvals, admit=None, enabled=None, *,
             slot_value: bool = False):
         from repro.kernels import ops
+        self._require_probe_fits()
         qk, sets, present, way_present, order = ops.probe_orders(
             self.cfg, state, jnp.asarray(qkeys, jnp.uint32)
         )
@@ -374,6 +399,7 @@ class PallasBackend(CacheBackend):
 
     def peek_victims(self, state, qkeys):
         from repro.kernels import ops
+        self._require_probe_fits()
         _, _, hit, _, _, vkey = ops.probe(self.cfg, state,
                                           jnp.asarray(qkeys, jnp.uint32))
         valid = (vkey != EMPTY_KEY) & (~hit)
@@ -416,9 +442,11 @@ class PallasBackend(CacheBackend):
           2. no hierarchy, flat state fits (``resident_fits``) → the flat
              megakernel: ALL lanes pinned in VMEM, bit-identical to
              ``replay_scan``.
-          3. otherwise → the chunked-scan replay (``vmem_budget`` event;
-             the hierarchical mode is named in the event detail as the
-             faster opt-in).
+          3. otherwise → the chunked-scan replay through the probe
+             kernels (``vmem_budget`` event; the hierarchical mode is named
+             in the event detail as the faster opt-in), or a ``ValueError``
+             with the numbers when even the probe kernels cannot hold the
+             cache (``probe_fits``).
         """
         from repro.kernels import ops
         if ttls is not None and tinylfu is not None:
@@ -449,6 +477,7 @@ class PallasBackend(CacheBackend):
             return hier_mod.replay_l1_over_l2(self.cfg, hierarchy, hst,
                                               chunks, enabled, ttls=ttls)
         if not self.resident_fits():
+            self._require_probe_fits()
             from repro.robust import events
             lane_bytes = self.cfg.num_sets * 128 * 4
             events.record(

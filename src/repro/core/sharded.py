@@ -139,18 +139,42 @@ class ShardedCache:
                     "mesh must carry a 'sets' axis of exactly num_shards "
                     f"devices (one shard per device); got axes "
                     f"{dict(mesh.shape)} for num_shards={cfg.num_shards}")
+            kind = dict(zip(mesh.axis_names, mesh.axis_types))["sets"]
+            if kind != jax.sharding.AxisType.Auto:
+                # the replicated router gathers the per-shard outputs back
+                # into request order; under an Explicit axis JAX asks for an
+                # out_sharding on every such gather instead of inserting it
+                raise ValueError(
+                    "the mesh's 'sets' axis must be AxisType.Auto "
+                    "(jax.make_mesh(..., axis_types=(AxisType.Auto,))); "
+                    f"got {kind}")
         self._fns: dict = {}   # (kind, *statics) -> jitted callable
 
     # ------------------------------------------------------------- plumbing
     def init(self, *, ttl: bool = False) -> KWayState:
+        """Empty stacked state (leaves [D, S/D, k]).  With a mesh, each
+        shard's lanes are built on the device that owns them (sharded on
+        the ``sets`` axis), never gathered on one device first."""
         d = self.cfg.num_shards
-        st = self.backend.init(ttl=ttl)
-        stack = lambda l: jnp.tile(l[None], (d,) + (1,) * l.ndim)  # noqa: E731
-        leaves = [stack(l)
-                  for l in (st.keys, st.fprint, st.vals, st.meta_a, st.meta_b)]
-        return KWayState(
-            *leaves, clock=jnp.zeros((d,), jnp.int32),
-            expiry=stack(st.expiry) if st.expiry is not None else None)
+
+        def build():
+            st = self.backend.init(ttl=ttl)
+            stack = lambda l: jnp.tile(  # noqa: E731
+                l[None], (d,) + (1,) * l.ndim)
+            leaves = [stack(l) for l in
+                      (st.keys, st.fprint, st.vals, st.meta_a, st.meta_b)]
+            return KWayState(
+                *leaves, clock=jnp.zeros((d,), jnp.int32),
+                expiry=stack(st.expiry) if st.expiry is not None else None)
+
+        if self.mesh is None:
+            return build()
+        return jax.jit(build, out_shardings=self._sharding("sets"))()
+
+    def _sharding(self, *spec):
+        """``NamedSharding`` on this cache's mesh (``()`` = replicated)."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        return NamedSharding(self.mesh, P(*spec))
 
     def init_sketches(self, tinylfu: TinyLFUConfig) -> TinyLFUState:
         """Per-shard TinyLFU sketches, stacked on the shard axis [D, …]."""
@@ -210,14 +234,13 @@ class ShardedCache:
         shard_ids = jnp.arange(d, dtype=jnp.int32)
         if self.mesh is None:
             return jax.vmap(body)(shard_ids, *args_bucketed, sketch, state)
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         def dev(body_args):
             out = body(*jax.tree_util.tree_map(lambda x: x[0], body_args))
             return jax.tree_util.tree_map(lambda x: x[None], out)
 
-        sharded = shard_map(
+        sharded = jax.shard_map(
             lambda *a: dev(a), mesh=self.mesh,
             in_specs=jax.tree_util.tree_map(
                 lambda _: P("sets"), (shard_ids,) + tuple(args_bucketed)
@@ -393,8 +416,11 @@ class ShardedCache:
         """
         trace = np.asarray(trace, np.uint32)
         chunks, en = router.pad_chunks(trace, batch)
-        chunks = jnp.asarray(chunks)
-        en = jnp.asarray(en)
+        if self.mesh is None:
+            chunks, en = jnp.asarray(chunks), jnp.asarray(en)
+        else:
+            # the router runs replicated: every device sees the whole batch
+            chunks, en = jax.device_put((chunks, en), self._sharding())
         capacity = self.cfg.capacity_for(batch)
         if ttls is not None:
             if two_phase:

@@ -281,7 +281,6 @@ def _replay_group_pallas(num_sets, ways, hash_seed, policy, tinylfu, trace_cn):
     from repro.kernels import kway_probe as _kp
     _TRACE_COUNTS[("pallas", num_sets, ways, 0, trace_cn.shape[1],
                    tinylfu is not None, int(policy))] += 1
-    interpret = jax.default_backend() != "tpu"
     qt = 8
 
     def pad_ways(arr, fill=-1):
@@ -302,8 +301,7 @@ def _replay_group_pallas(num_sets, ways, hash_seed, policy, tinylfu, trace_cn):
             jnp.concatenate([sets, zpad]),
             jnp.concatenate([qkey[None].astype(jnp.int32), zpad]),
             jnp.concatenate([t[None], zpad]),
-            policy=int(policy), ways=ways, qt=qt, interpret=interpret,
-            full_order=False)
+            policy=int(policy), ways=ways, qt=qt, full_order=False)
         return sets[0], hit[0].astype(jnp.bool_), way[0], vway[0]
 
     def init_lane(_):

@@ -8,14 +8,15 @@ TPU adaptation (DESIGN.md §2):
     VMEM-resident:
     a hot cache of S×k ≤ 64Ki entries is ≤ 1 MiB per lane — the software
     analogue of the paper's "short continuous region of memory" argument,
-    transplanted to the HBM→VMEM hierarchy.  BlockSpecs map each full lane
-    into VMEM once; every grid step reuses it (index_map is constant).
-  * Each grid step processes ``qt`` queries.  Per query, the set row is
-    fetched with a dynamic slice (``pl.ds``) — the TPU equivalent of the
-    paper's pointer-free set scan; ways are padded to the 128-lane register
-    width so the k-wide compare/reduce is a single VPU op.
-  * Set indices arrive via scalar prefetch (PrefetchScalarGridSpec) so they
-    are available to index VMEM before the vector body runs.
+    transplanted to the HBM→VMEM hierarchy.  Each lane is copied whole into
+    VMEM once per launch and every grid step indexes it; the pallas backend
+    refuses a cache whose lanes exceed ``VMEM_LIMIT``.
+  * Each grid step processes ``qt`` queries in a ``fori_loop``.  Per query,
+    the set row is fetched with a dynamic slice (``pl.ds``) — the TPU
+    equivalent of the paper's pointer-free set scan; ways are padded to the
+    128-lane register width so the k-wide compare/reduce is a single VPU op.
+  * Set indices, keys and times arrive as SMEM blocks, so each is a scalar
+    load that can address VMEM; per-query results go back through SMEM.
 
 The kernel returns probe *decisions* (hit, way, victim way, victim key);
 applying them is a single XLA scatter done by the caller (``ops.py``) — a
@@ -30,7 +31,8 @@ neither hit nor outrank an empty-way victim.  The probe therefore needs no
 expiry lane and no functional change for TTLs.
 
 Validated in ``interpret=True`` mode against ``ref.py`` (pure jnp oracle)
-over shape/dtype/policy sweeps in tests/test_kway_kernel.py.
+over shape/dtype/policy sweeps in tests/test_kernels.py, and compiled for a
+TPU v5e by tests/test_tpu_compile.py.
 """
 from __future__ import annotations
 
@@ -41,6 +43,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import kernels
 from repro.core.policies import Policy
 
 NEG_INF = -3.0e38  # python literal: jnp module-level constants would be
@@ -75,7 +78,12 @@ def _scores_for_policy(policy: int, keys, meta_a, meta_b, now):
     if policy == Policy.RANDOM:
         h = _hash_u32(keys.astype(jnp.uint32) ^ now.astype(jnp.uint32),
                       0xBADA)
-        return h.astype(jnp.float32)
+        # uint32 -> float32 as hi * 2^16 + lo: both halves and the product
+        # are exact, so the one rounding of the sum equals the direct
+        # conversion's (which Mosaic cannot lower)
+        hi = (h >> 16).astype(jnp.int32).astype(jnp.float32)
+        lo = (h & jnp.uint32(0xFFFF)).astype(jnp.int32).astype(jnp.float32)
+        return hi * 65536.0 + lo
     if policy == Policy.HYPERBOLIC:
         age = (now - meta_b).astype(jnp.float32) + 1.0
         return a / age
@@ -106,39 +114,50 @@ def _fingerprint_i32(key_u32):
     return (_hash_u32(key_u32, 0xF19E) & jnp.uint32(0xFFFF)).astype(jnp.int32)
 
 
+# query inputs ride in SMEM, one [F, qt] block per tile (rows below); the
+# per-query scalar outputs go back the same way, one [4, qt] block per tile.
+# Scalars live in SMEM: Mosaic cannot store a scalar into VMEM, and a rank-1
+# VMEM block must be a multiple of 128 long.
+_Q_SET, _Q_KEY, _Q_TIME = 0, 1, 2          # kway_probe query rows
+_Q_TPUT, _Q_EN = 3, 4                      # + kway_fused_probe's extra rows
+_O_HIT, _O_WAY, _O_VWAY, _O_VKEY = 0, 1, 2, 3
+
+
+def _query_tiles(rows, qt: int):
+    """Stack the int32 [B] query rows into the [B/qt, F, qt] SMEM tiles."""
+    q = jnp.stack([r.astype(jnp.int32) for r in rows])          # [F, B]
+    return q.reshape(len(rows), -1, qt).transpose(1, 0, 2)
+
+
+def _untile(out):
+    """[B/qt, F, qt] per-tile outputs -> F rows of int32 [B]."""
+    nt, f, qt = out.shape
+    return tuple(out.transpose(1, 0, 2).reshape(f, nt * qt))
+
+
 def _probe_kernel(
-    # scalar prefetch
-    sets_ref,            # int32 [B]    set index per query
-    # VMEM inputs
-    keys_ref,            # int32 [S, kp]   stored keys (bit-cast uint32)
-    fprint_ref,          # int32 [S, kp]   16-bit fingerprints
-    meta_a_ref,          # int32 [S, kp]
-    meta_b_ref,          # int32 [S, kp]
-    qkeys_ref,           # int32 [qt]      query keys for this tile
-    times_ref,           # int32 [qt]      logical timestamps
-    # VMEM outputs
-    hit_ref,             # int32 [qt]
-    way_ref,             # int32 [qt]
-    *rest,               # (vway_ref, vkey_ref[, vorder_ref]) when need_victims
+    q_ref,               # SMEM int32 [3, qt]   (set, key, time) per query
+    keys_ref,            # VMEM int32 [S, kp]   stored keys (bit-cast uint32)
+    fprint_ref,          # VMEM int32 [S, kp]   16-bit fingerprints
+    meta_a_ref,          # VMEM int32 [S, kp]
+    meta_b_ref,          # VMEM int32 [S, kp]
+    out_ref,             # SMEM int32 [4, qt]   (hit, way, vway, vkey)
+    *rest,               # (vorder_ref [qt, LANES],) when full_order
     policy: int,
     ways: int,
     qt: int,
     empty_key: int,
     need_victims: bool,
 ):
-    vway_ref = rest[0] if need_victims else None
-    vkey_ref = rest[1] if need_victims else None
-    vorder_ref = rest[2] if need_victims and len(rest) > 2 else None
-    tile = pl.program_id(0)
+    vorder_ref = rest[0] if rest else None
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
     valid_way = lane < ways
 
-    for i in range(qt):  # unrolled: qt dynamic row slices per grid step
-        q = tile * qt + i
-        s = sets_ref[q]
+    def body(i, _):
+        s = q_ref[_Q_SET, i]
         row_keys = keys_ref[pl.ds(s, 1), :]          # [1, kp]
         row_fpr = fprint_ref[pl.ds(s, 1), :]
-        qk = qkeys_ref[i]
+        qk = q_ref[_Q_KEY, i]
 
         occupied = (row_keys != empty_key) & valid_way
         # KW-WFSC Algorithm 5: the 16-bit fingerprint pre-filters the scan;
@@ -149,17 +168,17 @@ def _probe_kernel(
         # first matching way (stable argmax over the 128-lane mask)
         way = jnp.min(jnp.where(eq, lane, LANES))
 
-        hit_ref[i] = hit.astype(jnp.int32)
-        way_ref[i] = jnp.where(hit, way, 0)
+        out_ref[_O_HIT, i] = hit.astype(jnp.int32)
+        out_ref[_O_WAY, i] = jnp.where(hit, way, 0)
 
         if not need_victims:
             # Pure-get probe: skip the victim-selection rounds entirely —
             # the read path never consumes them.
-            continue
+            return 0
 
         row_a = meta_a_ref[pl.ds(s, 1), :]
         row_b = meta_b_ref[pl.ds(s, 1), :]
-        now = times_ref[i]
+        now = q_ref[_Q_TIME, i]
         scores = _scores_for_policy(policy, row_keys, row_a, row_b, now)
         scores = jnp.where(occupied, scores, NEG_INF)  # empty ways first
         scores = jnp.where(valid_way, scores, POS_INF)  # padding ways last
@@ -170,10 +189,33 @@ def _probe_kernel(
             ord_row, vway = _full_order_row(scores, lane, ways)
             vorder_ref[pl.ds(i, 1), :] = ord_row
 
-        vway_ref[i] = vway
-        vkey_ref[i] = jnp.sum(
+        out_ref[_O_VWAY, i] = vway
+        out_ref[_O_VKEY, i] = jnp.sum(
             jnp.where(lane == vway, row_keys, 0).astype(jnp.int32)
         )
+        return 0
+
+    jax.lax.fori_loop(0, qt, body, 0)
+
+
+def probe_vmem_bytes(num_sets: int) -> int:
+    """VMEM the probe kernels hold for a cache of ``num_sets`` sets: four
+    whole state lanes at the 128-lane width plus the fused kernel's
+    hit-updated ``meta_a`` copy, and 1 MiB for the double-buffered query
+    and victim-order tiles and the compiler's own scratch."""
+    return 5 * num_sets * LANES * 4 + (1 << 20)
+
+
+#: scoped-VMEM limit the cache kernels (probe and replay megakernels) compile
+#: under; the pallas backend refuses a cache whose ``probe_vmem_bytes``
+#: exceed it (core/backend.py), and its resident budget stays below it
+VMEM_LIMIT = 32 << 20
+
+
+def _whole_vmem():
+    # whole array, copied into VMEM once (no double buffering) — every grid
+    # step indexes it with dynamic set rows
+    return pl.BlockSpec(memory_space=pltpu.VMEM)
 
 
 @functools.partial(
@@ -192,12 +234,13 @@ def kway_probe(
     policy: int,
     ways: int,
     qt: int = 8,
-    interpret: bool = True,
+    interpret: bool | None = None,   # None: repro.kernels.interpret()
     full_order: bool = False,
     need_victims: bool = True,
 ):
-    """Run the probe kernel.  B must be a multiple of qt; kp (padded ways)
-    must equal LANES (one VREG row per set).
+    """Run the probe kernel.  B must be a multiple of qt, and qt a multiple
+    of 8 (the victim-order tile is [qt, LANES]); kp (padded ways) must equal
+    LANES (one VREG row per set).
 
     With ``full_order=True`` a fifth output is returned: vorder int32
     [B, LANES], the per-query victim order worst-first (entries past ``ways``
@@ -210,10 +253,12 @@ def kway_probe(
     s, kp = keys.shape
     b = sets.shape[0]
     assert kp == LANES, f"pad ways to {LANES} lanes (got {kp})"
-    assert b % qt == 0
+    assert b % qt == 0 and qt % 8 == 0
+    if interpret is None:
+        interpret = kernels.interpret()
     assert need_victims or not full_order, \
         "full_order requires need_victims=True"
-    grid = (b // qt,)
+    nt = b // qt
 
     kernel = functools.partial(
         _probe_kernel,
@@ -223,25 +268,26 @@ def kway_probe(
         empty_key=-1,  # EMPTY_KEY 0xFFFFFFFF viewed as int32
         need_victims=need_victims,
     )
-    n_scalar_outs = 4 if need_victims else 2
-    out_shape = [jax.ShapeDtypeStruct((b,), jnp.int32)] * n_scalar_outs
-    full = lambda: pl.BlockSpec((s, kp), lambda i, *_: (0, 0))  # noqa: E731
-    qtile = lambda: pl.BlockSpec((qt,), lambda i, *_: (i,))  # noqa: E731
-    out_specs = [qtile()] * n_scalar_outs
+    tile = lambda f: pl.BlockSpec((None, f, qt), lambda i: (i, 0, 0),  # noqa: E731
+                                  memory_space=pltpu.SMEM)
+    out_shape = [jax.ShapeDtypeStruct((nt, 4, qt), jnp.int32)]
+    out_specs = [tile(4)]
     if full_order:
-        out_shape = out_shape + [jax.ShapeDtypeStruct((b, LANES), jnp.int32)]
-        out_specs = out_specs + [pl.BlockSpec((qt, LANES), lambda i, *_: (i, 0))]
-    return pl.pallas_call(
+        out_shape.append(jax.ShapeDtypeStruct((b, LANES), jnp.int32))
+        out_specs.append(pl.BlockSpec((qt, LANES), lambda i: (i, 0)))
+    outs = pl.pallas_call(
         kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[full(), full(), full(), full(), qtile(), qtile()],
-            out_specs=out_specs,
-        ),
+        grid=(nt,),
+        in_specs=[tile(3)] + [_whole_vmem()] * 4,
+        out_specs=out_specs,
         out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
-    )(sets, keys, fprint, meta_a, meta_b, qkeys, times)
+    )(_query_tiles((sets, qkeys, times), qt), keys, fprint, meta_a, meta_b)
+    scal = _untile(outs[0])
+    res = scal if need_victims else scal[:2]
+    return res + tuple(outs[1:])
 
 
 # ---------------------------------------------------------------------------
@@ -249,23 +295,14 @@ def kway_probe(
 # ---------------------------------------------------------------------------
 
 def _fused_kernel(
-    # scalar prefetch
-    sets_ref,            # int32 [B]    set index per query
-    # VMEM inputs
-    keys_ref,            # int32 [S, kp]
-    fprint_ref,          # int32 [S, kp]
-    meta_a_ref,          # int32 [S, kp]
-    meta_b_ref,          # int32 [S, kp]
-    qkeys_ref,           # int32 [qt]
-    tg_ref,              # int32 [qt]   get-phase timestamps (t + i)
-    tp_ref,              # int32 [qt]   put-phase timestamps (t + B + i)
-    en_ref,              # int32 [qt]   1 = live lane (enabled & not padding)
-    # VMEM outputs
-    hit_ref,             # int32 [qt]
-    way_ref,             # int32 [qt]
-    vorder_ref,          # int32 [qt, LANES]
-    # VMEM scratch
-    scratch_a,           # int32 [S, kp]  hit-updated meta_a
+    q_ref,               # SMEM int32 [5, qt]  (set, key, t_get, t_put, en)
+    keys_ref,            # VMEM int32 [S, kp]
+    fprint_ref,          # VMEM int32 [S, kp]
+    meta_a_ref,          # VMEM int32 [S, kp]
+    meta_b_ref,          # VMEM int32 [S, kp]
+    out_ref,             # SMEM int32 [4, qt]  (hit, way, -, -)
+    vorder_ref,          # VMEM int32 [qt, LANES]
+    scratch_a,           # VMEM int32 [S, kp]  hit-updated meta_a
     *,
     policy: int,
     ways: int,
@@ -292,43 +329,52 @@ def _fused_kernel(
     def _init_scratch():
         scratch_a[...] = meta_a_ref[...]
 
-    for i in range(qt):  # unrolled: qt dynamic row slices per grid step
-        q = tile * qt + i
-        s = sets_ref[q]
+    def probe(i):
+        s = q_ref[_Q_SET, i]
         row_keys = keys_ref[pl.ds(s, 1), :]          # [1, kp]
         row_fpr = fprint_ref[pl.ds(s, 1), :]
-        qk = qkeys_ref[i]
-
+        qk = q_ref[_Q_KEY, i]
         occupied = (row_keys != empty_key) & valid_way
         # fingerprint pre-filter + full-key confirm (see _probe_kernel)
         eq = (row_fpr == _fingerprint_i32(qk)) & (row_keys == qk) & occupied
         hit = jnp.any(eq)
         way = jnp.min(jnp.where(eq, lane, LANES))    # LANES when no hit
+        return s, row_keys, occupied, hit, way
 
-        if policy not in (Policy.FIFO, Policy.RANDOM):  # on_hit is identity
-            @pl.when(phase == 0)
-            def _hit_update():
-                do = jnp.logical_and(hit, en_ref[i] != 0)
+    if policy not in (Policy.FIFO, Policy.RANDOM):  # on_hit is identity
+        @pl.when(phase == 0)
+        def _hit_phase():
+            def body(i, _):
+                s, _, _, hit, way = probe(i)
+                do = jnp.logical_and(hit, q_ref[_Q_EN, i] != 0)
                 row_a = scratch_a[pl.ds(s, 1), :]
                 upd = lane == way            # all-false when way == LANES
                 if policy == Policy.LRU:
-                    new_a = jnp.where(upd, tg_ref[i], row_a)
+                    new_a = jnp.where(upd, q_ref[_Q_TIME, i], row_a)
                 else:                        # LFU / HYPERBOLIC: count += 1
                     new_a = jnp.where(upd, row_a + 1, row_a)
                 scratch_a[pl.ds(s, 1), :] = jnp.where(do, new_a, row_a)
+                return 0
 
-        @pl.when(phase == 1)
-        def _score():
+            jax.lax.fori_loop(0, qt, body, 0)
+
+    @pl.when(phase == 1)
+    def _score_phase():
+        def body(i, _):
+            s, row_keys, occupied, hit, way = probe(i)
             row_a = scratch_a[pl.ds(s, 1), :]
             row_b = meta_b_ref[pl.ds(s, 1), :]
             scores = _scores_for_policy(policy, row_keys, row_a, row_b,
-                                        tp_ref[i])
+                                        q_ref[_Q_TPUT, i])
             scores = jnp.where(occupied, scores, NEG_INF)
             scores = jnp.where(valid_way, scores, POS_INF)
             ord_row, _ = _full_order_row(scores, lane, ways)
             vorder_ref[pl.ds(i, 1), :] = ord_row
-            hit_ref[i] = hit.astype(jnp.int32)
-            way_ref[i] = jnp.where(hit, way, 0)
+            out_ref[_O_HIT, i] = hit.astype(jnp.int32)
+            out_ref[_O_WAY, i] = jnp.where(hit, way, 0)
+            return 0
+
+        jax.lax.fori_loop(0, qt, body, 0)
 
 
 @functools.partial(
@@ -348,7 +394,7 @@ def kway_fused_probe(
     policy: int,
     ways: int,
     qt: int = 8,
-    interpret: bool = True,
+    interpret: bool | None = None,   # None: repro.kernels.interpret()
 ):
     """Single-launch fused probe for ``access``: hit decisions plus the full
     victim order scored on the hit-updated metadata (see ``_fused_kernel``).
@@ -361,8 +407,10 @@ def kway_fused_probe(
     s, kp = keys.shape
     b = sets.shape[0]
     assert kp == LANES, f"pad ways to {LANES} lanes (got {kp})"
-    assert b % qt == 0
-    grid = (2, b // qt)
+    assert b % qt == 0 and qt % 8 == 0
+    if interpret is None:
+        interpret = kernels.interpret()
+    nt = b // qt
 
     kernel = functools.partial(
         _fused_kernel,
@@ -371,23 +419,27 @@ def kway_fused_probe(
         qt=qt,
         empty_key=-1,
     )
-    full = lambda: pl.BlockSpec((s, kp), lambda p, i, *_: (0, 0))  # noqa: E731
-    qtile = lambda: pl.BlockSpec((qt,), lambda p, i, *_: (i,))  # noqa: E731
-    return pl.pallas_call(
+    # outputs are written in phase 1 only: phase 0 parks every output block
+    # on tile 0 (index p * i), so no unwritten block is ever flushed
+    out_tile = pl.BlockSpec((None, 4, qt), lambda p, i: (p * i, 0, 0),
+                            memory_space=pltpu.SMEM)
+    outs = pl.pallas_call(
         kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[full(), full(), full(), full(),
-                      qtile(), qtile(), qtile(), qtile()],
-            out_specs=[qtile(), qtile(),
-                       pl.BlockSpec((qt, LANES), lambda p, i, *_: (i, 0))],
-            scratch_shapes=[pltpu.VMEM((s, kp), jnp.int32)],
-        ),
+        grid=(2, nt),
+        in_specs=[pl.BlockSpec((None, 5, qt), lambda p, i: (i, 0, 0),
+                               memory_space=pltpu.SMEM)]
+        + [_whole_vmem()] * 4,
+        out_specs=[out_tile,
+                   pl.BlockSpec((qt, LANES), lambda p, i: (p * i, 0))],
+        scratch_shapes=[pltpu.VMEM((s, kp), jnp.int32)],
         out_shape=[
-            jax.ShapeDtypeStruct((b,), jnp.int32),
-            jax.ShapeDtypeStruct((b,), jnp.int32),
+            jax.ShapeDtypeStruct((nt, 4, qt), jnp.int32),
             jax.ShapeDtypeStruct((b, LANES), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
-    )(sets, keys, fprint, meta_a, meta_b, qkeys, times_get, times_put, en)
+    )(_query_tiles((sets, qkeys, times_get, times_put, en), qt),
+      keys, fprint, meta_a, meta_b)
+    hit, way = _untile(outs[0])[:2]
+    return hit, way, outs[1]

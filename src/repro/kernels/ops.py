@@ -1,8 +1,9 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 These adapt the framework's pytree state to the kernels' padded VMEM layouts
-and pick interpret mode automatically (interpret=True off-TPU, compiled on
-TPU).  The rest of the framework calls only these entry points.
+and leave interpret mode to the kernels' default
+(``repro.kernels.interpret()``: compiled on a TPU, the Pallas interpreter
+elsewhere).  The rest of the framework calls only these entry points.
 """
 from __future__ import annotations
 
@@ -13,13 +14,18 @@ import jax.numpy as jnp
 
 from repro.core import hashing
 from repro.core.kway import KWayConfig, KWayState
+from repro.kernels import interpret
 from repro.kernels import kway_probe as _kp
 from repro.kernels import paged_attention as _pa
 from repro.kernels import ref as _ref
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def query_tile(b: int) -> int:
+    """Queries per probe-kernel grid step for a batch of ``b``: one full
+    128-lane tile for large batches, the batch rounded up to the 8-row
+    sublane tile for small ones (so a decode tick of 8 lanes is not padded
+    to 128 dummy probes)."""
+    return min(_kp.LANES, -(-b // 8) * 8)
 
 
 def _pad_ways(arr: jnp.ndarray, lanes: int = _kp.LANES,
@@ -33,7 +39,7 @@ def _pad_ways(arr: jnp.ndarray, lanes: int = _kp.LANES,
 
 def _probe_impl(cfg, state, qkeys, use_kernel: bool, full_order: bool,
                 need_victims: bool = True):
-    """Shared probe core: sanitize + route + pad to the qt=8 query tile.
+    """Shared probe core: sanitize + route + pad to the query tile.
 
     Padding with dummy probes keeps the kernel on every batch size (probing
     is read-only, so padding lanes are harmless); outputs are sliced back
@@ -51,7 +57,7 @@ def _probe_impl(cfg, state, qkeys, use_kernel: bool, full_order: bool,
     mb = _pad_ways(state.meta_b)
     qk_i = qkeys.astype(jnp.int32)
 
-    qt = 8
+    qt = query_tile(b)
     if use_kernel:
         pad = (-b) % qt
         zpad = jnp.zeros((pad,), jnp.int32)
@@ -61,7 +67,7 @@ def _probe_impl(cfg, state, qkeys, use_kernel: bool, full_order: bool,
             jnp.concatenate([qk_i, zpad]),
             jnp.concatenate([times, zpad]),
             policy=int(cfg.policy), ways=cfg.ways, qt=qt,
-            interpret=not _on_tpu(), full_order=full_order,
+            full_order=full_order,
             need_victims=need_victims,
         )
     else:
@@ -149,7 +155,7 @@ def fused_probe(
     mb = _pad_ways(state.meta_b)
     qk_i = qkeys.astype(jnp.int32)
 
-    qt = 8
+    qt = query_tile(b)
     if use_kernel:
         pad = (-b) % qt
         zpad = jnp.zeros((pad,), jnp.int32)
@@ -162,7 +168,6 @@ def fused_probe(
             jnp.concatenate([times_put, zpad]),
             jnp.concatenate([en, zpad]),
             policy=int(cfg.policy), ways=cfg.ways, qt=qt,
-            interpret=not _on_tpu(),
         )
     else:
         outs = _ref.kway_fused_probe_ref(
@@ -216,7 +221,6 @@ def replay_resident(cfg: KWayConfig, state: KWayState, chunks, enabled,
         policy=int(cfg.policy), ways=cfg.ways, num_sets=cfg.num_sets,
         seed=cfg.seed, tinylfu=tinylfu, sketch=sketch,
         expiry=state.expiry, ttls=ttls,
-        interpret=not _on_tpu(),
     )
     keys, fpr, vals, ma, mb, clock = lanes[:6]
     state_out = KWayState(keys=keys, fprint=fpr, vals=vals, meta_a=ma,
@@ -255,7 +259,6 @@ def replay_hierarchical(cfg: KWayConfig, hier, state, chunks, enabled,
         l1_sets=hier.l1_sets, l2_sets=cfg.num_sets, seed=cfg.seed,
         promote=hier.promote, demote=hier.demote,
         l1_exp=l1.expiry, l2_exp=l2.expiry, ttls=ttls,
-        interpret=not _on_tpu(),
     )
 
     def unpack(lanes):
@@ -288,11 +291,11 @@ def attend_paged(
     computation there.  Pass an explicit bool to force either path.
     """
     if use_kernel is None:
-        use_kernel = _on_tpu()
+        use_kernel = not interpret()
     if use_kernel:
         return _pa.paged_attention(
             q, k_pages, v_pages, page_table, seq_lens,
-            scale=scale, softcap=softcap, interpret=not _on_tpu(),
+            scale=scale, softcap=softcap,
         )
     return _ref.paged_attention_ref(
         q, k_pages, v_pages, page_table, seq_lens, scale=scale, softcap=softcap

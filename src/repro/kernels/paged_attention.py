@@ -31,6 +31,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import kernels
+
 NEG_INF = -3.0e38
 
 
@@ -109,9 +111,11 @@ def paged_attention(
     *,
     scale: float | None = None,
     softcap: float = 0.0,
-    interpret: bool = True,
+    interpret: bool | None = None,   # None: repro.kernels.interpret()
 ) -> jnp.ndarray:
     """One decode step of paged GQA attention.  Returns [B, H, D]."""
+    if interpret is None:
+        interpret = kernels.interpret()
     b, h, d = q.shape
     kvh, _, page, _ = k_pages.shape
     pps = page_table.shape[1]

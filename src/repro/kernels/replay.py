@@ -11,8 +11,9 @@ This kernel retires that split for the replay workload (DESIGN.md §10):
     for the entire trace — they are outputs with a constant index map,
     initialised from the input state on the first grid step and mutated
     in place until the final flush;
-  * requests are streamed from HBM via a chunk-indexed BlockSpec
-    (one ``[1, B]`` row of keys / set ids / enabled flags per grid step);
+  * requests are streamed from HBM via a chunk-indexed BlockSpec into
+    SMEM (one ``[F, B]`` block of keys / set ids / enabled flags per grid
+    step), so every per-lane read is a scalar load;
   * the per-chunk hit/insert transitions of ``core/kway.apply_access`` are
     applied **in-kernel** (no read-kernel/write-scatter split), bit-identical
     to the chunked-scan replay: hits update metadata sequentially in batch
@@ -24,7 +25,8 @@ This kernel retires that split for the replay workload (DESIGN.md §10):
     ``admission.record``/``admit`` semantics (pre-chunk doorkeeper reads,
     max-merged counter increments, post-chunk aging);
   * the only per-step outputs are two scalar counters (hits, evictions) —
-    one int32 each per chunk.
+    one int32 each per chunk, written to SMEM (Mosaic stores no scalar to
+    VMEM).
 
 Equivalence contract: for any trace, ``replay_resident`` produces the same
 per-chunk hit counts, eviction counts and final state as scanning the same
@@ -46,11 +48,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import kernels
 from repro.core.kway import NO_EXPIRY
 from repro.core.policies import Policy
-from repro.kernels.kway_probe import (LANES, NEG_INF, POS_INF,
+from repro.kernels.kway_probe import (LANES, NEG_INF, POS_INF, VMEM_LIMIT,
                                       _fingerprint_i32, _hash_u32,
-                                      _scores_for_policy)
+                                      _scores_for_policy, _whole_vmem)
 
 # Trace/launch tally (same pattern as eval/runner.py): the jitted wrapper
 # bumps ("trace", ...) once per XLA compilation and ("launch", ...) once per
@@ -69,10 +72,34 @@ def reset_trace_counts() -> None:
     _TRACE_COUNTS.clear()
 
 
+# rows of the per-chunk request block [F, B] (SMEM): the flat kernel reads
+# key / set / enabled (+ ttl), the hierarchy kernel key / L1 set / L2 set /
+# enabled / ttl
+_R_KEY, _R_SET, _R_EN, _R_TTL = 0, 1, 2, 3
+_H_KEY, _H_S1, _H_S2, _H_EN, _H_TTL = 0, 1, 2, 3, 4
+
+
 def _lane_read(row_ref, blane, i):
-    """Scalar read of column ``i`` from a [1, Bp] row ref via a masked
+    """Scalar read of column ``i`` from a [1, Bp] VMEM row ref via a masked
     reduce — no dynamic VMEM addressing, just one VPU select+sum."""
     return jnp.sum(jnp.where(blane == i, row_ref[...], 0))
+
+
+def _chunk_block(rows, steps: int, batch: int):
+    """Stack int32 [T, B] request streams into the [T, F, B] array whose
+    [F, B] per-chunk blocks the megakernels read from SMEM."""
+    return jnp.stack([r.astype(jnp.int32).reshape(steps, batch)
+                      for r in rows], axis=1)
+
+
+def _counters_spec():
+    # per-chunk (hits, evictions), one [1, 2] SMEM block per grid step
+    return pl.BlockSpec((None, 1, 2), lambda t, *_: (t, 0, 0),
+                        memory_space=pltpu.SMEM)
+
+
+#: rows per hierarchy L2 DMA: one int32 (8, 128) tile of sublanes
+L2_BLOCK = 8
 
 
 def _row_select(row, lane, idx):
@@ -83,10 +110,9 @@ def _row_select(row, lane, idx):
 def _replay_kernel(
     # scalar prefetch
     scal_ref,            # int32 [2]  (initial clock, initial sketch additions)
+    # SMEM input
+    q_ref,               # int32 [F, B]  chunk t: key / set / en (/ ttl) rows
     # VMEM inputs
-    qk_ref,              # int32 [1, Bp]  sanitized query keys (chunk t)
-    sets_ref,            # int32 [1, Bp]  set index per query
-    en_ref,              # int32 [1, Bp]  1 = live lane
     keys0_ref,           # int32 [S, kp]  initial state lanes
     fpr0_ref,
     vals0_ref,
@@ -100,20 +126,20 @@ def _replay_kernel(
     ttl: bool,           # expiry lane + per-request TTL stream present
     empty_key: int,
 ):
-    # remaining refs: [tt, exp0] + [pk0, dr0] + outputs + scratch — unpack
+    # remaining refs: [exp0] + [pk0, dr0] + outputs + scratch — unpack
     # by shape of the static configuration.  With ``ttl`` False nothing
     # TTL-related is in the argument list, so the compiled graph is the
     # pre-expiry kernel verbatim.
     k = 0
     if ttl:
-        tt_ref, exp0_ref = rest[k], rest[k + 1]
-        k += 2
+        exp0_ref = rest[k]
+        k += 1
     if tl is not None:
         pk0_ref, dr0_ref = rest[k], rest[k + 1]
         k += 2
-    hits_ref, evs_ref = rest[k], rest[k + 1]
-    keys_ref, fpr_ref, vals_ref, ma_ref, mb_ref = rest[k + 2:k + 7]
-    k += 7
+    cnt_ref = rest[k]
+    keys_ref, fpr_ref, vals_ref, ma_ref, mb_ref = rest[k + 1:k + 6]
+    k += 6
     if ttl:
         exp_ref = rest[k]
         k += 1
@@ -132,7 +158,7 @@ def _replay_kernel(
     base = scal_ref[0] + jnp.int32(2 * batch) * t   # chunk t's clock origin
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
     valid_way = lane < ways
-    bp = qk_ref.shape[1]
+    bp = ins_s.shape[1]
     blane = jax.lax.broadcasted_iota(jnp.int32, (1, bp), 1)
 
     # ---- first grid step: pull the initial state into the resident buffers
@@ -226,9 +252,9 @@ def _replay_kernel(
         pk_new[...] = pk_ref[...]
 
         def rec_body(i, adds_inc):
-            en_i = _lane_read(en_ref, blane, i)
+            en_i = q_ref[_R_EN, i]
             live = en_i != 0
-            key_u = _lane_read(qk_ref, blane, i).astype(jnp.uint32)
+            key_u = q_ref[_R_KEY, i].astype(jnp.uint32)
             dword, dbit, rows = sketch_pos(key_u)
             in_door = door_bit(dword, dbit) != 0
             # admission.record scatter-SETs ``pre | dmask`` per lane, so for
@@ -248,9 +274,11 @@ def _replay_kernel(
                 upd = (wlane == word) & do_inc
                 # the scatter-max of admission.record compares whole words
                 # as uint32 — merge in that domain (a set nibble 7 makes the
-                # int32 view negative)
-                merged = jnp.maximum(row_acc.astype(jnp.uint32),
-                                     neww).astype(jnp.int32)
+                # int32 view negative).  Mosaic has no unsigned max: flip
+                # the sign bit, take the signed max, flip it back.
+                flip = jnp.int32(-0x80000000)
+                merged = jnp.maximum(row_acc ^ flip,
+                                     neww.astype(jnp.int32) ^ flip) ^ flip
                 pk_new[pl.ds(r, 1), :] = jnp.where(upd, merged, row_acc)
             return adds_inc + en_i
 
@@ -272,8 +300,8 @@ def _replay_kernel(
         # PRE-hit state at time base+i and gate admission on the
         # post-record sketch (the phase order of the chunked scan).
         def adm_body(i, _):
-            qk = _lane_read(qk_ref, blane, i)
-            s = _lane_read(sets_ref, blane, i)
+            qk = q_ref[_R_KEY, i]
+            s = q_ref[_R_SET, i]
             hit, _, row_keys, occupied = probe(s, qk)
             row_a = ma_ref[pl.ds(s, 1), :]
             row_b = mb_ref[pl.ds(s, 1), :]
@@ -296,9 +324,9 @@ def _replay_kernel(
     # scatter-max (LRU — batch times are increasing).
     # ------------------------------------------------------------------
     def hit_body(i, hits_acc):
-        qk = _lane_read(qk_ref, blane, i)
-        s = _lane_read(sets_ref, blane, i)
-        en_i = _lane_read(en_ref, blane, i)
+        qk = q_ref[_R_KEY, i]
+        s = q_ref[_R_SET, i]
+        en_i = q_ref[_R_EN, i]
         hit, way, _, _ = probe(s, qk)
         if policy not in (Policy.FIFO, Policy.RANDOM):  # on_hit is identity
             do = hit & (en_i != 0)
@@ -333,9 +361,9 @@ def _replay_kernel(
 
     def ins_body(i, carry):
         n, evs = carry
-        qk = _lane_read(qk_ref, blane, i)
-        s = _lane_read(sets_ref, blane, i)
-        en_i = _lane_read(en_ref, blane, i)
+        qk = q_ref[_R_KEY, i]
+        s = q_ref[_R_SET, i]
+        en_i = q_ref[_R_EN, i]
         adm_i = (_lane_read(adm_row, blane, i) if tl is not None
                  else jnp.int32(1))
         hit, _, row_keys, occupied = probe(s, qk)
@@ -370,7 +398,7 @@ def _replay_kernel(
         ins_t[...] = jnp.where(sel, t_put, ins_t[...])
         if ttl:
             # insert deadline = chunk base + 2B + ttl (kway.insert_deadlines)
-            tt_i = _lane_read(tt_ref, blane, i)
+            tt_i = q_ref[_R_TTL, i]
             dl = jnp.where(tt_i > 0, base + jnp.int32(2 * batch) + tt_i,
                            jnp.int32(NO_EXPIRY))
             ins_e[...] = jnp.where(sel, dl, ins_e[...])
@@ -410,8 +438,8 @@ def _replay_kernel(
 
     jax.lax.fori_loop(0, batch, app_body, 0)
 
-    hits_ref[0] = hits
-    evs_ref[0] = evs
+    cnt_ref[0, 0] = hits
+    cnt_ref[0, 1] = evs
 
 
 @functools.partial(
@@ -436,18 +464,15 @@ def _replay_resident_jit(
     _TRACE_COUNTS[("trace", int(policy), num_sets, ways, steps, batch,
                    tl is not None)] += 1
 
-    # ---- streams: sanitize + route once, pad columns to the 128-lane width
+    # ---- streams: sanitize + route once, one [F, B] SMEM block per chunk
     from repro.core import hashing
     qk = hashing.sanitize_keys(chunks.reshape(-1))
-    sets = hashing.set_index(qk, num_sets, seed).reshape(steps, batch)
-    qk = qk.astype(jnp.int32).reshape(steps, batch)
-    en = enabled.astype(jnp.int32)
-    bp = -(-batch // LANES) * LANES
-    if bp != batch:
-        pad = jnp.zeros((steps, bp - batch), jnp.int32)
-        qk = jnp.concatenate([qk, pad], axis=1)
-        sets = jnp.concatenate([sets, pad], axis=1)
-        en = jnp.concatenate([en, pad], axis=1)
+    sets = hashing.set_index(qk, num_sets, seed)
+    rows = [qk, sets, enabled]
+    if ttl:
+        rows.append(tt)
+    q = _chunk_block(rows, steps, batch)
+    bp = -(-batch // LANES) * LANES        # insert-buffer rows, lane-padded
 
     # ---- state lanes: pad ways to the LANES register width, bit-cast int32
     def pad_ways(arr, fill):
@@ -471,44 +496,35 @@ def _replay_resident_jit(
         _replay_kernel, policy=int(policy), ways=ways, batch=batch,
         tl=tl, ttl=ttl, empty_key=-1)
 
-    chunk_row = lambda: pl.BlockSpec((1, bp), lambda t, *_: (t, 0))  # noqa: E731
-    full = lambda a: pl.BlockSpec(a.shape, lambda t, *_: (0,) * a.ndim)  # noqa: E731
-    cnt = lambda: pl.BlockSpec((1,), lambda t, *_: (t,))  # noqa: E731
-
-    in_arrays = [qk, sets, en, keys_i, fpr_i, vals_i, ma_i, mb_i]
-    in_specs = [chunk_row(), chunk_row(), chunk_row(),
-                full(keys_i), full(fpr_i), full(vals_i), full(ma_i),
-                full(mb_i)]
-    out_shape = [jax.ShapeDtypeStruct((steps,), jnp.int32),
-                 jax.ShapeDtypeStruct((steps,), jnp.int32)] + [
-        jax.ShapeDtypeStruct((s, LANES), jnp.int32) for _ in range(5)]
-    out_specs = [cnt(), cnt()] + [full(keys_i) for _ in range(5)]
+    lanes_shape = jax.ShapeDtypeStruct((s, LANES), jnp.int32)
+    in_arrays = [q, keys_i, fpr_i, vals_i, ma_i, mb_i]
+    in_specs = [pl.BlockSpec((None, len(rows), batch),
+                             lambda t, *_: (t, 0, 0),
+                             memory_space=pltpu.SMEM)] + [_whole_vmem()] * 5
+    out_shape = [jax.ShapeDtypeStruct((steps, 1, 2), jnp.int32)] + [
+        lanes_shape] * 5
+    out_specs = [_counters_spec()] + [_whole_vmem()] * 5
     scratch = [pltpu.VMEM((1, bp), jnp.int32) for _ in range(4)]
 
     if ttl:
-        # ttl stream padded like the other chunk rows; expiry lane padded
-        # to the register width with NO_EXPIRY (padding ways never expire)
-        tt_i = tt.astype(jnp.int32)
-        if bp != batch:
-            tt_i = jnp.concatenate(
-                [tt_i, jnp.zeros((steps, bp - batch), jnp.int32)], axis=1)
-        exp_i = pad_ways(exp, NO_EXPIRY)
-        in_arrays += [tt_i, exp_i]
-        in_specs += [chunk_row(), full(exp_i)]
-        out_shape += [jax.ShapeDtypeStruct((s, LANES), jnp.int32)]
-        out_specs += [full(exp_i)]
-        scratch += [pltpu.VMEM((1, bp), jnp.int32)]       # ins_e
+        # expiry lane padded to the register width with NO_EXPIRY (padding
+        # ways never expire)
+        in_arrays.append(pad_ways(exp, NO_EXPIRY))
+        in_specs.append(_whole_vmem())
+        out_shape.append(lanes_shape)
+        out_specs.append(_whole_vmem())
+        scratch.append(pltpu.VMEM((1, bp), jnp.int32))    # ins_e
 
     if tl is not None:
         pk_i = pk.astype(jnp.int32)
         dr_i = dr.astype(jnp.int32)
         in_arrays += [pk_i, dr_i]
-        in_specs += [full(pk_i), full(dr_i)]
+        in_specs += [_whole_vmem(), _whole_vmem()]
         out_shape += [jax.ShapeDtypeStruct(pk_i.shape, jnp.int32),
                       jax.ShapeDtypeStruct(dr_i.shape, jnp.int32),
                       jax.ShapeDtypeStruct((1,), jnp.int32)]
-        out_specs += [full(pk_i), full(dr_i),
-                      pl.BlockSpec((1,), lambda t, *_: (0,))]
+        out_specs += [_whole_vmem(), _whole_vmem(),
+                      pl.BlockSpec(memory_space=pltpu.SMEM)]
         scratch += [pltpu.VMEM((1, bp), jnp.int32),       # adm_row
                     pltpu.VMEM(pk_i.shape, jnp.int32),    # pk_new
                     pltpu.VMEM(dr_i.shape, jnp.int32)]    # dr_delta
@@ -523,17 +539,19 @@ def _replay_resident_jit(
             scratch_shapes=scratch,
         ),
         out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
     )(scal, *in_arrays)
 
-    hits, evs = outs[0], outs[1]
-    keys_f, fpr_f, vals_f, ma_f, mb_f = outs[2:7]
+    hits, evs = outs[0][:, 0, 0], outs[0][:, 0, 1]
+    keys_f, fpr_f, vals_f, ma_f, mb_f = outs[1:6]
     unpad = lambda a: a[:, :ways]  # noqa: E731
     state_out = (unpad(keys_f).astype(jnp.uint32),
                  unpad(fpr_f).astype(jnp.uint32),
                  unpad(vals_f), unpad(ma_f), unpad(mb_f),
                  clock + jnp.int32(2 * batch * steps))
-    idx = 7
+    idx = 6
     if ttl:
         state_out = state_out + (unpad(outs[idx]),)
         idx += 1
@@ -558,7 +576,7 @@ def replay_resident(
     sketch=None,                  # TinyLFUState | None (fresh when None)
     expiry=None,                  # int32 [S, ways] | None
     ttls=None,                    # int32 [T, B] | None
-    interpret: bool = True,
+    interpret: bool | None = None,   # None: repro.kernels.interpret()
 ):
     """Run the replay megakernel: ONE launch for the whole chunked trace.
 
@@ -572,6 +590,8 @@ def replay_resident(
 
     steps, batch = chunks.shape
     ttl = ttls is not None
+    if interpret is None:
+        interpret = kernels.interpret()
     if ttl:
         if tinylfu is not None:
             raise ValueError(
@@ -652,21 +672,17 @@ def replay_resident(
 def _hier_replay_kernel(
     # scalar prefetch
     scal_ref,            # int32 [1]  initial clock
-    # VMEM inputs
-    qk_ref,              # int32 [1, Bp]  sanitized query keys (chunk t)
-    s1_ref,              # int32 [1, Bp]  L1 set index per query
-    s2_ref,              # int32 [1, Bp]  L2 set index per query
-    en_ref,              # int32 [1, Bp]  1 = live lane
-    tt_ref,              # int32 [1, Bp]  per-request TTL (zeros w/o ttl)
-    l1in_ref,            # int32 [S1, ROW_W]  packed L1 rows (initial)
+    # inputs
+    q_ref,               # SMEM  [5, B]  chunk t: key / L1 set / L2 set /
+    #                                    enabled / ttl (zeros w/o ttl) rows
+    l1in_ref,            # VMEM  [S1, ROW_W]  packed L1 rows (initial)
     l2in_ref,            # ANY   [S2, ROW_W]  packed L2 rows (initial)
     # outputs
-    hits_ref,            # int32 [1]  per-chunk hits
-    evs_ref,             # int32 [1]  per-chunk evictions
-    l1_ref,              # int32 [S1, ROW_W]  packed L1 rows (resident)
+    cnt_ref,             # SMEM  [1, 2]  per-chunk (hits, evictions)
+    l1_ref,              # VMEM  [S1, ROW_W]  packed L1 rows (resident)
     l2out_ref,           # ANY   [S2, ROW_W]  packed L2 rows (resident)
     # scratch
-    rowA,                # VMEM [1, ROW_W]  DMA staging row
+    rowA,                # VMEM [L2_BLOCK, ROW_W]  DMA staging block
     sem,                 # DMA semaphore
     *,
     policy: int,
@@ -689,8 +705,6 @@ def _hier_replay_kernel(
 
     t = pl.program_id(0)
     base = scal_ref[0] + jnp.int32(2 * batch) * t
-    bp = qk_ref.shape[1]
-    blane = jax.lax.broadcasted_iota(jnp.int32, (1, bp), 1)
     # chunk-exit clock: the lazy-scrub horizon and deadline base (§15)
     hz = base + jnp.int32(2 * batch) if ttl else None
 
@@ -705,10 +719,14 @@ def _hier_replay_kernel(
 
     # ---- L2 row glue.  The interpret path indexes the resident ref
     # directly (the emulator charges ~30 µs per DMA op, which would
-    # dominate the lane loop); the TPU path stages the row through VMEM
-    # scratch with real DMAs.  ``store`` returns the POST-store row —
-    # the lane loop is sequential, so on the DMA path the value just
-    # written IS the post-store row and no read-back is needed.
+    # dominate the lane loop); the TPU path stages rows through VMEM
+    # scratch with real DMAs.  A DMA of an HBM slice must cover whole
+    # (8, 128) tiles, so it moves the aligned block of L2_BLOCK rows that
+    # holds set ``s`` and the lane reads / rewrites its own row inside it.
+    # ``store`` returns the POST-store row — the lane loop is sequential,
+    # and every store follows the fetch of the same set, so on the DMA path
+    # the value just written IS the post-store row and the staged block
+    # still holds the other rows unchanged.
     if interpret:
         def fetch_l2(s, scratch):
             return l2out_ref[pl.ds(s, 1), :]
@@ -717,19 +735,21 @@ def _hier_replay_kernel(
             l2out_ref[pl.ds(s, 1), :] = row
             return l2out_ref[pl.ds(s, 1), :]
     else:
-        def fetch_l2(s, scratch):
-            cp = pltpu.make_async_copy(l2out_ref.at[pl.ds(s, 1), :],
-                                       scratch.at[pl.ds(0, 1), :], sem)
+        def block_dma(s, scratch, to_hbm):
+            first = pl.multiple_of((s // L2_BLOCK) * L2_BLOCK, L2_BLOCK)
+            hbm = l2out_ref.at[pl.ds(first, L2_BLOCK), :]
+            src, dst = (scratch, hbm) if to_hbm else (hbm, scratch)
+            cp = pltpu.make_async_copy(src, dst, sem)
             cp.start()
             cp.wait()
-            return scratch[...]
+
+        def fetch_l2(s, scratch):
+            block_dma(s, scratch, to_hbm=False)
+            return scratch[pl.ds(s % L2_BLOCK, 1), :]
 
         def store_l2(s, scratch, row):
-            scratch[...] = row
-            cp = pltpu.make_async_copy(scratch.at[pl.ds(0, 1), :],
-                                       l2out_ref.at[pl.ds(s, 1), :], sem)
-            cp.start()
-            cp.wait()
+            scratch[pl.ds(s % L2_BLOCK, 1), :] = row
+            block_dma(s, scratch, to_hbm=True)
             return row
 
     # ---- sequential lane loop (hierarchy semantics: lane i sees lane
@@ -743,15 +763,15 @@ def _hier_replay_kernel(
         hits, evs, hit1_c, l2_c, pval_c, pa_c, pb_c, pexp_c = carry
         i = step >> 1
         is_even = (step & jnp.int32(1)) == 0
-        qk = _lane_read(qk_ref, blane, i)
-        s1 = _lane_read(s1_ref, blane, i)
-        s2 = _lane_read(s2_ref, blane, i)
-        en = _lane_read(en_ref, blane, i) != 0
+        qk = q_ref[_H_KEY, i]
+        s1 = q_ref[_H_S1, i]
+        s2 = q_ref[_H_S2, i]
+        en = q_ref[_H_EN, i] != 0
         fp = _fingerprint_i32(qk.astype(jnp.uint32))
         t_get = base + i
         t_put = base + jnp.int32(batch) + i
         if ttl:
-            tt_i = _lane_read(tt_ref, blane, i)
+            tt_i = q_ref[_H_TTL, i]
             dl_i = jnp.where(tt_i > 0, hz + tt_i, jnp.int32(NO_EXPIRY))
         else:
             dl_i = None
@@ -817,8 +837,8 @@ def _hier_replay_kernel(
     z = jnp.int32(0)
     hits, evs, *_ = jax.lax.fori_loop(0, 2 * batch, body,
                                       (z, z, z, z, z, z, z, z))
-    hits_ref[0] = hits
-    evs_ref[0] = evs
+    cnt_ref[0, 0] = hits
+    cnt_ref[0, 1] = evs
 
 
 @functools.partial(
@@ -852,22 +872,12 @@ def _replay_hier_jit(
     _TRACE_COUNTS[("trace-hier", int(policy), l1_sets, l1_ways, l2_sets,
                    l2_ways, steps, batch, promote, demote)] += 1
 
-    # ---- streams: sanitize + route BOTH tiers once, pad to lane width
+    # ---- streams: sanitize + route BOTH tiers once, one [5, B] SMEM block
+    # per chunk
     qk = hashing.sanitize_keys(chunks.reshape(-1))
-    s1 = hashing.set_index(qk, l1_sets,
-                           seed ^ L1_SEED_SALT).reshape(steps, batch)
-    s2 = hashing.set_index(qk, l2_sets, seed).reshape(steps, batch)
-    qk = qk.astype(jnp.int32).reshape(steps, batch)
-    en = enabled.astype(jnp.int32)
-    tt = tt.astype(jnp.int32)
-    bp = -(-batch // LANES) * LANES
-    if bp != batch:
-        pad = jnp.zeros((steps, bp - batch), jnp.int32)
-        qk = jnp.concatenate([qk, pad], axis=1)
-        s1 = jnp.concatenate([s1, pad], axis=1)
-        s2 = jnp.concatenate([s2, pad], axis=1)
-        en = jnp.concatenate([en, pad], axis=1)
-        tt = jnp.concatenate([tt, pad], axis=1)
+    s1 = hashing.set_index(qk, l1_sets, seed ^ L1_SEED_SALT)
+    s2 = hashing.set_index(qk, l2_sets, seed)
+    q = _chunk_block([qk, s1, s2, enabled, tt], steps, batch)
 
     # ---- both tiers packed [S, ROW_W]: L1 VMEM-resident, L2 row-per-DMA
     l1p = _pack_lanes(l1_keys, l1_fpr, l1_vals, l1_ma, l1_mb, l1_exp)
@@ -880,36 +890,35 @@ def _replay_hier_jit(
         l2_ways=l2_ways, l2_sets=l2_sets, seed=seed, batch=batch,
         promote=promote, demote=demote, ttl=ttl, interpret=interpret)
 
-    chunk_row = lambda: pl.BlockSpec((1, bp), lambda t, *_: (t, 0))  # noqa: E731
-    full = lambda a: pl.BlockSpec(a.shape, lambda t, *_: (0,) * a.ndim)  # noqa: E731
-    cnt = lambda: pl.BlockSpec((1,), lambda t, *_: (t,))  # noqa: E731
-    anyspace = lambda: pl.BlockSpec(memory_space=pltpu.ANY)  # noqa: E731
+    anyspace = lambda: pl.BlockSpec(memory_space=pl.ANY)  # noqa: E731
 
     outs = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(steps,),
-            in_specs=[chunk_row(), chunk_row(), chunk_row(), chunk_row(),
-                      chunk_row(), full(l1p), anyspace()],
-            out_specs=[cnt(), cnt(), full(l1p), anyspace()],
-            scratch_shapes=[pltpu.VMEM((1, ROW_W), jnp.int32),
+            in_specs=[pl.BlockSpec((None, 5, batch), lambda t, *_: (t, 0, 0),
+                                   memory_space=pltpu.SMEM),
+                      _whole_vmem(), anyspace()],
+            out_specs=[_counters_spec(), _whole_vmem(), anyspace()],
+            scratch_shapes=[pltpu.VMEM((L2_BLOCK, ROW_W), jnp.int32),
                             pltpu.SemaphoreType.DMA],
         ),
-        out_shape=[jax.ShapeDtypeStruct((steps,), jnp.int32),
-                   jax.ShapeDtypeStruct((steps,), jnp.int32),
+        out_shape=[jax.ShapeDtypeStruct((steps, 1, 2), jnp.int32),
                    jax.ShapeDtypeStruct((l1_sets, ROW_W), jnp.int32),
                    jax.ShapeDtypeStruct((l2_sets, ROW_W), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
-    )(scal, qk, s1, s2, en, tt, l1p, l2p)
+    )(scal, q, l1p, l2p)
 
-    hits, evs = outs[0], outs[1]
+    hits, evs = outs[0][:, 0, 0], outs[0][:, 0, 1]
     clock_f = clock + jnp.int32(2 * batch * steps)
-    l1_out = _unpack_lanes(outs[2], l1_ways)
-    l2_out = _unpack_lanes(outs[3], l2_ways)
+    l1_out = _unpack_lanes(outs[1], l1_ways)
+    l2_out = _unpack_lanes(outs[2], l2_ways)
     if carry_exp:
-        l1_out = l1_out + (_unpack_expiry(outs[2], l1_ways),)
-        l2_out = l2_out + (_unpack_expiry(outs[3], l2_ways),)
+        l1_out = l1_out + (_unpack_expiry(outs[1], l1_ways),)
+        l2_out = l2_out + (_unpack_expiry(outs[2], l2_ways),)
     return hits, evs, l1_out, l2_out, clock_f
 
 
@@ -930,7 +939,7 @@ def replay_hierarchical(
     l1_exp=None,
     l2_exp=None,
     ttls=None,
-    interpret: bool = True,
+    interpret: bool | None = None,   # None: repro.kernels.interpret()
 ):
     """Run the hierarchical replay megakernel: ONE launch, L1 pinned in
     VMEM, L2 in slow memory behind per-set row DMAs.
@@ -950,6 +959,12 @@ def replay_hierarchical(
     uint32).
     """
     steps, batch = chunks.shape
+    if interpret is None:
+        interpret = kernels.interpret()
+    if l2_sets % L2_BLOCK:
+        raise ValueError(f"replay_hierarchical moves L2 rows in aligned "
+                         f"blocks of {L2_BLOCK}; l2_sets={l2_sets} is not a "
+                         f"multiple of it")
     _TRACE_COUNTS[("launch-hier", int(policy), l1_sets, l1_ways, l2_sets,
                    l2_ways, steps, batch, promote, demote)] += 1
     carry_exp = (l1_exp is not None or l2_exp is not None

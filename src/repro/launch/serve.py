@@ -1,13 +1,16 @@
 """Serving driver: batched requests through the K-way paged engine.
 
-    PYTHONPATH=src python -m repro.launch.serve --arch deepseek-7b --smoke \
+    PYTHONPATH=src python -m repro.launch.serve --arch deepseek-7b \
         --requests 16 --policy lru [--tinylfu] [--jitted] [--decode-block 4]
 
 Prints throughput, prefix-cache hit ratio and page-pool stats — the serving
-analogue of the paper's §5.3 trace runs.  ``--jitted`` runs the
-device-resident one-traced-program serving tick (DESIGN.md §11) instead of
-the host loop; ``--decode-block`` sets the multi-step decode burst both
-modes schedule.
+analogue of the paper's §5.3 trace runs.  ``--smoke`` (the default) serves
+the architecture's 2-layer smoke config; ``--no-smoke`` serves its
+published config with weights drawn from ``--seed`` (e.g. ``--arch
+minicpm-2b --no-smoke``: 2.7 B parameters, 5.5 GB in bf16 — a TPU job).
+``--jitted`` runs the device-resident one-traced-program serving tick
+(DESIGN.md §11) instead of the host loop; ``--decode-block`` sets the
+multi-step decode burst both modes schedule.
 """
 from __future__ import annotations
 
@@ -27,7 +30,10 @@ from repro.serve.engine import Engine, EngineConfig
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="deepseek-7b", choices=configs.ARCH_IDS)
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="serve the 2-layer smoke config (default); "
+                         "--no-smoke serves the published config")
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--max-new", type=int, default=8)
     ap.add_argument("--policy", default="lru",
@@ -50,12 +56,14 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     spec = configs.get(args.arch)
-    cfg = spec.smoke
+    cfg = spec.smoke if args.smoke else spec.config
     if not (cfg.has_attention and cfg.enc_layers == 0 and not cfg.has_ssm):
         print(f"{args.arch}: paged engine targets decoder-only attention "
               "archs (DESIGN.md §4); serving via plain batched decode only.")
         return 0
-    params = lm.init_params(cfg, jax.random.key(0))
+    # jitted: the bf16 weights are drawn on the device, never staged in f32
+    params = jax.jit(lm.init_params, static_argnums=0)(
+        cfg, jax.random.key(args.seed))
     eng = Engine(cfg, params, EngineConfig(
         page=8, num_sets=32, ways=8, policy=Policy[args.policy.upper()],
         tinylfu=args.tinylfu, max_batch=8, max_seq=256, private_pages=256,
@@ -78,4 +86,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+    compile_cache.enable()
     sys.exit(main())

@@ -153,4 +153,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+    compile_cache.enable()
     sys.exit(main())

@@ -48,6 +48,34 @@ def test_pallas_rejects_unsupported():
         make_backend("pallas", KWayConfig(num_sets=1, ways=64, sample=8))
 
 
+def test_pallas_refuses_cache_over_vmem_limit():
+    """A cache whose lanes the probe kernels cannot hold in VMEM is refused
+    with the numbers, on every flat path — never handed to a kernel the TPU
+    compiler would reject, nor demoted to the probe-driven scan."""
+    from repro.core import backend as backend_mod
+    cfg = KWayConfig(num_sets=1 << 15, ways=8)
+    pb = make_backend("pallas", cfg)
+    assert not pb.probe_fits()
+    st = pb.init()
+    keys = jnp.arange(8, dtype=jnp.uint32)
+    chunks, en = keys[None], jnp.ones((1, 8), bool)
+    for call in (lambda: pb.get(st, keys),
+                 lambda: pb.put(st, keys, keys.astype(jnp.int32)),
+                 lambda: pb.access(st, keys, keys.astype(jnp.int32)),
+                 lambda: pb.peek_victims(st, keys),
+                 lambda: pb.replay(st, chunks, en)):
+        with pytest.raises(ValueError, match="VMEM"):
+            call()
+    with backend_mod.vmem_budget(0):      # no resident path: same refusal
+        with pytest.raises(ValueError, match="num_sets=32768"):
+            pb.replay(st, chunks, en)
+    # a hierarchy keeps the L2 in HBM and still replays this capacity
+    from repro.core.hierarchy import HierarchyConfig
+    hits, _, _, _ = pb.replay(st, chunks, en,
+                              hierarchy=HierarchyConfig(l1_sets=8, l1_ways=4))
+    assert int(hits.sum()) == 0
+
+
 @pytest.mark.parametrize("policy", ALL_POLICIES)
 @pytest.mark.parametrize("layout", ["soa", "aos"])
 def test_serial_equivalence_policies(policy, layout):

@@ -17,7 +17,7 @@ import re
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.core import kway
 from repro.core.kway import KWayConfig
