@@ -1,0 +1,219 @@
+"""What decides a run's ``correct``, on the CPU at small sizes.
+
+* The control (the reference with one stated guarantee broken, put in the
+  program's place) differs from the reference on every cell's numbers.
+* A whole run of the harness, with the chip check skipped and the timed
+  path broken underneath, reads ``correct`` false, for each fault a
+  one-chip cache cell can have: a step that returns its state unchanged,
+  half of each batch left out, an answer altered where it is produced.
+  (No cell spans chips yet, so there is no exchange to leave out.)
+* Each number that says whether a run measured its cell (the warm fill,
+  compiles and demotions in the window) reads above its limit under its
+  own fault.
+"""
+import dataclasses
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from bench import gen, harness
+from repro.core import backend as backend_mod
+from repro.robust import events
+
+# 64 sets x 8 ways under 1024 keys over 341 items: sets overflow, so the
+# window evicts as well as hits
+TINY_CONF = {"num_sets": 64, "segment_chunks": 2}
+TINY_MIX = {"keys": 1024, "items": 341, "batch": 128}
+CELLS = ["getput.read_only", "getput.served"]
+WINDOW_NUMBERS = {"chunk_mismatches", "lane_mismatches", "state_mismatches"}
+
+
+def tiny_cell(name):
+    cell = harness.resolve(name)
+    cell.config.update(TINY_CONF)
+    cell.mix.update(TINY_MIX)
+    return cell
+
+
+def run(cell, seed=2**31 + 5, steps=3):
+    return harness.run_cell(cell, seed, 60.0, False, t_start=time.perf_counter(),
+                            require_tpu=False, max_steps=steps,
+                            log=lambda *a: None)
+
+
+def failing(result):
+    return [n for n, c in result["checks"].items() if c["value"] > c["limit"]]
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_sound_run_is_correct(name):
+    cell = tiny_cell(name)
+    r = run(cell)
+    assert r["correct"], r["checks"]
+    per = cell.mix["batch"] * (cell.config["segment_chunks"]
+                               if cell.mix["client"] == "replay" else 1)
+    assert r["attempted"] == 3 * per
+    assert set(r) >= {"correct", "attempted", "failed", "metrics", "device"}
+    assert list(r)[-1] == "checks"
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_control_differs_from_reference(name):
+    cell = tiny_cell(name)
+    conf, mix = cell.config, cell.mix
+    client = harness.load_module("clients", mix["client"])
+    ref = harness.load_module("refs", conf["reference"])
+    keys = gen.key_array(4, mix)
+    fill, _ = harness.reference_fill(ref, conf, harness.fill_chunks(keys, mix["batch"]))
+    requests = gen.Cycled(keys, mix["batch"], 24)
+    st = harness.copy.deepcopy(fill)
+    want = client.reference(ref, conf, st, requests)
+    numbers, control = harness.check_window(client, ref, conf, fill, requests,
+                                            want, st.lanes(), control=True)
+    assert not any(numbers.values()), numbers
+    assert control["state_mismatches"] > 0
+
+
+@pytest.mark.parametrize("sets", [64, 4096])          # evicting; hit-only
+@pytest.mark.parametrize("control", [False, True])
+def test_reference_fast_forward_matches_stepping(sets, control):
+    """``flat.run`` skips whole periods of hit-only batches; it returns the
+    same answers and final state as stepping every batch."""
+    conf = {**harness.resolve("getput.read_only").config, "num_sets": sets}
+    ref = harness.load_module("refs", "flat")
+    keys = gen.key_array(2**31 + 9, {**harness.resolve("getput.read_only").mix,
+                                     **TINY_MIX})
+    fill, _ = harness.reference_fill(ref, conf, harness.fill_chunks(keys, 128))
+    batches = gen.Cycled(keys, 128, 8 * 7 + 3)
+    fast, slow = harness.copy.deepcopy(fill), harness.copy.deepcopy(fill)
+    got = ref.run(fast, conf, batches, control=control)
+    want = [ref.step(slow, conf, batches[i], control=control)
+            for i in range(len(batches))]
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        for a, b in zip(g, w):
+            np.testing.assert_array_equal(a, b)
+    assert harness.slot_mismatches(fast.lanes(), slow.lanes()) == 0
+    evicting = sum(int(w[2].sum()) for w in want) > 0
+    assert evicting == (sets == 64)
+
+
+def test_unknown_client_or_reference_is_an_error():
+    with pytest.raises(FileNotFoundError, match="no clients named"):
+        harness.load_module("clients", "open_loop_imaginary")
+    with pytest.raises(FileNotFoundError, match="no refs named"):
+        harness.load_module("refs", "tiered_imaginary")
+
+
+def _replay_fault(kind):
+    inner = backend_mod.CacheBackend.replay
+
+    def replay(self, state, chunks, enabled, *a, **k):
+        if kind == "half_batch":
+            b = enabled.shape[-1]
+            enabled = jnp.asarray(enabled) & (jnp.arange(b) < b // 2)
+        hits, evs, out, sk = inner(self, state, chunks, enabled, *a, **k)
+        if kind == "state_unchanged":
+            out = state
+        if kind == "answer_altered":
+            hits = hits.at[0].add(1)
+        return hits, evs, out, sk
+    return replay
+
+
+def _access_fault(kind):
+    inner = backend_mod.JnpBackend.access
+
+    def access(self, state, qkeys, qvals, admit_on_miss=None, enabled=None,
+               ttls=None, **k):
+        timed = not isinstance(qkeys, jax.core.Tracer)   # not the jitted fill
+        if timed and kind == "half_batch":
+            b = qkeys.shape[0]
+            enabled = jnp.arange(b) < b // 2
+        out, hit, vals, ek, ev = inner(self, state, qkeys, qvals,
+                                       admit_on_miss, enabled, ttls, **k)
+        if timed and kind == "state_unchanged":
+            out = state
+        if timed and kind == "answer_altered":
+            vals = vals.at[0].add(1)
+        return out, hit, vals, ek, ev
+    return access
+
+
+@pytest.mark.parametrize("kind", ["state_unchanged", "half_batch", "answer_altered"])
+@pytest.mark.parametrize("name", CELLS)
+def test_broken_timed_path_reads_incorrect(name, kind, monkeypatch):
+    cell = tiny_cell(name)
+    if cell.mix["client"] == "replay":
+        monkeypatch.setattr(backend_mod.CacheBackend, "replay", _replay_fault(kind))
+    else:
+        monkeypatch.setattr(backend_mod.JnpBackend, "access", _access_fault(kind))
+    r = run(cell)
+    assert not r["correct"]
+    assert set(failing(r)) & WINDOW_NUMBERS, r["checks"]
+
+
+def _fill_fault(kind):
+    inner = harness.System.fill
+
+    def fill(self, chunks):
+        state, evs = inner(self, chunks)
+        if kind == "fill_dropped":
+            state = self.filler.init()
+        if kind == "fill_reports_evictions":
+            evs = evs + 1
+        if kind == "fingerprint_corrupted":
+            state = dataclasses.replace(state, fprint=state.fprint ^ jnp.uint32(1))
+        return state, evs
+    return fill
+
+
+def _replay_side_effect(kind):
+    inner = backend_mod.CacheBackend.replay
+    calls = []
+
+    def replay(self, state, chunks, enabled, *a, **k):
+        calls.append(1)
+        if len(calls) > 2:                        # inside the window
+            if kind == "compiles_in_window":
+                jax.jit(lambda x: x + len(calls))(jnp.zeros(len(calls)))
+            if kind == "degrades_in_window":
+                events.record(component="test", reason="demoted")
+        return inner(self, state, chunks, enabled, *a, **k)
+    return replay
+
+
+@pytest.mark.parametrize("kind,number", [
+    ("fill_dropped", "fill_mismatches"),
+    ("fill_reports_evictions", "fill_mismatches"),
+    ("fingerprint_corrupted", "invariant_violations"),
+    ("compiles_in_window", "window_compiles"),
+    ("degrades_in_window", "degradation_events"),
+])
+def test_each_validity_number_reads_its_fault(kind, number, monkeypatch):
+    """The numbers that say whether a run measured its cell each read
+    above their limit 0 under the fault they exist for."""
+    cell = tiny_cell("getput.read_only")
+    if kind.startswith(("fill", "fingerprint")):
+        monkeypatch.setattr(harness.System, "fill", _fill_fault(kind))
+    else:
+        monkeypatch.setattr(backend_mod.CacheBackend, "replay",
+                            _replay_side_effect(kind))
+    r = run(cell)
+    assert r["checks"][number]["value"] > r["checks"][number]["limit"]
+    assert not r["correct"]
+
+
+def test_tiny_cell_evicts_in_its_window():
+    """The small cells above exercise eviction, not only hits."""
+    cell = tiny_cell("getput.read_only")
+    conf, mix = cell.config, cell.mix
+    ref = harness.load_module("refs", "flat")
+    keys = gen.key_array(2**31 + 5, mix)
+    st, _ = harness.reference_fill(ref, conf, harness.fill_chunks(keys, mix["batch"]))
+    evs = sum(int(ref.step(st, conf, c)[2].sum())
+              for c in gen.cycled(keys, 0, 6 * mix["batch"]).reshape(6, -1))
+    assert evs > 0
