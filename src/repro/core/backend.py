@@ -35,6 +35,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -70,6 +71,27 @@ def vmem_budget(nbytes: int):
         yield
     finally:
         RESIDENT_VMEM_BUDGET = prev
+
+#: The profiler span around a host call of a backend's ``access``: the
+#: program's own host time per batch (dispatch, argument handling) on the
+#: profiler's clock, beside the device ops of the call.
+ACCESS_SPAN = "cache.access"
+
+
+def _access_span(access):
+    """Open the ``ACCESS_SPAN`` profiler span around a host call of
+    ``access``.  A call traced into another program (``jit``/``scan``
+    bodies, where the keys are tracers) opens none: there it runs once, at
+    trace time.  Without an active profiler the span costs well under a
+    microsecond."""
+    @functools.wraps(access)
+    def call(self, state, qkeys, *args, **kwargs):
+        if isinstance(qkeys, jax.core.Tracer):
+            return access(self, state, qkeys, *args, **kwargs)
+        with jax.profiler.TraceAnnotation(ACCESS_SPAN):
+            return access(self, state, qkeys, *args, **kwargs)
+    return call
+
 
 _REGISTRY: dict[str, type] = {}
 
@@ -151,6 +173,7 @@ class CacheBackend:
             vals = jnp.where(hit, vals, qvals)
         return state, hit, vals, ek, ev
 
+    @_access_span
     def access(self, state, qkeys, qvals, admit_on_miss=None, enabled=None,
                ttls=None, *, slot_value: bool = False):
         """-> (state', hit[B], vals[B], evicted_keys[B], evicted_valid[B])
@@ -293,6 +316,7 @@ class JnpBackend(CacheBackend):
         return kway.put(self.cfg, state, qkeys, qvals, admit=admit,
                         enabled=enabled, slot_value=slot_value)
 
+    @_access_span
     def access(self, state, qkeys, qvals, admit_on_miss=None, enabled=None,
                ttls=None, *, slot_value: bool = False):
         # fused single-probe path (kway.apply_access); bit-identical to
@@ -365,6 +389,7 @@ class PallasBackend(CacheBackend):
             hit = hit & enabled
         return kway.apply_get(self.cfg, state, sets, hit, way)
 
+    @_access_span
     def access(self, state, qkeys, qvals, admit_on_miss=None, enabled=None,
                ttls=None, *, slot_value: bool = False):
         # ONE kernel launch (fused probe + victim order on hit-updated
@@ -629,6 +654,7 @@ class RefBackend(CacheBackend):
         ref.clock = clock0
         return jnp.asarray(vk), jnp.asarray(vv)
 
+    @_access_span
     def access(self, state, qkeys, qvals, admit_on_miss=None, enabled=None,
                ttls=None, *, slot_value: bool = False):
         """Oracle access with the same expiry discipline as the batched

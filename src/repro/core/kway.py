@@ -30,6 +30,14 @@ baseline the paper also measures).
 The fully-associative oracle is *this same cache* with ``num_sets=1,
 ways=capacity`` — the paper's observation that full associativity is the
 degenerate corner of the design space.
+
+Each phase of an operation runs under a ``jax.named_scope``: ``kway.scrub``
+(expiry scrub), ``kway.probe`` (set index, gather, key compare), ``kway.hit``
+(hit-side metadata update and value gather), ``kway.victims`` (victim
+order), ``kway.resolve`` (intra-batch dedupe and rank) and ``kway.insert``
+(evicted-key gather, lane scatters).  The names ride in the compiled
+program's op metadata only, and a profiler trace carries them per device op
+(its ``tf_op``), so device time can be split by phase.
 """
 from __future__ import annotations
 
@@ -139,6 +147,7 @@ def ensure_expiry(state: KWayState) -> KWayState:
         state, expiry=jnp.full(state.keys.shape, NO_EXPIRY, jnp.int32))
 
 
+@jax.named_scope("kway.scrub")
 def scrub_expired(state: KWayState, horizon: jnp.ndarray) -> KWayState:
     """Reclaim every entry whose deadline is at or before ``horizon``.
 
@@ -186,6 +195,7 @@ def insert_deadlines(clock, b: int, ttls: Optional[jnp.ndarray]):
 # probing
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("kway.probe")
 def _probe(cfg: KWayConfig, state: KWayState, qkeys: jnp.ndarray):
     """Gather each query's set and locate the key.
 
@@ -267,6 +277,7 @@ def sampled_way_ids(sample: int, ways: int, times: jnp.ndarray) -> jnp.ndarray:
     return (h % jnp.uint32(ways)).astype(jnp.int32)
 
 
+@jax.named_scope("kway.victims")
 def _victim_order_arrays(cfg: KWayConfig, keys_arr, meta_a_arr, meta_b_arr,
                          sets, set_keys, times):
     """Per request: ways of its set ordered worst-victim-first. [B, k]
@@ -298,6 +309,7 @@ def _victim_order(cfg: KWayConfig, state: KWayState, sets, set_keys, times):
                                 sets, set_keys, times)
 
 
+@jax.named_scope("kway.resolve")
 def _resolve_inserts(cfg: KWayConfig, qkeys, sets, eligible, order):
     """Deterministic insert conflict resolution, shared by ``apply_put`` and
     ``apply_access`` (one definition so the fused and two-phase paths cannot
@@ -334,21 +346,23 @@ def apply_get(cfg: KWayConfig, state: KWayState, sets, hit, way):
     b = sets.shape[0]
     times, clock = _batch_times(state, b)
 
-    ma_hit = state.meta_a[sets, way]
-    mb_hit = state.meta_b[sets, way]
-    new_a, new_b = on_hit(cfg.policy, ma_hit, mb_hit, times)
-    # Duplicate (set, way) pairs in one batch: LFU/Hyperbolic counts must
-    # accumulate (two hits = +2), LRU must take the max timestamp.  Scatter-add
-    # the deltas instead of scatter-set.
-    da = jnp.where(hit, new_a - ma_hit, 0)
-    if cfg.policy in (Policy.LFU, Policy.HYPERBOLIC):
-        meta_a = state.meta_a.at[sets, way].add(da)
-    else:
-        meta_a = state.meta_a.at[sets, way].max(jnp.where(hit, new_a, -(2**31 - 1)))
-    db = jnp.where(hit, new_b - mb_hit, 0)
-    meta_b = state.meta_b.at[sets, way].add(db)
+    with jax.named_scope("kway.hit"):
+        ma_hit = state.meta_a[sets, way]
+        mb_hit = state.meta_b[sets, way]
+        new_a, new_b = on_hit(cfg.policy, ma_hit, mb_hit, times)
+        # Duplicate (set, way) pairs in one batch: LFU/Hyperbolic counts must
+        # accumulate (two hits = +2), LRU must take the max timestamp.  Scatter-add
+        # the deltas instead of scatter-set.
+        da = jnp.where(hit, new_a - ma_hit, 0)
+        if cfg.policy in (Policy.LFU, Policy.HYPERBOLIC):
+            meta_a = state.meta_a.at[sets, way].add(da)
+        else:
+            meta_a = state.meta_a.at[sets, way].max(
+                jnp.where(hit, new_a, -(2**31 - 1)))
+        db = jnp.where(hit, new_b - mb_hit, 0)
+        meta_b = state.meta_b.at[sets, way].add(db)
 
-    vals = jnp.where(hit, state.vals[sets, way], -1)
+        vals = jnp.where(hit, state.vals[sets, way], -1)
     return (
         dataclasses.replace(state, meta_a=meta_a, meta_b=meta_b, clock=clock),
         hit,
@@ -393,41 +407,44 @@ def apply_put(
     is_insert, way_victim = _resolve_inserts(
         cfg, qkeys, sets, (~present) & admit & enabled, order)
 
-    way = jnp.where(present, way_present, way_victim)
-    active = present | is_insert
+    with jax.named_scope("kway.insert"):
+        way = jnp.where(present, way_present, way_victim)
+        active = present | is_insert
 
-    evicted_keys = state.keys[sets, way_victim]
-    evicted_valid = is_insert & (evicted_keys != EMPTY_KEY)
+        evicted_keys = state.keys[sets, way_victim]
+        evicted_valid = is_insert & (evicted_keys != EMPTY_KEY)
 
-    ia, ib = on_insert(cfg.policy, times, (b,))
+        ia, ib = on_insert(cfg.policy, times, (b,))
 
-    # For present keys: overwrite value, metadata takes the on_hit transition
-    # (a put of an existing key counts as an access — paper Algorithm 3 line 6).
-    ha, hb = on_hit(cfg.policy, state.meta_a[sets, way], state.meta_b[sets, way], times)
-    new_a = jnp.where(present, ha, ia)
-    new_b = jnp.where(present, hb, ib)
+        # For present keys: overwrite value, metadata takes the on_hit
+        # transition (a put of an existing key counts as an access — paper
+        # Algorithm 3 line 6).
+        ha, hb = on_hit(cfg.policy, state.meta_a[sets, way],
+                        state.meta_b[sets, way], times)
+        new_a = jnp.where(present, ha, ia)
+        new_b = jnp.where(present, hb, ib)
 
-    if slot_value:
-        qvals = (sets * jnp.int32(cfg.ways) + way).astype(jnp.int32)
+        if slot_value:
+            qvals = (sets * jnp.int32(cfg.ways) + way).astype(jnp.int32)
 
-    # Inactive lanes scatter to an out-of-bounds set index — JAX drops
-    # out-of-bounds scatter updates, making them true no-ops.  (Routing them
-    # to slot (0,0) with its "current" value is NOT a no-op: a duplicate
-    # scatter index lets the stale inactive write clobber an active lane's
-    # genuine insert into (0,0).)
-    sets_w = jnp.where(active, sets, jnp.int32(cfg.num_sets))
-    way_w = jnp.where(active, way, 0)
+        # Inactive lanes scatter to an out-of-bounds set index — JAX drops
+        # out-of-bounds scatter updates, making them true no-ops.  (Routing them
+        # to slot (0,0) with its "current" value is NOT a no-op: a duplicate
+        # scatter index lets the stale inactive write clobber an active lane's
+        # genuine insert into (0,0).)
+        sets_w = jnp.where(active, sets, jnp.int32(cfg.num_sets))
+        way_w = jnp.where(active, way, 0)
 
-    keys = state.keys.at[sets_w, way_w].set(qkeys)
-    fpr = state.fprint.at[sets_w, way_w].set(hashing.fingerprint(qkeys))
-    vals = state.vals.at[sets_w, way_w].set(qvals)
-    meta_a = state.meta_a.at[sets_w, way_w].set(new_a)
-    meta_b = state.meta_b.at[sets_w, way_w].set(new_b)
-    # put has no TTL argument (TTL riding is the fused access path's job);
-    # an expiry lane, when present, is carried with landing lanes marked
-    # never-expiring so the structural invariants stay intact.
-    expiry = (None if state.expiry is None
-              else state.expiry.at[sets_w, way_w].set(jnp.int32(NO_EXPIRY)))
+        keys = state.keys.at[sets_w, way_w].set(qkeys)
+        fpr = state.fprint.at[sets_w, way_w].set(hashing.fingerprint(qkeys))
+        vals = state.vals.at[sets_w, way_w].set(qvals)
+        meta_a = state.meta_a.at[sets_w, way_w].set(new_a)
+        meta_b = state.meta_b.at[sets_w, way_w].set(new_b)
+        # put has no TTL argument (TTL riding is the fused access path's job);
+        # an expiry lane, when present, is carried with landing lanes marked
+        # never-expiring so the structural invariants stay intact.
+        expiry = (None if state.expiry is None
+                  else state.expiry.at[sets_w, way_w].set(jnp.int32(NO_EXPIRY)))
 
     new_state = KWayState(keys, fpr, vals, meta_a, meta_b, clock, expiry)
     slot_sets = jnp.where(active, sets, -1)
@@ -562,19 +579,20 @@ def apply_access(
     hit = hit_raw if enabled is None else (hit_raw & enabled)
 
     # ---- hit phase (apply_get semantics at times t+i) --------------------
-    ma_hit = state.meta_a[sets, way]
-    new_a, _ = on_hit(cfg.policy, ma_hit, state.meta_b[sets, way], times_get)
-    if cfg.policy in (Policy.LFU, Policy.HYPERBOLIC):
-        meta_a1 = state.meta_a.at[sets, way].add(
-            jnp.where(hit, new_a - ma_hit, 0))
-    elif cfg.policy in (Policy.FIFO, Policy.RANDOM):
-        meta_a1 = state.meta_a          # on_hit is the identity here
-    else:
-        meta_a1 = state.meta_a.at[sets, way].max(
-            jnp.where(hit, new_a, -(2**31 - 1)))
-    # on_hit keeps meta_b for every policy, so the apply_get meta_b
-    # scatter-add is always adding zero — elided.
-    vals_out = jnp.where(hit, state.vals[sets, way], qvals)
+    with jax.named_scope("kway.hit"):
+        ma_hit = state.meta_a[sets, way]
+        new_a, _ = on_hit(cfg.policy, ma_hit, state.meta_b[sets, way], times_get)
+        if cfg.policy in (Policy.LFU, Policy.HYPERBOLIC):
+            meta_a1 = state.meta_a.at[sets, way].add(
+                jnp.where(hit, new_a - ma_hit, 0))
+        elif cfg.policy in (Policy.FIFO, Policy.RANDOM):
+            meta_a1 = state.meta_a          # on_hit is the identity here
+        else:
+            meta_a1 = state.meta_a.at[sets, way].max(
+                jnp.where(hit, new_a, -(2**31 - 1)))
+        # on_hit keeps meta_b for every policy, so the apply_get meta_b
+        # scatter-add is always adding zero — elided.
+        vals_out = jnp.where(hit, state.vals[sets, way], qvals)
 
     # ---- miss phase (apply_put semantics at times t+B+i) -----------------
     # In the composition, every lane the put phase sees is either disabled
@@ -591,35 +609,36 @@ def apply_access(
     is_insert, way_victim = _resolve_inserts(
         cfg, qkeys, sets, (~hit_raw) & admit & enabled, order)
 
-    evicted_keys = state.keys[sets, way_victim]
-    evicted_valid = is_insert & (evicted_keys != EMPTY_KEY)
+    with jax.named_scope("kway.insert"):
+        evicted_keys = state.keys[sets, way_victim]
+        evicted_valid = is_insert & (evicted_keys != EMPTY_KEY)
 
-    if slot_value:
-        slot_id = (sets * jnp.int32(cfg.ways) + way_victim).astype(jnp.int32)
-        qvals = slot_id                      # stored payload for inserts
-        vals_out = jnp.where(
-            hit, state.vals[sets, way],
-            jnp.where(is_insert, slot_id, jnp.int32(-1)))
+        if slot_value:
+            slot_id = (sets * jnp.int32(cfg.ways) + way_victim).astype(jnp.int32)
+            qvals = slot_id                      # stored payload for inserts
+            vals_out = jnp.where(
+                hit, state.vals[sets, way],
+                jnp.where(is_insert, slot_id, jnp.int32(-1)))
 
-    ia, ib = on_insert(cfg.policy, times_put, (b,))
+        ia, ib = on_insert(cfg.policy, times_put, (b,))
 
-    # One packed scatter pass: the (set, way) index pair is computed once and
-    # shared by all five lanes.  Inactive lanes route out of bounds (dropped
-    # by JAX) — see apply_put for why slot (0,0) is not a safe parking spot.
-    sets_w = jnp.where(is_insert, sets, jnp.int32(cfg.num_sets))
-    way_w = jnp.where(is_insert, way_victim, 0)
+        # One packed scatter pass: the (set, way) index pair is computed once and
+        # shared by all five lanes.  Inactive lanes route out of bounds (dropped
+        # by JAX) — see apply_put for why slot (0,0) is not a safe parking spot.
+        sets_w = jnp.where(is_insert, sets, jnp.int32(cfg.num_sets))
+        way_w = jnp.where(is_insert, way_victim, 0)
 
-    keys = state.keys.at[sets_w, way_w].set(qkeys)
-    fpr = state.fprint.at[sets_w, way_w].set(hashing.fingerprint(qkeys))
-    vals = state.vals.at[sets_w, way_w].set(qvals)
-    meta_a = meta_a1.at[sets_w, way_w].set(ia)
-    meta_b = state.meta_b.at[sets_w, way_w].set(ib)
-    expiry = state.expiry
-    if expiry is not None:
-        ie = insert_deadlines(state.clock, b, ttls)
-        if ie is None:           # lane present, no TTLs: never-expiring
-            ie = jnp.full((b,), NO_EXPIRY, jnp.int32)
-        expiry = expiry.at[sets_w, way_w].set(ie)
+        keys = state.keys.at[sets_w, way_w].set(qkeys)
+        fpr = state.fprint.at[sets_w, way_w].set(hashing.fingerprint(qkeys))
+        vals = state.vals.at[sets_w, way_w].set(qvals)
+        meta_a = meta_a1.at[sets_w, way_w].set(ia)
+        meta_b = state.meta_b.at[sets_w, way_w].set(ib)
+        expiry = state.expiry
+        if expiry is not None:
+            ie = insert_deadlines(state.clock, b, ttls)
+            if ie is None:           # lane present, no TTLs: never-expiring
+                ie = jnp.full((b,), NO_EXPIRY, jnp.int32)
+            expiry = expiry.at[sets_w, way_w].set(ie)
 
     new_state = KWayState(keys, fpr, vals, meta_a, meta_b, clock, expiry)
     return new_state, hit, vals_out, evicted_keys, evicted_valid

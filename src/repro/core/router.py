@@ -29,6 +29,11 @@ Layout contract (DESIGN.md §9):
 
 Everything here is shape-static in (B, D, capacity): one XLA compilation per
 shape, asserted by the trace counters in core/sharded.py.
+
+Profiler names: ownership, bucketing and its mask run under the
+``jax.named_scope`` ``router.route``, the inverse permutation under
+``router.unscatter`` (op metadata only; core/sharded.py names the per-shard
+step ``shard.access``).
 """
 from __future__ import annotations
 
@@ -77,6 +82,7 @@ def pad_chunks(trace: np.ndarray, batch: int):
     return padded.reshape(steps, batch), enabled.reshape(steps, batch)
 
 
+@jax.named_scope("router.route")
 def owner_of(keys: jnp.ndarray, num_sets: int, num_shards: int,
              seed: int) -> jnp.ndarray:
     """Owning shard per key: high bits of the global set index. int32 [B]."""
@@ -85,6 +91,7 @@ def owner_of(keys: jnp.ndarray, num_sets: int, num_shards: int,
     return gset // jnp.int32(num_sets // num_shards)
 
 
+@jax.named_scope("router.route")
 def route(owner: jnp.ndarray, num_shards: int, capacity: int,
           enabled: Optional[jnp.ndarray] = None) -> RoutePlan:
     """Stable-argsort bucketing of one batch.  Traceable, shape-static.
@@ -126,6 +133,7 @@ def _dest(plan: RoutePlan, capacity: int, num_shards: int) -> jnp.ndarray:
                      jnp.int32(num_shards * capacity))
 
 
+@jax.named_scope("router.route")
 def bucket(plan: RoutePlan, values: jnp.ndarray, num_shards: int,
            capacity: int, fill) -> jnp.ndarray:
     """Scatter a per-request [B] array into the [D, capacity] bucket layout.
@@ -135,6 +143,7 @@ def bucket(plan: RoutePlan, values: jnp.ndarray, num_shards: int,
     return flat.reshape(num_shards, capacity)
 
 
+@jax.named_scope("router.route")
 def bucket_mask(plan: RoutePlan, num_shards: int,
                 capacity: int) -> jnp.ndarray:
     """The [D, capacity] enabled mask: True exactly where a request landed."""
@@ -144,6 +153,7 @@ def bucket_mask(plan: RoutePlan, num_shards: int,
     return flat.reshape(num_shards, capacity)
 
 
+@jax.named_scope("router.unscatter")
 def unscatter(plan: RoutePlan, bucketed: jnp.ndarray, fill) -> jnp.ndarray:
     """Inverse permutation: gather per-request results [B] back into the
     original batch order from the [D, capacity, ...] bucket layout.

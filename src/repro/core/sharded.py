@@ -193,6 +193,7 @@ class ShardedCache:
                                 self.cfg.num_shards, self.cfg.cache.seed)
         return router.route(owner, self.cfg.num_shards, capacity, enabled)
 
+    @jax.named_scope("shard.access")
     def _local_access(self, tinylfu, two_phase, shard_idx, keys, vals, en,
                       sketch, state: KWayState, ttls=None):
         """One shard's step on its own bucket ([capacity] lanes).

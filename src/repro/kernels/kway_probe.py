@@ -284,6 +284,7 @@ def kway_probe(
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
+        name="kway_probe",
     )(_query_tiles((sets, qkeys, times), qt), keys, fprint, meta_a, meta_b)
     scal = _untile(outs[0])
     res = scal if need_victims else scal[:2]
@@ -439,6 +440,7 @@ def kway_fused_probe(
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
+        name="kway_fused_probe",
     )(_query_tiles((sets, qkeys, times_get, times_put, en), qt),
       keys, fprint, meta_a, meta_b)
     hit, way = _untile(outs[0])[:2]

@@ -152,5 +152,6 @@ def paged_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, d), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(page_table, seq_lens, qg, k_pages, v_pages)
     return out.reshape(b, h, d)

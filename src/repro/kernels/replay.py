@@ -542,6 +542,7 @@ def _replay_resident_jit(
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
+        name="kway_replay_resident",
     )(scal, *in_arrays)
 
     hits, evs = outs[0][:, 0, 0], outs[0][:, 0, 1]
@@ -910,6 +911,7 @@ def _replay_hier_jit(
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
+        name="kway_replay_hier",
     )(scal, q, l1p, l2p)
 
     hits, evs = outs[0][:, 0, 0], outs[0][:, 0, 1]

@@ -20,12 +20,19 @@ def enable() -> str:
     """Turn on JAX's persistent compilation cache and return its directory.
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
-    nothing is set here.  Otherwise the cache goes to ``DEFAULT_DIR``
+    no directory is set here.  Otherwise the cache goes to ``DEFAULT_DIR``
     inside the checkout.
+
+    The cache key includes each op's metadata (its name stack, the
+    ``jax.named_scope`` names among them).  JAX leaves it out by default,
+    and then a program read back from the cache carries the op names of
+    whichever source compiled the same instructions first: a profile would
+    name the phases of another version of the code, or none.
     """
+    import jax
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env
-    import jax
     jax.config.update("jax_compilation_cache_dir", str(DEFAULT_DIR))
     return str(DEFAULT_DIR)
