@@ -226,44 +226,6 @@ def _batch_times(state: KWayState, b: int):
     return times, state.clock + jnp.int32(b)
 
 
-def _intra_batch_rank(sets: jnp.ndarray, active: jnp.ndarray) -> jnp.ndarray:
-    """rank[i] = #(j<i : active[j] and sets[j]==sets[i]) for active i.
-
-    The vectorized stand-in for the paper's CAS retry loop: the r-th insert
-    colliding on a set takes the r-th worst victim.  O(B log B) via sort.
-    """
-    b = sets.shape[0]
-    order_key = jnp.where(active, sets, jnp.int32(0x7FFFFFFF))
-    # Stable sort by set id; arrival order preserved inside each set group.
-    perm = jnp.argsort(order_key, stable=True)
-    sorted_sets = order_key[perm]
-    new_group = jnp.concatenate(
-        [jnp.ones((1,), jnp.bool_), sorted_sets[1:] != sorted_sets[:-1]]
-    )
-    idx = jnp.arange(b, dtype=jnp.int32)
-    group_start = jax.lax.associative_scan(jnp.maximum, jnp.where(new_group, idx, 0))
-    rank_sorted = idx - group_start
-    rank = jnp.zeros((b,), jnp.int32).at[perm].set(rank_sorted)
-    return jnp.where(active, rank, 0)
-
-
-def _first_occurrence(qkeys: jnp.ndarray, active: jnp.ndarray) -> jnp.ndarray:
-    """True for the first active occurrence of each key in the batch."""
-    b = qkeys.shape[0]
-    # Inactive lanes sort under EMPTY_KEY, which sanitize_keys guarantees is
-    # never a real key — a valid-key sentinel (e.g. 0) would absorb the first
-    # occurrence of that key whenever an inactive lane precedes it.
-    order_key = jnp.where(active, qkeys, EMPTY_KEY).astype(jnp.uint32)
-    # sort by (key, arrival); first of each equal-key run wins
-    perm = jnp.argsort(order_key, stable=True)
-    sorted_keys = order_key[perm]
-    first_sorted = jnp.concatenate(
-        [jnp.ones((1,), jnp.bool_), sorted_keys[1:] != sorted_keys[:-1]]
-    )
-    first = jnp.zeros((b,), jnp.bool_).at[perm].set(first_sorted)
-    return first & active
-
-
 def sampled_way_ids(sample: int, ways: int, times: jnp.ndarray) -> jnp.ndarray:
     """Pseudo-random way ids (with replacement) for sampled victim selection
     (Redis-style, O(sample)).  ``times`` int32 [...] -> int32 [..., sample].
@@ -317,15 +279,54 @@ def _resolve_inserts(cfg: KWayConfig, qkeys, sets, eligible, order):
     by arrival order, cap at k admits per set, and pick each insert's victim
     way from ``order`` ([B, m], worst-victim-first).
 
+    The vectorized stand-in for the paper's CAS retry loop, in three sorts
+    that carry their payloads (no data-dependent gather or scatter: on the
+    TPU one over a batch's lanes costs several sorts of it):
+
+      1. by (key, arrival), carrying each lane's set (a sentinel past every
+         real set if ineligible): the first eligible lane of each equal-key
+         run is the one that inserts;
+      2. by (set, arrival) over the inserts, the rest under the sentinel: a
+         segmented scan of group starts gives
+         rank[i] = #(earlier inserts into i's set);
+      3. by arrival, carrying the ordinal rank + 1 (0 for lanes that do not
+         insert) back into batch order.
+
+    Each sort's keys are unique (arrival breaks ties), so none needs to be
+    stable.  Ineligible lanes sort under ``EMPTY_KEY``, which
+    ``sanitize_keys`` guarantees is never a real key — a valid-key sentinel
+    (e.g. 0) would absorb the first occurrence of that key whenever an
+    ineligible lane precedes it.
+
     Returns (is_insert bool[B], way_victim int32[B]); way_victim is the
     rank-selected way for every lane (callers mask with is_insert).
     """
-    is_insert = eligible & _first_occurrence(qkeys, eligible)
-    rank = _intra_batch_rank(sets, is_insert)
-    is_insert &= rank < cfg.ways                          # ≤ k admits per set
-    rank_c = jnp.clip(rank, 0, order.shape[1] - 1)  # dropped lanes: safe idx
-    way_victim = jnp.take_along_axis(order, rank_c[:, None], axis=-1)[:, 0]
-    return is_insert, way_victim
+    b = qkeys.shape[0]
+    idx = jnp.arange(b, dtype=jnp.int32)
+    no_set = jnp.int32(0x7FFFFFFF)
+    key1 = jnp.where(eligible, qkeys, EMPTY_KEY).astype(jnp.uint32)
+    set1 = jnp.where(eligible, sets.astype(jnp.int32), no_set)
+    key1, arr1, set1 = jax.lax.sort((key1, idx, set1), num_keys=2,
+                                    is_stable=False)
+    first = jnp.concatenate([jnp.ones((1,), jnp.bool_), key1[1:] != key1[:-1]])
+
+    key2 = jnp.where(first, set1, no_set)
+    key2, arr2 = jax.lax.sort((key2, arr1), num_keys=2, is_stable=False)
+    new_group = jnp.concatenate(
+        [jnp.ones((1,), jnp.bool_), key2[1:] != key2[:-1]])
+    group_start = jax.lax.associative_scan(
+        jnp.maximum, jnp.where(new_group, idx, 0))
+    ordinal = jnp.where(key2 != no_set, idx - group_start + 1, 0)
+
+    _, ordinal = jax.lax.sort((arr2, ordinal), num_keys=1, is_stable=False)
+    is_insert = (ordinal > 0) & (ordinal <= cfg.ways)     # ≤ k admits per set
+    # One-hot select of column rank = ordinal - 1 (lanes that do not insert:
+    # column 0; past a sampled order's end: its last column).
+    rank_c = jnp.clip(ordinal - 1, 0, order.shape[1] - 1)
+    cols = jnp.arange(order.shape[1], dtype=jnp.int32)
+    way_victim = jnp.sum(
+        jnp.where(cols[None, :] == rank_c[:, None], order, 0), axis=-1)
+    return is_insert, way_victim.astype(order.dtype)
 
 
 # ---------------------------------------------------------------------------
