@@ -6,11 +6,12 @@ control, at a cell's own size, over several seeds in one process.
 
 For each seed this runs the cell as ``bench/run.py`` does (set-up, the
 measured window, the check against the reference) and then puts the
-control in the program's place: the configuration's reference with one
-stated guarantee broken (``bench/refs/flat.py``: hits that do not refresh
-LRU recency), compared with the reference by the same numbers.  The
-program's readings are the lower readings of each limit, the control's
-the upper ones.  One JSON line per seed, then a summary line.  The
+control in the program's place: the configuration's reference with the
+stated guarantee that the configuration's ``control`` names broken
+(``bench/refs/flat.py``: hits that do not refresh LRU recency, or victims
+taken most recent first), compared with the reference by the same
+numbers.  The program's readings are the lower readings of each limit,
+the control's the upper ones.  One JSON line per seed, then a summary line.  The
 benchmark's own runs never run the control.
 """
 import time

@@ -1,9 +1,17 @@
-"""The benchmark's one traffic generator.
+"""The benchmark's traffic generator.
 
-A mix file names the generator and gives its parameters; the only
-generator so far is ``ycsb_scrambled_zipfian``: YCSB's
-``ScrambledZipfianGenerator`` with its published constants, as Caffeine's
-``GetPutBenchmark`` draws its keys with it.
+A mix file names a generator and gives its parameters; ``key_array``
+dispatches on the name.  A mix may also carry a ``fill`` object of the
+same shape (a ``generator`` and its parameters): the warm fill's own key
+stream (``fill_keys``).  Without one, the fill is the window's key array.
+
+``sequential`` gives ``start + i`` for ``i < keys`` as uint32, as
+Caffeine's ``EvictionBenchmark`` puts ``Integer.MIN_VALUE + i`` and then
+``key++``.
+
+``ycsb_scrambled_zipfian`` is YCSB's ``ScrambledZipfianGenerator`` with its
+published constants, as Caffeine's ``GetPutBenchmark`` draws its keys with
+it:
 
 * ``ZipfianGenerator`` (Gray et al., "Quickly generating billion-record
   synthetic databases", SIGMOD 1994), O(1) per draw from a uniform ``u``
@@ -85,13 +93,39 @@ def scrambled_zipfian(u: np.ndarray, mix: dict) -> np.ndarray:
     return fnvhash64(ranks) % int(mix["items"])
 
 
-def key_array(seed: int, mix: dict) -> np.ndarray:
-    """The mix's ``keys`` drawn keys, uint32, from ``seed``: the same seed
-    gives the same array, and every seed the same size."""
-    if mix.get("generator") != "ycsb_scrambled_zipfian":
-        raise ValueError(f"unknown generator {mix.get('generator')!r}")
+def ycsb_scrambled_zipfian(seed: int, mix: dict) -> np.ndarray:
     u = np.random.default_rng(seed).random(int(mix["keys"]))
     return scrambled_zipfian(u, mix).astype(np.uint32)
+
+
+def sequential(seed: int, mix: dict) -> np.ndarray:
+    """``uint32(start + i)`` for ``i < keys``, wrapping mod 2**32; the seed
+    plays no part.  ``start`` = 2**31 gives ``Integer.MIN_VALUE + i`` as
+    the cache's 32-bit key.  A key equal to 0xFFFFFFFF, the empty-way
+    sentinel, is folded to 0xFFFFFFFE by the cache's ``sanitize`` (and by
+    the references'), so a stream that reaches it holds that key twice."""
+    start = int(mix["start"]) % (1 << 32)
+    return (np.arange(int(mix["keys"]), dtype=np.uint64) + np.uint64(start)).astype(np.uint32)
+
+
+GENERATORS = {"ycsb_scrambled_zipfian": ycsb_scrambled_zipfian,
+              "sequential": sequential}
+
+
+def key_array(seed: int, mix: dict) -> np.ndarray:
+    """The ``keys`` keys of the mix's generator, uint32, from ``seed``: the
+    same seed gives the same array, and every seed the same size."""
+    draw = GENERATORS.get(mix.get("generator"))
+    if draw is None:
+        raise ValueError(f"unknown generator {mix.get('generator')!r}; "
+                         f"have {sorted(GENERATORS)}")
+    return draw(seed, mix)
+
+
+def fill_keys(seed: int, mix: dict, keys: np.ndarray) -> np.ndarray:
+    """The warm fill's keys, in order: the mix's ``fill`` stream where it
+    gives one, else ``keys``, the window's own array."""
+    return key_array(seed, mix["fill"]) if "fill" in mix else keys
 
 
 def cycled(keys: np.ndarray, start: int, count: int) -> np.ndarray:

@@ -7,16 +7,16 @@ geometry, backend and the name of its plain reference,
 (``bench/traffic/<traffic>.json``: generator parameters and the name of
 its client, ``bench/clients/<client>.py``), and one reader per metric
 (``bench/metrics/<metric>.py``).  The code here is general: set up (draw
-the key array, fill the cache, warm the timed call), measure for
-``seconds``, then check what the timed path produced against the plain
-reference.
+the key array, fill the cache with the mix's fill stream or that array,
+warm the timed call), measure for ``seconds``, then check what the timed
+path produced against the plain reference.
 
 A client module gives ``SPANS``, ``build``, ``window``, ``requests``,
 ``reference``, ``outputs``, ``compare`` and ``totals`` (see
 ``clients/replay.py``); a reference module gives ``init(conf)``,
 ``step(state, conf, keys, control=)`` for one batch and
-``run(state, conf, batches, control=)`` for a window's, and its state
-``lanes()``.
+``run(state, conf, batches, control=)`` for a window's (``control``: the
+control the configuration names), and its state ``lanes()``.
 """
 from __future__ import annotations
 
@@ -196,10 +196,10 @@ def slot_mismatches(got: dict, want: dict) -> int:
 
 
 def fill_chunks(keys: np.ndarray, batch: int) -> np.ndarray:
-    """The warm fill's requests: every key of the array once, in order, in
-    batches of ``batch``."""
+    """The warm fill's requests: every key of the fill's array
+    (``gen.fill_keys``) once, in order, in batches of ``batch``."""
     if keys.size % batch:
-        raise ValueError(f"the key array ({keys.size}) is not a whole number "
+        raise ValueError(f"the fill's key array ({keys.size}) is not a whole number "
                          f"of batches of {batch}")
     return keys.reshape(-1, batch)
 
@@ -280,7 +280,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     marks = [("start", t_start), ("devices", t_devices), ("load", time.perf_counter())]
     keys = gen.key_array(seed, mix)
     traffic = client.build(conf, mix, keys, devs[0])
-    fchunks = fill_chunks(keys, batch)
+    fchunks = fill_chunks(gen.fill_keys(seed, mix, keys), batch)
     marks.append(("traffic", time.perf_counter()))
     filled, fill_evs = jax.block_until_ready(
         system.fill(jax.device_put(fchunks, devs[0])))
@@ -387,6 +387,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
               "device": device}
     if trace:
         result["breakdown"] = trace_reduce.breakdown(ctx.trace)
+    result["window"] = {"hits": hits, "evictions": evs}
     if control:
         result["control"] = control_checks
     result["checks"] = {n: {"value": checks[n], "limit": limits[n]} for n in checks}

@@ -20,9 +20,16 @@ from the cache's published contract (DESIGN.md sections 2, 3 and 8):
 
 The float32 ranking is the configuration's stated guarantee: recency
 stamps above 2**24 round to even, so stamps a few ticks apart can tie and
-the lower way goes first.  The control (``stale_recency=True``) is the
-same cache with one guarantee broken: hits do not refresh recency, so
-eviction is by insertion age and not by LRU.
+the lower way goes first.  A control is the same cache with one guarantee
+broken; the configuration names it (``control``, ``stale_recency`` where
+it names none):
+
+* ``stale_recency``: hits do not refresh recency, so eviction is by
+  insertion age and not by LRU;
+* ``mru_victims``: the victim order ranks occupied ways most recent
+  first, so an insert evicts the newest entry of its set and not the
+  oldest.
+
 Nothing here imports the program.
 """
 from __future__ import annotations
@@ -105,23 +112,32 @@ def run(st: FlatState, conf: dict, batches, *, control: bool = False) -> list:
     return out
 
 
+CONTROLS = ("stale_recency", "mru_victims")
+
+
 def step(st: FlatState, conf: dict, keys: np.ndarray, *, control: bool = False):
     """One batch of get-or-insert of ``keys`` with the payload convention
     value == key (as int32), under the configuration's hash seed; the
-    control when ``control``.  -> as ``access``."""
+    configuration's control when ``control``.  -> as ``access``."""
+    name = conf.get("control", "stale_recency")
+    if name not in CONTROLS:
+        raise ValueError(f"the flat reference has no control {name!r}; "
+                         f"have {CONTROLS}")
     return access(st, keys, keys.astype(np.int32), int(conf["seed"]),
-                  stale_recency=control)
+                  stale_recency=control and name == "stale_recency",
+                  mru_victims=control and name == "mru_victims")
 
 
 def access(st: FlatState, qkeys: np.ndarray, qvals: np.ndarray, seed: int,
-           *, stale_recency: bool = False):
+           *, stale_recency: bool = False, mru_victims: bool = False):
     """One batch of get-or-insert.  Mutates ``st``; returns (hit bool[B],
     vals int32[B], evicted bool[B], evicted_key uint32[B])."""
     b = qkeys.shape[0]
     sets, ways = st.keys.shape
     q = khash.sanitize(np, qkeys)
     s = khash.set_index(np, q, sets, seed)
-    row = st.keys[s]
+    # np.take, not st.keys[s]: the same rows, gathered several times faster
+    row = np.take(st.keys, s, axis=0)
     eq = (row == q[:, None]) & (row != EMPTY)
     hit = eq.any(axis=1)
     way = eq.argmax(axis=1)
@@ -129,7 +145,8 @@ def access(st: FlatState, qkeys: np.ndarray, qvals: np.ndarray, seed: int,
     if not stale_recency:
         np.maximum.at(st.meta_a, (s[hit], way[hit]),
                       (st.clock + lanes[hit]).astype(np.int32))
-    vals = np.where(hit, st.vals[s, way], qvals).astype(np.int32)
+    vals = qvals.astype(np.int32)
+    vals[hit] = st.vals[s[hit], way[hit]]
 
     miss = np.flatnonzero(~hit)
     _, first = np.unique(q[miss], return_index=True)
@@ -145,10 +162,14 @@ def access(st: FlatState, qkeys: np.ndarray, qvals: np.ndarray, seed: int,
     keep = rank < ways
     ins, si, rank = ins[keep], si[keep], rank[keep]
 
-    score = st.meta_a[si].astype(np.float32)
-    score[st.keys[si] == EMPTY] = NEG
-    victim = np.argsort(score, axis=1, kind="stable")[np.arange(si.size), rank]
-    old = st.keys[si, victim]
+    keys = row[ins]                                  # st.keys[si]: no key moved yet
+    score = np.take(st.meta_a, si, axis=0).astype(np.float32)
+    if mru_victims:
+        score = -score
+    score[keys == EMPTY] = NEG
+    n = np.arange(si.size)
+    victim = np.argsort(score, axis=1, kind="stable")[n, rank]
+    old = keys[n, victim]
     ev = np.zeros(b, bool)
     ek = np.zeros(b, np.uint32)
     ev[ins] = old != EMPTY

@@ -15,11 +15,13 @@ What it reads, beside what ``trace_reduce`` reads:
   lines are skipped by their length, so the cost is a few hundred metadata
   records, not one Python step per event;
 * the replay program's optimized HLO, from the ``/host:metadata`` plane
-  (xla's ``hlo.proto``), for the one kind of op whose name the compiler
-  drops: on a TPU a scatter into a 2-D state lane is rebuilt as a 1-D
-  scatter over the flattened lane, with no ``op_name``.  The rebuilt index
-  computation keeps the name of the scope that built the indices, so such
-  a scatter is booked to the nearest scoped op that feeds its indices;
+  (xla's ``hlo.proto``), for the ops whose name the compiler drops: on a
+  TPU a scatter into a 2-D state lane is rebuilt as a 1-D scatter over the
+  flattened lane, with no ``op_name``.  The rebuilt index computation
+  keeps the name of the scope that built the indices, so such a scatter is
+  booked to the nearest scoped op that feeds its indices.  The nameless
+  copies that lay the lane out for it and lay its result back (at 2^20
+  sets a ``while`` of ``dynamic-update-slice`` ops) go with it;
 * the host spans ``window`` (the harness's) and ``cache.access`` (the
   program's, ``repro.core.backend.ACCESS_SPAN``).
 
@@ -190,7 +192,7 @@ def op_metadata(path) -> dict:
 # ---------------------------------------------------------------------------
 
 _Instruction = collections.namedtuple(
-    "_Instruction", "name opcode phase operands calls parameter computation")
+    "_Instruction", "name opcode phase named operands calls parameter computation")
 
 
 def _instructions(proto) -> tuple:
@@ -221,7 +223,8 @@ def _instructions(proto) -> tuple:
             found = _SCOPE.findall(op_name)
             instructions[d[_INS_ID]] = _Instruction(
                 _text(d[_INS_NAME]), _text(d[_INS_OPCODE]),
-                found[-1] if found else None, operands, calls, d[_INS_PARAMETER], cid)
+                found[-1] if found else None, bool(op_name), operands, calls,
+                d[_INS_PARAMETER], cid)
     return instructions, roots
 
 
@@ -232,6 +235,11 @@ def scatter_phases(proto) -> dict:
     that feeds the scatter's indices, followed through fusion parameters to
     the fusion's operands.  An op with no scoped feeder is not listed."""
     ins, roots = _instructions(proto)
+    return {ins[i].name: p for i, p in _scatters(ins, roots).items()}
+
+
+def _scatters(ins: dict, roots: dict) -> dict:
+    """``scatter_phases`` by instruction id."""
     caller = {c: i for i in ins.values() if i.opcode == "fusion" for c in i.calls}
 
     def feeders(i):
@@ -259,18 +267,77 @@ def scatter_phases(proto) -> dict:
         return None
 
     out = {}
-    for i in ins.values():
+    for iid, i in ins.items():
         result = ins.get(roots.get(i.calls[0])) if i.opcode == "fusion" and i.calls else i
         if (i.phase is None and result is not None and result.opcode == "scatter"
                 and result.phase is None and result.operands):
             phase = indices_phase(result)
             if phase:
-                out[i.name] = phase
+                out[iid] = phase
     return out
 
 
+# ops that join nothing: a constant or a parameter may be shared by ops of
+# any phase, and runs no device time of its own
+_NO_BRIDGE = {"constant", "parameter"}
+_LOOPS = {"while", "call", "conditional"}
+
+
+def _attached(ins: dict, scatters: dict) -> dict:
+    """Instruction id -> phase of each op with no name at all (no
+    ``op_name``) that data flow ties to a booked scatter through other such
+    ops: the copies that lay a state lane out for the scatter, and the
+    nameless loop that lays its result back out (a large lane's layout
+    change becomes a ``while`` of ``dynamic-update-slice`` ops), with the
+    ops of that loop's body.  Each goes to the nearest booked scatter, by
+    the fewest such edges; a named op, a constant and a parameter join
+    nothing."""
+    users = collections.defaultdict(list)
+    members = collections.defaultdict(list)
+    loop_of = {}
+    for iid, i in ins.items():
+        for o in i.operands:
+            users[o].append(iid)
+        members[i.computation].append(iid)
+        if i.opcode in _LOOPS and not i.named:
+            for c in i.calls:
+                loop_of[c] = iid
+
+    def neighbours(iid):
+        i = ins[iid]
+        out = i.operands + users[iid]
+        if i.opcode in _LOOPS:
+            out = out + [m for c in i.calls for m in members[c]]
+        if i.computation in loop_of:
+            out = out + [loop_of[i.computation]]
+        return out
+
+    phase = {}
+    queue = collections.deque(scatters.items())
+    while queue:
+        iid, p = queue.popleft()
+        for n in neighbours(iid):
+            i = ins.get(n)
+            if (i is None or n in phase or n in scatters or i.named
+                    or i.opcode in _NO_BRIDGE):
+                continue
+            phase[n] = p
+            queue.append((n, p))
+    return phase
+
+
+def program_phases(proto) -> dict:
+    """Instruction name -> phase of every op of an ``HloProto`` that runs
+    with no ``kway.*`` scope of its own yet belongs to a phase: the
+    nameless scatters (``scatter_phases``) and the nameless ops that lay
+    their lanes out (``_attached``)."""
+    ins, roots = _instructions(proto)
+    scatters = _scatters(ins, roots)
+    return {ins[i].name: p for i, p in {**_attached(ins, scatters), **scatters}.items()}
+
+
 def booked_scatters(path) -> dict:
-    """program_id -> ``scatter_phases`` of each replay program in the
+    """program_id -> ``program_phases`` of each replay program in the
     ``/host:metadata`` plane of an ``.xplane.pb`` file (empty where the
     plane is missing)."""
     out = {}
@@ -280,7 +347,7 @@ def booked_scatters(path) -> dict:
         for pid, program, _, stats in _event_metadata(plane):
             proto = next((v for v in stats.values() if isinstance(v, memoryview)), None)
             if REPLAY_PROGRAM.match(program) and proto is not None:
-                out[pid] = scatter_phases(proto)
+                out[pid] = program_phases(proto)
     return out
 
 

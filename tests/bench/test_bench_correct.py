@@ -1,7 +1,8 @@
 """What decides a run's ``correct``, on the CPU at small sizes.
 
-* The control (the reference with one stated guarantee broken, put in the
-  program's place) differs from the reference on every cell's numbers.
+* The control (the reference with the stated guarantee that the
+  configuration names broken, put in the program's place) differs from
+  the reference on every cell's numbers.
 * A whole run of the harness, with the chip check skipped and the timed
   path broken underneath, reads ``correct`` false, for each fault a
   one-chip cache cell can have: a step that returns its state unchanged,
@@ -27,14 +28,20 @@ from repro.robust import events
 # window evicts as well as hits
 TINY_CONF = {"num_sets": 64, "segment_chunks": 2}
 TINY_MIX = {"keys": 1024, "items": 341, "batch": 128}
-CELLS = ["getput.read_only", "getput.served"]
+# evict's shape: 256 sets x 8 ways prepopulated with twice its capacity
+# (2^12 keys from 2^31, as the cell fills 2^24), then 2^13 new keys
+TINY_EVICT_CONF = {"num_sets": 256, "segment_chunks": 2}
+TINY_EVICT_MIX = {"keys": 1 << 13, "batch": 128,
+                  "fill": {"generator": "sequential", "start": 2**31, "keys": 1 << 12}}
+CELLS = ["getput.read_only", "getput.served", "evict.put_new"]
 WINDOW_NUMBERS = {"chunk_mismatches", "lane_mismatches", "state_mismatches"}
 
 
 def tiny_cell(name):
     cell = harness.resolve(name)
-    cell.config.update(TINY_CONF)
-    cell.mix.update(TINY_MIX)
+    evict = cell.config_name == "evict"
+    cell.config.update(TINY_EVICT_CONF if evict else TINY_CONF)
+    cell.mix.update(TINY_EVICT_MIX if evict else TINY_MIX)
     return cell
 
 
@@ -67,7 +74,8 @@ def test_control_differs_from_reference(name):
     client = harness.load_module("clients", mix["client"])
     ref = harness.load_module("refs", conf["reference"])
     keys = gen.key_array(4, mix)
-    fill, _ = harness.reference_fill(ref, conf, harness.fill_chunks(keys, mix["batch"]))
+    fill, _ = harness.reference_fill(
+        ref, conf, harness.fill_chunks(gen.fill_keys(4, mix, keys), mix["batch"]))
     requests = gen.Cycled(keys, mix["batch"], 24)
     st = harness.copy.deepcopy(fill)
     want = client.reference(ref, conf, st, requests)
@@ -217,3 +225,43 @@ def test_tiny_cell_evicts_in_its_window():
     evs = sum(int(ref.step(st, conf, c)[2].sum())
               for c in gen.cycled(keys, 0, 6 * mix["batch"]).reshape(6, -1))
     assert evs > 0
+
+
+def test_evict_cell_misses_and_evicts_in_its_window():
+    """A small cell of evict's shape through the whole harness: every
+    check reads 0, no window request hits, and nearly every one evicts,
+    since the fill left the sets full."""
+    cell = tiny_cell("evict.put_new")
+    r = run(cell, steps=4)
+    assert r["correct"], r["checks"]
+    assert all(c["value"] == 0 for c in r["checks"].values()), r["checks"]
+    assert r["window"]["hits"] == 0
+    assert r["window"]["evictions"] >= 0.95 * r["attempted"] > 0
+
+
+@pytest.mark.parametrize("control,bites", [("stale_recency", False),
+                                           ("mru_victims", True)])
+def test_evict_needs_a_control_on_its_victims(control, bites):
+    """Where nothing hits, hits that do not refresh recency change nothing;
+    victims taken most recent first change the state."""
+    cell = tiny_cell("evict.put_new")
+    conf, mix = {**cell.config, "control": control}, cell.mix
+    client = harness.load_module("clients", mix["client"])
+    ref = harness.load_module("refs", conf["reference"])
+    keys = gen.key_array(5, mix)
+    fill, _ = harness.reference_fill(
+        ref, conf, harness.fill_chunks(gen.fill_keys(5, mix, keys), mix["batch"]))
+    requests = gen.Cycled(keys, mix["batch"], 16)
+    st = harness.copy.deepcopy(fill)
+    want = client.reference(ref, conf, st, requests)
+    _, got = harness.check_window(client, ref, conf, fill, requests, want,
+                                  st.lanes(), control=True)
+    assert (got["state_mismatches"] > 0) == bites, got
+
+
+def test_unknown_control_is_an_error():
+    ref = harness.load_module("refs", "flat")
+    conf = {**harness.resolve("evict.put_new").config, "num_sets": 8,
+            "control": "imaginary"}
+    with pytest.raises(ValueError, match="no control 'imaginary'"):
+        ref.step(ref.init(conf), conf, np.arange(4, dtype=np.uint32))

@@ -1,5 +1,6 @@
-"""The benchmark's traffic generator and warm fill, on the CPU at small
+"""The benchmark's traffic generators and warm fill, on the CPU at small
 sizes."""
+import hashlib
 import json
 from pathlib import Path
 
@@ -101,3 +102,58 @@ def test_warm_fill_is_clean_and_matches_the_reference(sets, keys):
     assert int(evictions) == want_evs
     assert harness.slot_mismatches(harness.System.lanes(state), want.lanes()) == 0
     assert int(state.occupancy()) == int((want.keys != ref.EMPTY).sum()) > 0
+
+
+def test_sequential_gives_min_value_plus_i_and_wraps():
+    """``start`` = 2**31 is Java's ``Integer.MIN_VALUE`` as the cache's
+    uint32 key; the stream wraps mod 2**32 and ignores the seed."""
+    mix = {"generator": "sequential", "start": 2**31, "keys": 5}
+    k = gen.key_array(2**31 + 11, mix)
+    assert k.dtype == np.uint32
+    assert list(k.view(np.int32)) == [-2**31 + i for i in range(5)]
+    np.testing.assert_array_equal(k, gen.key_array(3, mix))
+    wrap = gen.key_array(0, {"generator": "sequential", "start": 2**32 - 2, "keys": 4})
+    assert list(wrap) == [2**32 - 2, 2**32 - 1, 0, 1]
+
+
+def test_unknown_generator_is_an_error():
+    with pytest.raises(ValueError, match="unknown generator 'uniform_imaginary'"):
+        gen.key_array(1, {"generator": "uniform_imaginary", "keys": 4})
+
+
+# sha256 of getput's key array (and so of its warm fill, the same array in
+# order) as the harness drew it before mixes could carry a fill stream
+GETPUT_DIGESTS = {
+    7: "b265c6f78c154ca0ce7b0a3e7cf6c66ec607e58b42feee8847c9fe73d410acd4",
+    2**31 + 5: "eb68007cdebf6014644b537c6c3addaa1a08a8372f0de7d6542b48f883607310",
+    3_000_000_017: "477d85cdf2b98bedf1ee9c1cb45463ff680877857f9b3e46ace9869ee9152b17",
+}
+
+
+@pytest.mark.parametrize("seed", sorted(GETPUT_DIGESTS))
+@pytest.mark.parametrize("name", ["read_only", "served"])
+def test_getput_keys_and_fill_are_pinned(name, seed):
+    """A mix without a ``fill`` stream fills with its own key array, every
+    key once, in order: getput's cells set up bit for bit as before."""
+    mix = _mix(name)
+    assert "fill" not in mix
+    keys = gen.key_array(seed, mix)
+    chunks = harness.fill_chunks(gen.fill_keys(seed, mix, keys), mix["batch"])
+    assert chunks.shape == (8, 4096) and chunks.dtype == np.uint32
+    assert hashlib.sha256(keys.tobytes()).hexdigest() == GETPUT_DIGESTS[seed]
+    assert hashlib.sha256(chunks.tobytes()).hexdigest() == GETPUT_DIGESTS[seed]
+
+
+def test_put_new_fills_with_its_own_stream():
+    """``put_new`` prepopulates with ``Integer.MIN_VALUE + i`` and then
+    sends keys from 0: the two streams share no key, and neither reaches
+    the empty-way sentinel that ``sanitize`` folds."""
+    mix = _mix("put_new")
+    keys = gen.key_array(2**31 + 11, mix)
+    fill = gen.fill_keys(2**31 + 11, mix, keys)
+    assert keys.size == 2**25 and fill.size == 2**24
+    assert fill[0] == 2**31 and fill[-1] == 2**31 + 2**24 - 1
+    assert keys[0] == 0 and keys[-1] == 2**25 - 1
+    assert int(keys.max()) < int(fill.min())
+    assert int(fill.max()) < 0xFFFFFFFF
+    assert harness.fill_chunks(fill, mix["batch"]).shape == (4096, 4096)

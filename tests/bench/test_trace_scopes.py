@@ -11,6 +11,11 @@ four 4096-request chunks and twenty served ``access`` batches on
 plane keeps only the replay program's HLO, and of that only what the
 reduction reads (``slim``).  It lives apart from ``fixtures/``, whose
 newest file ``trace_reduce.load`` reads.
+
+``traces/evict_replay.hlo.pb`` is the replay program's ``HloProto`` at
+``evict``'s geometry (2^20 sets x 8 ways, 64 chunks a call), taken from the
+``/host:metadata`` plane of a traced ``evict.put_new`` run on a TPU v5e
+and cut the same way.
 """
 import shutil
 import sys
@@ -27,6 +32,7 @@ from bench import trace_scopes  # noqa: E402
 HERE = Path(__file__).resolve().parent
 TIERED = HERE / "fixtures" / "kv_tiered_1s.xplane.pb"
 SCOPES = HERE / "traces" / "getput_scopes.xplane.pb"
+NODE_HLO = HERE / "traces" / "evict_replay.hlo.pb"
 
 
 # ---------------------------------------------------------------------------
@@ -155,6 +161,67 @@ def test_unnamed_scatter_is_booked_by_its_indices():
     ])
     assert trace_scopes.scatter_phases(proto) == {
         "fusion.83": "insert", "scatter.84": "insert"}
+
+
+def test_layout_ops_go_to_the_nearest_booked_scatter():
+    """Nameless ops tied to a booked scatter through other nameless ops
+    take its phase: the copy that lays a lane out for it, the loop that
+    lays its result back out and that loop's body.  A named op, a
+    constant and a parameter join nothing, so the scan's own named
+    bookkeeping and what lies beyond it stay ``other``."""
+    proto = _hlo_proto([
+        # the scatter fusion's computation
+        (2, 23, [_instruction(20, "p.0", "parameter", parameter=0),
+                 _instruction(21, "p.1", "parameter", parameter=1),
+                 _instruction(22, "p.2", "parameter", parameter=2),
+                 _instruction(23, "scatter.84", "scatter", operands=(20, 21, 22))]),
+        # the layout loop's body: a dynamic-update-slice of the result
+        (4, 42, [_instruction(40, "wide.param", "parameter"),
+                 _instruction(41, "get-tuple-element.1", "get-tuple-element",
+                              operands=(40,)),
+                 _instruction(43, "dynamic-slice.1", "dynamic-slice", operands=(41,)),
+                 _instruction(42, "dynamic-update-slice.1", "dynamic-update-slice",
+                              operands=(41, 43))]),
+        (1, 19, [_instruction(10, "lane", "parameter"),
+                 _instruction(11, "indices", "select",
+                              "jit(f)/kway.insert/select_n"),
+                 _instruction(12, "copy.1", "copy", operands=(10,)),
+                 _instruction(13, "fusion.83", "fusion", operands=(12, 11, 11),
+                              calls=(2,)),
+                 _instruction(14, "constant.1", "constant"),
+                 _instruction(15, "tuple.1", "tuple", operands=(14, 13)),
+                 _instruction(16, "while.1", "while", operands=(15,), calls=(4,)),
+                 _instruction(17, "dus.2", "dynamic-update-slice",
+                              "jit(f)/while/body/dynamic_update_slice",
+                              operands=(16, 14)),
+                 _instruction(18, "copy.2", "copy", operands=(17,)),
+                 _instruction(19, "copy.3", "copy", operands=(14,))]),
+    ])
+    assert trace_scopes.program_phases(proto) == {
+        "fusion.83": "insert", "scatter.84": "insert", "copy.1": "insert",
+        "tuple.1": "insert", "while.1": "insert", "get-tuple-element.1": "insert",
+        "dynamic-slice.1": "insert", "dynamic-update-slice.1": "insert"}
+
+
+def test_recorded_layout_loops_at_node_scale():
+    """At 2^20 sets the v5e lays each scattered lane back out with a
+    nameless ``while`` of ``dynamic-update-slice`` ops over the ways, and
+    lays each lane out for its scatter with a nameless copy.  They go with
+    their scatters: ``kway.hit``'s one loop and ``kway.insert``'s five,
+    every nameless copy and loop body op to one of those two phases, none
+    to ``kway.resolve``."""
+    proto = NODE_HLO.read_bytes()
+    ins, _ = trace_scopes._instructions(proto)
+    phases = trace_scopes.program_phases(proto)
+    loops = [i.name for i in ins.values() if i.opcode == "while" and not i.named]
+    assert sorted(phases[n] for n in loops) == ["hit"] + ["insert"] * 5
+    moved = [i.name for i in ins.values() if not i.named and i.opcode in (
+        "dynamic-update-slice", "dynamic-slice", "copy", "copy-start", "copy-done")
+]
+    assert len(moved) > 20
+    assert {phases.get(n) for n in moved} <= {"hit", "insert", None}
+    assert {phases[n] for n in moved if "update-slice" in n} == {"hit", "insert"}
+    assert "resolve" not in phases.values()
 
 
 # ---------------------------------------------------------------------------
