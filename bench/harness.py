@@ -1,22 +1,40 @@
 """The benchmark harness: one cell, one process, one result line.
 
 Everything specific to a cell is found by name: the configuration file
-named in ``BENCHMARK.json`` (``bench/configs/<config>.json``: the cache's
-geometry, backend and the name of its plain reference,
+named in ``BENCHMARK.json`` (``bench/configs/<config>.json``: the
+deployment's sizes, the name of its system under test,
+``bench/systems/<system>.py``, and of its plain reference,
 ``bench/refs/<reference>.py``), the traffic mix
 (``bench/traffic/<traffic>.json``: generator parameters and the name of
 its client, ``bench/clients/<client>.py``), and one reader per metric
 (``bench/metrics/<metric>.py``).  The code here is general: set up (draw
-the key array, fill the cache with the mix's fill stream or that array,
+the key array, fill the system with the mix's fill stream or that array,
 warm the timed call), measure for ``seconds``, then check what the timed
-path produced against the plain reference.
+path produced against the plain reference.  It imports nothing of the
+program but its compile cache and its degradation events.
 
-A client module gives ``SPANS``, ``build``, ``window``, ``requests``,
-``reference``, ``outputs``, ``compare`` and ``totals`` (see
-``clients/replay.py``); a reference module gives ``init(conf)``,
-``step(state, conf, keys, control=)`` for one batch and
-``run(state, conf, batches, control=)`` for a window's (``control``: the
-control the configuration names), and its state ``lanes()``.
+A system module gives a class ``System(conf, devs)`` (``devs``: the
+cell's devices) with
+
+* ``fill(chunks)``: the warm fill of host chunks [n, B], placed by the
+  system itself, in order into an empty state -> (state, evictions
+  during the fill);
+* ``replay(state, chunks, enabled)`` -> (hits, evictions, state);
+* ``access(state, keys, vals)`` -> (state, hit, value, evicted key,
+  evicted);
+* ``check(state)`` -> the invariant bits of a state, 0 when sound;
+* ``occupancy(state)`` -> entries held, and ``capacity``;
+* ``lanes(state)`` -> a dict from lane name to numpy array, scalars such
+  as the clock included, for ``slot_mismatches``.
+
+The state is whatever pytree the system's own calls take; the harness and
+the clients pass it along and never look inside it.  A client module
+gives ``SPANS``, ``build``, ``window``, ``requests``, ``reference``,
+``outputs``, ``compare`` and ``totals`` (see ``clients/replay.py``); a
+reference module gives ``init(conf)``, ``step(state, conf, keys,
+control=)`` for one batch and ``run(state, conf, batches, control=)`` for
+a window's (``control``: the control the configuration names), and its
+state ``lanes()``.
 """
 from __future__ import annotations
 
@@ -32,21 +50,15 @@ import time
 from pathlib import Path
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from bench import gen, trace_reduce
-from repro.core.backend import make_backend
-from repro.core.kway import KWayConfig
-from repro.core.policies import Policy
 from repro.launch import compile_cache
 from repro.robust import events
-from repro.robust.invariants import check_cache
 
 ROOT = Path(__file__).resolve().parents[1]
 BENCH = ROOT / "bench"
 OUT = ROOT / ".bench_out"
-LANES = ("keys", "fprint", "vals", "meta_a", "meta_b")
 # A traced run's window: collecting a trace of the replay loop takes the
 # profiler about fifteen times the window on a v5e, and a 16 s trace lost
 # events, so a traced run measures this much of the steady window.
@@ -91,8 +103,8 @@ def resolve(workload: str, spec: dict | None = None) -> Cell:
 
 
 def load_module(kind: str, name: str):
-    """``bench/<kind>/<name>.py``: a client, a reference or a metric
-    reader, found by its name."""
+    """``bench/<kind>/<name>.py``: a system, a client, a reference or a
+    metric reader, found by its name."""
     path = BENCH / kind / f"{name}.py"
     if not path.is_file():
         raise FileNotFoundError(f"no {kind} named {name!r} ({path})")
@@ -133,66 +145,34 @@ class CompileClock:
 
 
 # ---------------------------------------------------------------------------
-# the system under test, as the configuration names it
-# ---------------------------------------------------------------------------
-
-class System:
-    """The program's public API at the configuration's geometry."""
-
-    def __init__(self, conf: dict):
-        self.cfg = KWayConfig(num_sets=int(conf["num_sets"]),
-                              ways=int(conf["ways"]),
-                              policy=Policy.parse(conf["policy"]),
-                              seed=int(conf["seed"]))
-        self.backend = make_backend(conf["backend"], self.cfg)
-        self.filler = make_backend("jnp", self.cfg)
-
-    def fill(self, chunks):
-        """Get-or-insert ``chunks`` [n, B] in order into an empty cache
-        through the jnp backend's ``access``; one jitted scan.  Returns
-        (state, evictions during the fill)."""
-        @jax.jit
-        def go(state, chunks):
-            def step(st, kk):
-                st, _, _, _, ev = self.filler.access(st, kk, kk.astype(jnp.int32))
-                return st, jnp.sum(ev.astype(jnp.int32))
-            st, evs = jax.lax.scan(step, state, chunks)
-            return st, jnp.sum(evs)
-
-        return go(self.filler.init(), chunks)
-
-    def replay(self, state, chunks, enabled):
-        hits, evs, state, _ = self.backend.replay(state, chunks, enabled)
-        return hits, evs, state
-
-    def access(self, state, keys, vals):
-        return self.backend.access(state, keys, vals)
-
-    def check(self, state) -> int:
-        return int(jax.device_get(check_cache(self.cfg, state,
-                                              vals_mode="key").bits))
-
-    @staticmethod
-    def lanes(state) -> dict:
-        got = jax.device_get({n: getattr(state, n) for n in LANES + ("clock",)})
-        return {n: np.asarray(v) for n, v in got.items()}
-
-
-# ---------------------------------------------------------------------------
 # comparison
 # ---------------------------------------------------------------------------
 
 def slot_mismatches(got: dict, want: dict) -> int:
-    """Slots where any lane differs, plus one if the clocks differ (both
-    sides as ``System.lanes`` / a reference state's ``lanes()`` give
-    them)."""
-    bad = np.zeros(want["keys"].shape, bool)
-    for name in LANES:
-        g, w = got[name], want[name]
-        if g.shape != w.shape:
-            return int(w.size) + 1
-        bad |= g.view(w.dtype) != w
-    return int(bad.sum()) + int(int(got["clock"]) != int(want["clock"]))
+    """How far a system's ``lanes()`` lie from the reference's, over the
+    lanes the reference names: the slots where any lane of one entry per
+    slot (the shape of ``keys``) differs, plus every element that differs
+    in each other lane (the clock).  A lane missing on the system's side,
+    or of another shape, counts every element it has."""
+    slots = np.shape(want["keys"])
+    bad = np.zeros(slots, bool)
+    other = 0
+    for name, w in want.items():
+        w, g = np.asarray(w), got.get(name)
+        if g is None or np.shape(g) != w.shape:
+            if w.shape == slots:
+                bad[...] = True
+            else:
+                other += w.size
+            continue
+        g = np.asarray(g)
+        if g.dtype.itemsize == w.dtype.itemsize:
+            g = g.view(w.dtype)
+        if w.shape == slots:
+            bad |= g != w
+        else:
+            other += int(np.sum(g != w))
+    return int(bad.sum()) + other
 
 
 def fill_chunks(keys: np.ndarray, batch: int) -> np.ndarray:
@@ -273,7 +253,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     conf, mix = cell.config, cell.mix
     client = load_module("clients", mix["client"])
     ref = load_module("refs", conf["reference"])
-    system = System(conf)
+    system = load_module("systems", conf["system"]).System(conf, devs)
     batch = int(mix["batch"])
 
     # ---- set-up: key array, warm fill, warm-up of the timed call ----------
@@ -282,11 +262,10 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     traffic = client.build(conf, mix, keys, devs[0])
     fchunks = fill_chunks(gen.fill_keys(seed, mix, keys), batch)
     marks.append(("traffic", time.perf_counter()))
-    filled, fill_evs = jax.block_until_ready(
-        system.fill(jax.device_put(fchunks, devs[0])))
+    filled, fill_evs = jax.block_until_ready(system.fill(fchunks))
     marks.append(("fill", time.perf_counter()))
     fill_bits = system.check(filled)
-    occupancy = int(jax.device_get(filled.occupancy()))
+    occupancy = system.occupancy(filled)
     marks.append(("check_cache", time.perf_counter()))
     # warm-up: the window's first call (on the filled state) and a later
     # one (on a state the program returned), whose inputs may differ in
@@ -327,7 +306,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0) for d in devs)
 
     # ---- free the program's state, then check against the reference --------
-    lanes, fill_lanes = System.lanes(state), System.lanes(filled)
+    lanes, fill_lanes = system.lanes(state), system.lanes(filled)
     outputs = client.outputs(got)
     requests = client.requests(traffic, steps)
     fill_evs = int(fill_evs)
@@ -352,7 +331,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
 
     log(f"{cell.name}: seed {seed}, {steps} steps of {per_step} "
         f"requests in {window_s:.4f} s; hit ratio {hits / max(attempted, 1):.6f}, "
-        f"{evs} evictions; fill occupancy {occupancy}/{system.cfg.capacity}, "
+        f"{evs} evictions; fill occupancy {occupancy}/{system.capacity}, "
         f"{fill_evs} fill evictions; set-up {setup_s:.3f} s "
         f"({clock.seconds:.3f} s compiling, {clock.cache_hits} programs read back; "
         + ", ".join(f"{n} {t - t0:.3f} s" for (_, t0), (n, t)

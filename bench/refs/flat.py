@@ -58,7 +58,8 @@ class FlatState:
         return np.where(self.keys == EMPTY, np.uint32(0), fp)
 
     def lanes(self) -> dict:
-        """The five lanes and the clock, as ``System.lanes`` gives them."""
+        """The five lanes and the clock, as the ``kway`` system's ``lanes()``
+        gives them."""
         return {"keys": self.keys, "fprint": self.fprint, "vals": self.vals,
                 "meta_a": self.meta_a, "meta_b": self.meta_b,
                 "clock": self.clock}

@@ -11,9 +11,15 @@
 * Each number that says whether a run measured its cell (the warm fill,
   compiles and demotions in the window) reads above its limit under its
   own fault.
+* A system under test is found by its configuration's name, and its
+  ``lanes()`` are compared over the lanes the reference names: a
+  test-only system with one more state leaf (``fixtures/systems/``) runs
+  through the unedited harness, and a fault in that leaf alone fails.
 """
+import ast
 import dataclasses
 import time
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -33,8 +39,10 @@ TINY_MIX = {"keys": 1024, "items": 341, "batch": 128}
 TINY_EVICT_CONF = {"num_sets": 256, "segment_chunks": 2}
 TINY_EVICT_MIX = {"keys": 1 << 13, "batch": 128,
                   "fill": {"generator": "sequential", "start": 2**31, "keys": 1 << 12}}
-CELLS = ["getput.read_only", "getput.served", "evict.put_new"]
+CELLS = ["getput.read_only", "getput.served", "evict.put_new", "evict.served"]
 WINDOW_NUMBERS = {"chunk_mismatches", "lane_mismatches", "state_mismatches"}
+KWAY = harness.load_module("systems", "kway").System
+FIXTURES = Path(__file__).with_name("fixtures")
 
 
 def tiny_cell(name):
@@ -83,6 +91,9 @@ def test_control_differs_from_reference(name):
                                             want, st.lanes(), control=True)
     assert not any(numbers.values()), numbers
     assert control["state_mismatches"] > 0
+    # a served cell's control also answers differently: other victims
+    # (evict) or other hits (getput) than the reference's
+    assert control.get("lane_mismatches", 1) > 0, control
 
 
 @pytest.mark.parametrize("sets", [64, 4096])          # evicting; hit-only
@@ -114,6 +125,21 @@ def test_unknown_client_or_reference_is_an_error():
         harness.load_module("clients", "open_loop_imaginary")
     with pytest.raises(FileNotFoundError, match="no refs named"):
         harness.load_module("refs", "tiered_imaginary")
+    with pytest.raises(FileNotFoundError, match="no systems named"):
+        harness.load_module("systems", "tiered_imaginary")
+
+
+@pytest.mark.parametrize("system,error", [(None, KeyError),
+                                          ("tiered_imaginary", FileNotFoundError)])
+def test_configuration_must_name_an_existing_system(system, error):
+    """No system is taken by default: a configuration without the key, or
+    naming a system that has no module, stops the run before it sets up."""
+    cell = tiny_cell("getput.read_only")
+    del cell.config["system"]
+    if system:
+        cell.config["system"] = system
+    with pytest.raises(error, match="system"):
+        run(cell)
 
 
 def _replay_fault(kind):
@@ -165,7 +191,7 @@ def test_broken_timed_path_reads_incorrect(name, kind, monkeypatch):
 
 
 def _fill_fault(kind):
-    inner = harness.System.fill
+    inner = KWAY.fill
 
     def fill(self, chunks):
         state, evs = inner(self, chunks)
@@ -206,7 +232,7 @@ def test_each_validity_number_reads_its_fault(kind, number, monkeypatch):
     above their limit 0 under the fault they exist for."""
     cell = tiny_cell("getput.read_only")
     if kind.startswith(("fill", "fingerprint")):
-        monkeypatch.setattr(harness.System, "fill", _fill_fault(kind))
+        monkeypatch.setattr(KWAY, "fill", _fill_fault(kind))
     else:
         monkeypatch.setattr(backend_mod.CacheBackend, "replay",
                             _replay_side_effect(kind))
@@ -265,3 +291,78 @@ def test_unknown_control_is_an_error():
             "control": "imaginary"}
     with pytest.raises(ValueError, match="no control 'imaginary'"):
         ref.step(ref.init(conf), conf, np.arange(4, dtype=np.uint32))
+
+
+def test_slot_mismatches_counts_slots_and_other_elements():
+    """Lanes of one entry per slot count the slots where any differs; any
+    other lane counts its differing elements; a lane missing or of another
+    shape on the system's side counts every element it has."""
+    want = {"keys": np.zeros((4, 2), np.uint32), "vals": np.zeros((4, 2), np.int32),
+            "clock": 10, "sketch": np.zeros(6, np.int32)}
+    got = {n: np.array(v, copy=True) for n, v in want.items()}
+    assert harness.slot_mismatches(got, want) == 0
+    got["keys"][0, 0] = 1
+    got["vals"][0, 0] = 1                     # the same slot as the key
+    got["vals"][3, 0] = 1
+    assert harness.slot_mismatches(got, want) == 2
+    got["clock"] = np.int32(11)
+    got["sketch"][:2] = 5
+    assert harness.slot_mismatches(got, want) == 2 + 1 + 2
+    got["extra"] = np.ones(3)                 # a lane the reference does not name
+    assert harness.slot_mismatches(got, want) == 5
+    del got["sketch"]
+    assert harness.slot_mismatches(got, want) == 2 + 1 + 6
+    got["vals"] = np.zeros((2, 4), np.int32)
+    assert harness.slot_mismatches(got, want) == 8 + 1 + 6
+
+
+@pytest.fixture
+def fixture_bench(tmp_path, monkeypatch):
+    """The benchmark's tree by links, with the test-only systems and
+    references beside the real ones: the harness, unedited, finds them by
+    the configuration's names."""
+    root = tmp_path / "bench"
+    root.mkdir()
+    for kind in ("clients", "metrics", "traffic"):
+        (root / kind).symlink_to(harness.BENCH / kind)
+    for kind in ("systems", "refs"):
+        (root / kind).mkdir()
+        for src in [*(harness.BENCH / kind).glob("*.py"), *(FIXTURES / kind).glob("*.py")]:
+            (root / kind / src.name).symlink_to(src)
+    monkeypatch.setattr(harness, "BENCH", root)
+
+
+def counted_cell(name):
+    cell = tiny_cell(name)
+    cell.config.update(system="counted", reference="counted")
+    return cell
+
+
+@pytest.mark.parametrize("name", ["getput.read_only", "getput.served"])
+def test_a_new_system_runs_through_the_harness(name, fixture_bench):
+    r = run(counted_cell(name))
+    assert r["correct"], r["checks"]
+
+
+@pytest.mark.parametrize("name", ["getput.read_only", "getput.served"])
+def test_a_fault_in_a_system_leaf_alone_fails(name, fixture_bench, monkeypatch):
+    """The served count gains one more on each window call; every key,
+    value and answer is right."""
+    counted = harness.load_module("systems", "counted")
+    monkeypatch.setattr(counted, "_served",
+                        jax.jit(lambda n, enabled: n + jnp.sum(enabled, dtype=jnp.int32) + 1))
+    r = run(counted_cell(name))
+    assert not r["correct"]
+    assert failing(r) == ["state_mismatches"], r["checks"]
+    assert r["checks"]["state_mismatches"]["value"] == 1
+
+
+def test_harness_imports_nothing_of_the_cache():
+    """The system under test lives in ``bench/systems/``; the harness keeps
+    of the program only its compile cache and its degradation events."""
+    tree = ast.parse(Path(harness.__file__).read_text())
+    names = {a.name for n in ast.walk(tree) if isinstance(n, ast.Import) for a in n.names}
+    names |= {f"{n.module}.{a.name}" for n in ast.walk(tree)
+              if isinstance(n, ast.ImportFrom) for a in n.names}
+    assert {n for n in names if n.startswith("repro")} == {
+        "repro.launch.compile_cache", "repro.robust.events"}

@@ -92,16 +92,16 @@ def test_cycled_wraps_around():
 def test_warm_fill_is_clean_and_matches_the_reference(sets, keys):
     conf = {"backend": "jnp", "num_sets": sets, "ways": 8, "policy": "LRU",
             "seed": 0x51CA}
-    system = harness.System(conf)
+    system = harness.load_module("systems", "kway").System(conf, jax.devices())
     ks = gen.key_array(9, _mix(keys=keys, items=keys // 3))
     chunks = harness.fill_chunks(ks, 256)
-    state, evictions = system.fill(jax.device_put(chunks))
+    state, evictions = system.fill(chunks)
     assert system.check(state) == 0
     ref = harness.load_module("refs", "flat")
     want, want_evs = harness.reference_fill(ref, conf, chunks)
     assert int(evictions) == want_evs
-    assert harness.slot_mismatches(harness.System.lanes(state), want.lanes()) == 0
-    assert int(state.occupancy()) == int((want.keys != ref.EMPTY).sum()) > 0
+    assert harness.slot_mismatches(system.lanes(state), want.lanes()) == 0
+    assert system.occupancy(state) == int((want.keys != ref.EMPTY).sum()) > 0
 
 
 def test_sequential_gives_min_value_plus_i_and_wraps():
@@ -157,3 +157,13 @@ def test_put_new_fills_with_its_own_stream():
     assert int(keys.max()) < int(fill.min())
     assert int(fill.max()) < 0xFFFFFFFF
     assert harness.fill_chunks(fill, mix["batch"]).shape == (4096, 4096)
+
+
+def test_put_new_served_sends_put_new_keys_through_the_served_client():
+    """``evict.served`` fills and sends exactly what ``evict.put_new`` does,
+    batch by batch through ``access`` in place of ``replay``."""
+    served, replayed = _mix("put_new_served"), _mix("put_new")
+    assert served["client"] == "closed_loop" and replayed["client"] == "replay"
+    keep = {"about", "client"}
+    assert ({k: v for k, v in served.items() if k not in keep}
+            == {k: v for k, v in replayed.items() if k not in keep})
