@@ -192,6 +192,68 @@ def insert_deadlines(clock, b: int, ttls: Optional[jnp.ndarray]):
 
 
 # ---------------------------------------------------------------------------
+# the slot view
+#
+# Every scatter into a state lane, and every gather of single (set, way)
+# slots, goes through a flat [S*k] view of the lane.  On the TPU an [S, k]
+# 32-bit lane with 8 ways is carried ways-major in 8x128 tiles
+# ({0,1:T(8,128)}), while a scatter writes only a linear 1-D array: a
+# scatter into the [S, k] lane itself makes XLA copy the lane to linear form
+# and lay it back out, by a loop over the whole lane, after every scatter.
+# A view in the tile's own memory order is a bitcast of the carried layout,
+# so the lanes are written in place.  A set's whole row ([B, k]: the probe,
+# the victims' scores) is still gathered from the [S, k] lane: through the
+# view it is B*k single-slot reads, which on a v5e took 15-30 times as long
+# as the row gather.
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class _Slots:
+    """The flat slot view of ``[num_sets, ways]`` lanes: the bijection
+    ``slot(set, way)`` onto ``[0, S*k)`` and the reshapes between a lane
+    and its view.
+
+    With 8 ways over a multiple of 128 sets the order is the chip's tile
+    order, 128 sets of one way after another:
+    ``slot = (set >> 7 << 10) | (way << 7) | (set & 127)``.  Any other
+    geometry takes ``set * k + way``: as exact, but the chip then copies
+    the lane into and out of the view.  ``drop`` (``S * k``) is one past
+    the last slot, where a write is dropped.
+    """
+
+    num_sets: int
+    ways: int
+
+    @property
+    def tiled(self) -> bool:
+        return self.ways == 8 and self.num_sets % 128 == 0
+
+    @property
+    def drop(self) -> int:
+        return self.num_sets * self.ways
+
+    def slot(self, sets, ways):
+        """Slot of each (set, way) pair (int32 arrays that broadcast)."""
+        if self.tiled:
+            return ((sets >> 7) << 10) | (ways << 7) | (sets & 127)
+        return sets * jnp.int32(self.ways) + ways
+
+    def flat(self, lane):
+        """[S, k] lane -> its [S*k] view."""
+        s, k = self.num_sets, self.ways
+        if self.tiled:
+            return lane.reshape(s // 128, 128, k).transpose(0, 2, 1).reshape(-1)
+        return lane.reshape(-1)
+
+    def lane(self, flat):
+        """[S*k] view -> its [S, k] lane."""
+        s, k = self.num_sets, self.ways
+        if self.tiled:
+            return flat.reshape(s // 128, k, 128).transpose(0, 2, 1).reshape(s, k)
+        return flat.reshape(s, k)
+
+
+# ---------------------------------------------------------------------------
 # probing
 # ---------------------------------------------------------------------------
 
@@ -335,8 +397,53 @@ def _resolve_inserts(cfg: KWayConfig, qkeys, sets, eligible, order):
 # Probing (locate the key / rank the victims) and applying (scatter the new
 # contents) are split so alternative probe substrates — the pure-jnp path
 # below, the Pallas kernel in kernels/kway_probe.py — feed one common apply
-# and stay bit-identical (DESIGN.md §3).
+# and stay bit-identical (DESIGN.md §3).  The applies write the lanes by two
+# helpers on slot views, one per phase, so the three cannot drift.
 # ---------------------------------------------------------------------------
+
+def _write_hits(cfg: KWayConfig, meta_a, meta_b, slot, hit, times):
+    """The hit phase's lane write: each hit's ``on_hit`` transition of
+    ``meta_a`` at its ``slot`` (slot views in, ``meta_a``'s view out).
+
+    Duplicate slots in one batch: LFU/Hyperbolic counts accumulate (two
+    hits = +2, a scatter-add of the deltas), LRU takes the latest stamp (a
+    scatter-max).  ``on_hit`` is the identity for FIFO/RANDOM and keeps
+    ``meta_b`` for every policy, so neither is ever written.
+    """
+    if cfg.policy in (Policy.FIFO, Policy.RANDOM):
+        return meta_a
+    ma_hit = meta_a[slot]
+    new_a, _ = on_hit(cfg.policy, ma_hit, meta_b[slot], times)
+    if cfg.policy in (Policy.LFU, Policy.HYPERBOLIC):
+        return meta_a.at[slot].add(jnp.where(hit, new_a - ma_hit, 0),
+                                   mode="drop")
+    return meta_a.at[slot].max(jnp.where(hit, new_a, -(2**31 - 1)),
+                               mode="drop")
+
+
+def _write_entries(v: _Slots, state: KWayState, meta_a, slot_w, qkeys, qvals,
+                   new_a, new_b, deadline, clock) -> KWayState:
+    """The insert phase's lane writes: one packed scatter pass, a single
+    slot index per request shared by every lane of ``state`` (``meta_a``
+    given as the slot view the hit phase left).  Requests that land nowhere
+    carry ``v.drop``, past the last slot, where the write is dropped — a
+    parking slot such as 0 is not a no-op, since a duplicate index lets the
+    parked write clobber an active request's insert there.  Returns the
+    state with its lanes back in ``[S, k]``."""
+    def put(flat, x):
+        return v.lane(flat.at[slot_w].set(x, mode="drop"))
+
+    return KWayState(
+        keys=put(v.flat(state.keys), qkeys),
+        fprint=put(v.flat(state.fprint), hashing.fingerprint(qkeys)),
+        vals=put(v.flat(state.vals), qvals),
+        meta_a=put(meta_a, new_a),
+        meta_b=put(v.flat(state.meta_b), new_b),
+        clock=clock,
+        expiry=(None if state.expiry is None
+                else put(v.flat(state.expiry), deadline)),
+    )
+
 
 @partial(jax.jit, static_argnums=0)
 def apply_get(cfg: KWayConfig, state: KWayState, sets, hit, way):
@@ -346,26 +453,15 @@ def apply_get(cfg: KWayConfig, state: KWayState, sets, hit, way):
     """
     b = sets.shape[0]
     times, clock = _batch_times(state, b)
+    v = _Slots(*state.keys.shape)
 
     with jax.named_scope("kway.hit"):
-        ma_hit = state.meta_a[sets, way]
-        mb_hit = state.meta_b[sets, way]
-        new_a, new_b = on_hit(cfg.policy, ma_hit, mb_hit, times)
-        # Duplicate (set, way) pairs in one batch: LFU/Hyperbolic counts must
-        # accumulate (two hits = +2), LRU must take the max timestamp.  Scatter-add
-        # the deltas instead of scatter-set.
-        da = jnp.where(hit, new_a - ma_hit, 0)
-        if cfg.policy in (Policy.LFU, Policy.HYPERBOLIC):
-            meta_a = state.meta_a.at[sets, way].add(da)
-        else:
-            meta_a = state.meta_a.at[sets, way].max(
-                jnp.where(hit, new_a, -(2**31 - 1)))
-        db = jnp.where(hit, new_b - mb_hit, 0)
-        meta_b = state.meta_b.at[sets, way].add(db)
-
-        vals = jnp.where(hit, state.vals[sets, way], -1)
+        slot = v.slot(sets, way)
+        meta_a = _write_hits(cfg, v.flat(state.meta_a), v.flat(state.meta_b),
+                             slot, hit, times)
+        vals = jnp.where(hit, v.flat(state.vals)[slot], -1)
     return (
-        dataclasses.replace(state, meta_a=meta_a, meta_b=meta_b, clock=clock),
+        dataclasses.replace(state, meta_a=v.lane(meta_a), clock=clock),
         hit,
         vals,
     )
@@ -408,11 +504,12 @@ def apply_put(
     is_insert, way_victim = _resolve_inserts(
         cfg, qkeys, sets, (~present) & admit & enabled, order)
 
+    v = _Slots(*state.keys.shape)
     with jax.named_scope("kway.insert"):
         way = jnp.where(present, way_present, way_victim)
         active = present | is_insert
 
-        evicted_keys = state.keys[sets, way_victim]
+        evicted_keys = v.flat(state.keys)[v.slot(sets, way_victim)]
         evicted_valid = is_insert & (evicted_keys != EMPTY_KEY)
 
         ia, ib = on_insert(cfg.policy, times, (b,))
@@ -420,34 +517,23 @@ def apply_put(
         # For present keys: overwrite value, metadata takes the on_hit
         # transition (a put of an existing key counts as an access — paper
         # Algorithm 3 line 6).
-        ha, hb = on_hit(cfg.policy, state.meta_a[sets, way],
-                        state.meta_b[sets, way], times)
+        slot = v.slot(sets, way)
+        meta_a = v.flat(state.meta_a)
+        ha, hb = on_hit(cfg.policy, meta_a[slot], v.flat(state.meta_b)[slot],
+                        times)
         new_a = jnp.where(present, ha, ia)
         new_b = jnp.where(present, hb, ib)
 
         if slot_value:
             qvals = (sets * jnp.int32(cfg.ways) + way).astype(jnp.int32)
 
-        # Inactive lanes scatter to an out-of-bounds set index — JAX drops
-        # out-of-bounds scatter updates, making them true no-ops.  (Routing them
-        # to slot (0,0) with its "current" value is NOT a no-op: a duplicate
-        # scatter index lets the stale inactive write clobber an active lane's
-        # genuine insert into (0,0).)
-        sets_w = jnp.where(active, sets, jnp.int32(cfg.num_sets))
-        way_w = jnp.where(active, way, 0)
+        # put has no TTL argument (TTL riding is the fused access path's
+        # job); an expiry lane, when present, is carried with landing lanes
+        # marked never-expiring so the structural invariants stay intact.
+        new_state = _write_entries(
+            v, state, meta_a, jnp.where(active, slot, v.drop), qkeys, qvals,
+            new_a, new_b, jnp.int32(NO_EXPIRY), clock)
 
-        keys = state.keys.at[sets_w, way_w].set(qkeys)
-        fpr = state.fprint.at[sets_w, way_w].set(hashing.fingerprint(qkeys))
-        vals = state.vals.at[sets_w, way_w].set(qvals)
-        meta_a = state.meta_a.at[sets_w, way_w].set(new_a)
-        meta_b = state.meta_b.at[sets_w, way_w].set(new_b)
-        # put has no TTL argument (TTL riding is the fused access path's job);
-        # an expiry lane, when present, is carried with landing lanes marked
-        # never-expiring so the structural invariants stay intact.
-        expiry = (None if state.expiry is None
-                  else state.expiry.at[sets_w, way_w].set(jnp.int32(NO_EXPIRY)))
-
-    new_state = KWayState(keys, fpr, vals, meta_a, meta_b, clock, expiry)
     slot_sets = jnp.where(active, sets, -1)
     slot_ways = jnp.where(active, way, -1)
     return new_state, evicted_keys, evicted_valid, slot_sets, slot_ways
@@ -546,8 +632,11 @@ def apply_access(
     Scatter economy vs the two-phase applies (7 scatters per step): the hit
     phase scatters only ``meta_a`` (``on_hit`` keeps ``meta_b`` for every
     policy, and is the identity for FIFO/RANDOM), and the insert phase is
-    one packed scatter pass — a single (set, way) index pair shared by all
-    five state lanes.
+    one packed scatter pass — a single slot index shared by all five state
+    lanes (six with the expiry lane).  Every gather and scatter reads or
+    writes a lane through its flat slot view (``_Slots``), in the chip's
+    tile order where the geometry allows, so on the TPU a lane is written
+    in place rather than laid out anew after each scatter.
 
     ``slot_value`` is the cache-as-allocator mode (the paged-KV engine's
     page-id convention): inserts store ``set * ways + way`` — the landing
@@ -578,22 +667,16 @@ def apply_access(
     clock = state.clock + jnp.int32(2 * b)
 
     hit = hit_raw if enabled is None else (hit_raw & enabled)
+    v = _Slots(*state.keys.shape)
+    keys_f, meta_b_f, vals_f = (v.flat(state.keys), v.flat(state.meta_b),
+                                v.flat(state.vals))
 
     # ---- hit phase (apply_get semantics at times t+i) --------------------
     with jax.named_scope("kway.hit"):
-        ma_hit = state.meta_a[sets, way]
-        new_a, _ = on_hit(cfg.policy, ma_hit, state.meta_b[sets, way], times_get)
-        if cfg.policy in (Policy.LFU, Policy.HYPERBOLIC):
-            meta_a1 = state.meta_a.at[sets, way].add(
-                jnp.where(hit, new_a - ma_hit, 0))
-        elif cfg.policy in (Policy.FIFO, Policy.RANDOM):
-            meta_a1 = state.meta_a          # on_hit is the identity here
-        else:
-            meta_a1 = state.meta_a.at[sets, way].max(
-                jnp.where(hit, new_a, -(2**31 - 1)))
-        # on_hit keeps meta_b for every policy, so the apply_get meta_b
-        # scatter-add is always adding zero — elided.
-        vals_out = jnp.where(hit, state.vals[sets, way], qvals)
+        slot_hit = v.slot(sets, way)
+        meta_a1 = _write_hits(cfg, v.flat(state.meta_a), meta_b_f, slot_hit,
+                              hit, times_get)
+        vals_out = jnp.where(hit, vals_f[slot_hit], qvals)
 
     # ---- miss phase (apply_put semantics at times t+B+i) -----------------
     # In the composition, every lane the put phase sees is either disabled
@@ -604,44 +687,32 @@ def apply_access(
     if enabled is None:
         enabled = jnp.ones((b,), jnp.bool_)
     if order is None:
-        order = _victim_order_arrays(
-            cfg, state.keys, meta_a1, state.meta_b, sets, set_keys, times_put)
+        order = _victim_order_arrays(cfg, state.keys, v.lane(meta_a1),
+                                     state.meta_b, sets, set_keys, times_put)
 
     is_insert, way_victim = _resolve_inserts(
         cfg, qkeys, sets, (~hit_raw) & admit & enabled, order)
 
     with jax.named_scope("kway.insert"):
-        evicted_keys = state.keys[sets, way_victim]
+        slot_ins = v.slot(sets, way_victim)
+        evicted_keys = keys_f[slot_ins]
         evicted_valid = is_insert & (evicted_keys != EMPTY_KEY)
 
         if slot_value:
             slot_id = (sets * jnp.int32(cfg.ways) + way_victim).astype(jnp.int32)
             qvals = slot_id                      # stored payload for inserts
             vals_out = jnp.where(
-                hit, state.vals[sets, way],
+                hit, vals_f[slot_hit],
                 jnp.where(is_insert, slot_id, jnp.int32(-1)))
 
         ia, ib = on_insert(cfg.policy, times_put, (b,))
+        ie = insert_deadlines(state.clock, b, ttls)
+        if ie is None:           # lane present, no TTLs: never-expiring
+            ie = jnp.int32(NO_EXPIRY)
+        new_state = _write_entries(
+            v, state, meta_a1, jnp.where(is_insert, slot_ins, v.drop), qkeys,
+            qvals, ia, ib, ie, clock)
 
-        # One packed scatter pass: the (set, way) index pair is computed once and
-        # shared by all five lanes.  Inactive lanes route out of bounds (dropped
-        # by JAX) — see apply_put for why slot (0,0) is not a safe parking spot.
-        sets_w = jnp.where(is_insert, sets, jnp.int32(cfg.num_sets))
-        way_w = jnp.where(is_insert, way_victim, 0)
-
-        keys = state.keys.at[sets_w, way_w].set(qkeys)
-        fpr = state.fprint.at[sets_w, way_w].set(hashing.fingerprint(qkeys))
-        vals = state.vals.at[sets_w, way_w].set(qvals)
-        meta_a = meta_a1.at[sets_w, way_w].set(ia)
-        meta_b = state.meta_b.at[sets_w, way_w].set(ib)
-        expiry = state.expiry
-        if expiry is not None:
-            ie = insert_deadlines(state.clock, b, ttls)
-            if ie is None:           # lane present, no TTLs: never-expiring
-                ie = jnp.full((b,), NO_EXPIRY, jnp.int32)
-            expiry = expiry.at[sets_w, way_w].set(ie)
-
-    new_state = KWayState(keys, fpr, vals, meta_a, meta_b, clock, expiry)
     return new_state, hit, vals_out, evicted_keys, evicted_valid
 
 

@@ -9,7 +9,9 @@ VMEM checks of the pallas backend are compiled at their largest admitted
 size, which ties ``RESIDENT_VMEM_BUDGET`` and ``VMEM_LIMIT`` to what
 the compiler accepts.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -19,7 +21,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.core import hierarchy as H
 from repro.core.admission import for_capacity
 from repro.core.backend import make_backend
-from repro.core.kway import KWayConfig
+from repro.core.kway import KWayConfig, KWayState
 from repro.core.policies import Policy
 from repro.kernels import kway_probe as kp
 from repro.kernels import replay as rp
@@ -138,3 +140,55 @@ def test_paged_attention_compiles(sds):
              sds((heads, pages, page, d), jnp.bfloat16),
              sds((heads, pages, page, d), jnp.bfloat16),
              sds((b, pps)), sds((b,)))
+
+
+def _shapes(line: str) -> list[str]:
+    """The array shapes, with their layouts, of an instruction's result (a
+    tuple's in order), memory spaces dropped."""
+    result = line.split(" = ", 1)[1].rsplit("(%", 1)[0]
+    return [re.sub(r"S\(\d+\)", "", m)
+            for m in re.findall(r"\w+\[[\d,]*\]\{[^}]*\}", result)]
+
+
+def test_jnp_replay_writes_lanes_in_place(sds):
+    """A 64-chunk scan over ``JnpBackend.access`` at 2^20 sets x 8 ways, the
+    node-scale geometry: every lane scatter goes through the lanes' slot
+    view, in the order of the lanes' tiles, so the program is
+    the scan's one loop, no ``dynamic-update-slice`` lays out a lane, and
+    no copy changes a lane's layout (the view is a bitcast)."""
+    sets, ways, steps, b = 1 << 20, 8, 64, 4096
+    be = make_backend("jnp", KWayConfig(num_sets=sets, ways=ways))
+
+    def replay(state, chunks, enabled):
+        def step(st, xs):
+            st, hit, _, _, ev = be.access(st, xs[0], xs[0].astype(jnp.int32),
+                                          None, xs[1])
+            return st, (jnp.sum(hit), jnp.sum(ev))
+        return jax.lax.scan(step, state, (chunks, enabled))
+
+    lanes = _lanes(sds, sets, ways)
+    state = KWayState(*lanes, clock=sds(()))
+    hlo = jax.jit(replay).lower(
+        state, sds((steps, b), jnp.uint32), sds((steps, b), jnp.bool_)
+    ).compile().as_text()
+
+    loops = re.findall(r"\bwhile\(", hlo)
+    assert len(loops) == 1, len(loops)
+
+    def lane_sized(line):
+        return sets * ways in [math.prod(int(d) for d in dims.split(",") if d)
+                               for dims in re.findall(r"\[([\d,]*)\]",
+                                                      " ".join(_shapes(line)))]
+
+    assert not [ln for ln in hlo.splitlines()
+                if "dynamic-update-slice(" in ln and lane_sized(ln)]
+    lines = [ln for ln in hlo.splitlines() if " = " in ln]
+    shapes = {ln.split(" = ", 1)[0].split()[-1]: _shapes(ln) for ln in lines}
+    for ln in lines:
+        op = re.search(r" (copy|copy-start)\(%([\w.\-]+)\)", ln)
+        if op and lane_sized(ln):
+            if op.group(1) == "copy":
+                dst, src = _shapes(ln)[0], shapes["%" + op.group(2)][0]
+            else:                       # (destination, source, context)
+                dst, src = _shapes(ln)[:2]
+            assert dst == src, ln
