@@ -10,7 +10,10 @@ Public API:
     Set sharding       — sharded.{ShardedConfig, ShardedCache} (DESIGN.md §5)
     Request routing    — router.{route, bucket, unscatter}: the device-
                          resident owner router behind sharding (DESIGN.md §9)
-    simulate.replay    — jitted hit-ratio trace replay
+    CacheBackend.replay — the one chunked replay loop on one device; the
+                         pallas backend picks the megakernel from the VMEM
+                         fit, the ref oracle loops on the host
+    simulate.replay    — hit-ratio front end over CacheBackend.replay
     traces.generate    — synthetic workload families
 """
 from repro.core.backend import (  # noqa: F401

@@ -12,7 +12,9 @@ This module gives them one contract (DESIGN.md §3):
     state, ek, ev, slot_sets, slot_ways = backend.put(state, keys, vals)
     state, hit, vals, ek, ev = backend.access(state, keys, vals)
     vkeys, vvalid = backend.peek_victims(state, keys)
-    hits, evs, state, sketch = backend.replay(state, chunks, enabled)
+    hits, evs, state, sketch = backend.replay(
+        state, chunks, enabled, tinylfu=None, sketch=None, hierarchy=None,
+        ttls=None, two_phase=False)
 
 All backends are functional (state in, state out) over the same ``KWayState``
 pytree, so states are interchangeable between backends mid-stream — the
@@ -22,6 +24,20 @@ bit-identical hits, evictions and final state.
 ``put`` returns the landing ``(set, way)`` slot per request (-1 when the key
 did not land), which is what lets serve/engine.py store "payload == slot id"
 in a single call instead of probing again after the write.
+
+``replay`` is the one replay entry point on one device, and each path is
+picked where it can be observed:
+  * ``CacheBackend.replay`` is the chunked replay loop: one jitted
+    scan (the program ``jit_fn``) whose options — TinyLFU
+    admission, a TTL stream in the scan's ``xs``, the ``two_phase`` oracle
+    — are fixed at trace time; the jnp backend runs it as is.
+  * ``PallasBackend.replay`` picks the hierarchical or the flat
+    trace-resident megakernel from the VMEM fit, and that same scan
+    (``replay_scan``) for ``two_phase`` or a state over the budget.
+  * ``RefBackend.replay`` is the oracle's host loop over the chunks.
+  * ``_check_replay`` refuses, for all of them, the options that do not
+    compose (TTL × TinyLFU, TTL × two_phase, hierarchy × TinyLFU,
+    hierarchy × two_phase).
 
 Semantics:
   * ``jnp`` and ``pallas`` share the deterministic batched conflict
@@ -93,6 +109,31 @@ def _access_span(access):
     return call
 
 
+def _check_replay(tinylfu, hierarchy, ttls, two_phase):
+    """Refuse the replay options no path composes; every backend's
+    ``replay`` calls this one check.  Returns ``hierarchy``, or None when
+    it is absent or off (``l1_sets == 0``: the flat replay)."""
+    if hierarchy is not None and not hierarchy.enabled:
+        hierarchy = None
+    if ttls is not None and tinylfu is not None:
+        raise ValueError(
+            "per-request TTLs and TinyLFU admission are mutually "
+            "exclusive (the sketch has no expiry-aware semantics)")
+    if ttls is not None and two_phase:
+        raise ValueError(
+            "per-request TTLs require the fused access path; "
+            "two_phase has no expiry semantics")
+    if hierarchy is not None and tinylfu is not None:
+        raise ValueError(
+            "hierarchical replay does not support TinyLFU admission "
+            "(the sketch has no per-tier semantics yet)")
+    if hierarchy is not None and two_phase:
+        raise ValueError(
+            "hierarchical replay is the fused sequential-lane path; "
+            "two_phase does not compose with it")
+    return hierarchy
+
+
 _REGISTRY: dict[str, type] = {}
 
 
@@ -128,7 +169,8 @@ class CacheBackend:
 
     def __init__(self, cfg: KWayConfig):
         self.cfg = cfg
-        # (tinylfu, has_ttl) -> jitted chunked-scan replay
+        # (tinylfu, has_ttl, two_phase) -> the jitted chunked-scan replay,
+        # whose program is named ``jit_fn``
         self._replay_fns: dict = {}
 
     def init(self, *, ttl: bool = False) -> KWayState:
@@ -192,24 +234,19 @@ class CacheBackend:
                                      admit_on_miss=admit_on_miss,
                                      enabled=enabled, slot_value=slot_value)
 
-    def _replay_hier(self, state, chunks, enabled, tinylfu, hierarchy,
-                     ttls=None):
+    def _replay_hier(self, state, chunks, enabled, hierarchy, ttls=None):
         """Hierarchical replay through the pure-XLA twin
         (core/hierarchy.replay_l1_over_l2).  ``state`` may be a
         ``HierState`` (resumed hierarchy) or a plain ``KWayState`` (the L2;
         a fresh empty L1 is attached).  Returns (hits, evs, HierState',
         None)."""
         from repro.core import hierarchy as hier_mod
-        if tinylfu is not None:
-            raise ValueError(
-                "hierarchical replay does not support TinyLFU admission "
-                "(the sketch has no per-tier semantics yet)")
         hst = hier_mod.as_hier_state(self.cfg, hierarchy, state)
         return hier_mod.replay_l1_over_l2(self.cfg, hierarchy, hst,
                                           chunks, enabled, ttls=ttls)
 
     def replay(self, state, chunks, enabled, tinylfu=None, sketch=None,
-               hierarchy=None, ttls=None):
+               hierarchy=None, ttls=None, two_phase=False):
         """Replay a whole chunked trace: ``chunks`` uint32 [steps, B] and
         ``enabled`` bool [steps, B] in the ``router.pad_chunks`` layout,
         payload convention ``val == key`` (as int32).
@@ -222,86 +259,68 @@ class CacheBackend:
         with ``l1_sets > 0``) selects the L1-over-L2 replay mode: ``state``
         may then be a ``HierState`` or a bare L2 ``KWayState``, and the
         returned state is a ``HierState``.  ``l1_sets == 0`` (or None)
-        falls through to the flat paths unchanged.
+        is the flat replay.
 
         ``ttls`` (int32 [steps, B], chunked like the trace) enables expiry
         semantics: each request's insert carries a deadline, expired
         entries are scrubbed at every batch entry and never count as hits
-        (DESIGN.md §15).  Mutually exclusive with ``tinylfu`` (admission
-        has no expiry-aware victim semantics yet).
+        (DESIGN.md §15).
 
-        Default implementation: one jitted ``lax.scan`` over the chunks
-        through the fused ``access`` with the TinyLFU record → peek → admit
-        phase order of the batched replay — the chunked-scan oracle the
-        trace-resident megakernel (PallasBackend) is pinned against.
+        ``two_phase`` runs each chunk through ``access_two_phase``, the
+        unfused get-then-put oracle, in place of the fused ``access``.
+
+        Default implementation, and the one chunked replay loop on one
+        device: a jitted scan over the chunks through ``access``
+        with the TinyLFU record → peek → admit phase order, the TTL stream
+        riding in the scan's ``xs``.  It is the oracle the trace-resident
+        megakernel (PallasBackend) is pinned against.
         """
-        if not self.traceable:
-            raise ValueError(
-                f"backend {self.name!r} is host Python and has no scanned "
-                "replay; drive it through simulate.replay_batched")
-        if ttls is not None and tinylfu is not None:
-            raise ValueError(
-                "per-request TTLs and TinyLFU admission are mutually "
-                "exclusive (the sketch has no expiry-aware semantics)")
-        if hierarchy is not None and hierarchy.enabled:
-            return self._replay_hier(state, chunks, enabled, tinylfu,
-                                     hierarchy, ttls=ttls)
+        hierarchy = _check_replay(tinylfu, hierarchy, ttls, two_phase)
+        if hierarchy is not None:
+            return self._replay_hier(state, chunks, enabled, hierarchy,
+                                     ttls=ttls)
         if ttls is not None:
-            return self._replay_ttl(state, chunks, enabled, ttls)
+            state = kway.ensure_expiry(state)
+            ttls = jnp.asarray(ttls, jnp.int32)
         if tinylfu is not None and sketch is None:
             sketch = admission.make_sketch(tinylfu)
         if tinylfu is None and sketch is None:
             sketch = jnp.zeros((), jnp.int32)   # scan carry placeholder
-        if tinylfu not in self._replay_fns:
-            def fn(state, chunks, enabled, sketch, _tl=tinylfu):
+        key = (tinylfu, ttls is not None, two_phase)
+        if key not in self._replay_fns:
+            access = self.access_two_phase if two_phase else self.access
+
+            def fn(state, chunks, enabled, sketch, ttls, _tl=tinylfu):
                 def step(carry, xs):
                     cache, sk = carry
-                    keys, en = xs
+                    keys, en, tt = xs
                     admit = None
                     if _tl is not None:
                         sk = admission.record(_tl, sk, keys, enabled=en)
                         vk, vv = self.peek_victims(cache, keys)
                         admit = admission.admit(_tl, sk, keys, vk, vv)
-                    cache, hit, _, _, ev = self.access(
-                        cache, keys, keys.astype(jnp.int32), admit, en)
+                    ttl_kw = {} if tt is None else {"ttls": tt}
+                    cache, hit, _, _, ev = access(
+                        cache, keys, keys.astype(jnp.int32), admit, en,
+                        **ttl_kw)
                     return (cache, sk), (jnp.sum(hit.astype(jnp.int32)),
                                          jnp.sum(ev.astype(jnp.int32)))
 
                 (state, sk), (hits, evs) = jax.lax.scan(
-                    step, (state, sketch), (chunks, enabled))
+                    step, (state, sketch), (chunks, enabled, ttls))
                 return hits, evs, state, sk
-            self._replay_fns[tinylfu] = jax.jit(fn)
-        hits, evs, state, sk = self._replay_fns[tinylfu](
+            self._replay_fns[key] = jax.jit(fn)
+        hits, evs, state, sk = self._replay_fns[key](
             jax.tree_util.tree_map(jnp.asarray, state),
             jnp.asarray(chunks, jnp.uint32), jnp.asarray(enabled, jnp.bool_),
-            sketch)
+            sketch, ttls)
         return hits, evs, state, (sk if tinylfu is not None else None)
 
-    def _replay_ttl(self, state, chunks, enabled, ttls):
-        """TTL-enabled chunked-scan replay: a separate scan whose xs carry
-        the per-request TTL stream.  Kept apart from the TTL-less scan so
-        the ``ttls=None`` replay traces the exact pre-TTL program."""
-        state = kway.ensure_expiry(state)
-        key = ("ttl",)
-        if key not in self._replay_fns:
-            def fn(state, chunks, enabled, tchunks):
-                def step(cache, xs):
-                    keys, en, tt = xs
-                    cache, hit, _, _, ev = self.access(
-                        cache, keys, keys.astype(jnp.int32), None, en,
-                        ttls=tt)
-                    return cache, (jnp.sum(hit.astype(jnp.int32)),
-                                   jnp.sum(ev.astype(jnp.int32)))
-
-                state, (hits, evs) = jax.lax.scan(
-                    step, state, (chunks, enabled, tchunks))
-                return hits, evs, state
-            self._replay_fns[key] = jax.jit(fn)
-        hits, evs, state = self._replay_fns[key](
-            jax.tree_util.tree_map(jnp.asarray, state),
-            jnp.asarray(chunks, jnp.uint32), jnp.asarray(enabled, jnp.bool_),
-            jnp.asarray(ttls, jnp.int32))
-        return hits, evs, state, None
+    #: The chunked scan under its own name on every backend: ``replay``
+    #: itself here, and still this scan on pallas, whose ``replay`` picks
+    #: the megakernel — the megakernel's differential oracle and the path
+    #: of ``two_phase`` and of a state over the VMEM budget.
+    replay_scan = replay
 
 
 @register_backend("jnp")
@@ -447,42 +466,29 @@ class PallasBackend(CacheBackend):
         from repro.core.hierarchy import hier_footprint_bytes
         return hier_footprint_bytes(hierarchy) <= RESIDENT_VMEM_BUDGET
 
-    def replay_scan(self, state, chunks, enabled, tinylfu=None, sketch=None,
-                    ttls=None):
-        """The chunked-scan replay (the CacheBackend default), kept callable
-        on this backend as the megakernel's differential oracle and as the
-        fallback when the cache state exceeds the VMEM budget."""
-        return CacheBackend.replay(self, state, chunks, enabled,
-                                   tinylfu=tinylfu, sketch=sketch, ttls=ttls)
-
     def replay(self, state, chunks, enabled, tinylfu=None, sketch=None,
-               hierarchy=None, ttls=None):
-        """Trace-resident replay with a three-way dispatch (DESIGN.md §14):
+               hierarchy=None, ttls=None, two_phase=False):
+        """Trace-resident replay, its path picked from the arguments and
+        the VMEM fit (DESIGN.md §14):
 
           1. ``hierarchy`` configured (``l1_sets > 0``) → the hierarchical
              megakernel: L1 pinned in VMEM, L2 behind per-set row DMAs —
              near-resident throughput at capacities far past the flat
              budget.  If even the L1 exceeds the budget, the L1 tier is
              abandoned (``l1_demotion`` event) and the jnp twin runs.
-          2. no hierarchy, flat state fits (``resident_fits``) → the flat
-             megakernel: ALL lanes pinned in VMEM, bit-identical to
-             ``replay_scan``.
-          3. otherwise → the chunked-scan replay through the probe
+          2. ``two_phase`` → ``replay_scan``: the megakernel is the fused
+             access path only.
+          3. flat state fits (``resident_fits``) → the flat megakernel: ALL
+             lanes pinned in VMEM, bit-identical to ``replay_scan``.
+          4. otherwise → the chunked-scan replay through the probe
              kernels (``vmem_budget`` event; the hierarchical mode is named
              in the event detail as the faster opt-in), or a ``ValueError``
              with the numbers when even the probe kernels cannot hold the
              cache (``probe_fits``).
         """
         from repro.kernels import ops
-        if ttls is not None and tinylfu is not None:
-            raise ValueError(
-                "per-request TTLs and TinyLFU admission are mutually "
-                "exclusive (the sketch has no expiry-aware semantics)")
-        if hierarchy is not None and hierarchy.enabled:
-            if tinylfu is not None:
-                raise ValueError(
-                    "hierarchical replay does not support TinyLFU admission "
-                    "(the sketch has no per-tier semantics yet)")
+        hierarchy = _check_replay(tinylfu, hierarchy, ttls, two_phase)
+        if hierarchy is not None:
             from repro.core import hierarchy as hier_mod
             hst = hier_mod.as_hier_state(self.cfg, hierarchy, state,
                                          ttl=ttls is not None)
@@ -501,6 +507,9 @@ class PallasBackend(CacheBackend):
                         f"demoted to the jnp l1_over_l2 twin"))
             return hier_mod.replay_l1_over_l2(self.cfg, hierarchy, hst,
                                               chunks, enabled, ttls=ttls)
+        if two_phase:
+            return self.replay_scan(state, chunks, enabled, tinylfu=tinylfu,
+                                    sketch=sketch, two_phase=True)
         if not self.resident_fits():
             self._require_probe_fits()
             from repro.robust import events
@@ -698,3 +707,35 @@ class RefBackend(CacheBackend):
         else:
             vals = jnp.where(hit, vals, qvals)
         return state, hit, vals, ek, ev
+
+    def replay(self, state, chunks, enabled, tinylfu=None, sketch=None,
+               hierarchy=None, ttls=None, two_phase=False):
+        """The oracle's replay: a host loop over the chunks, each through
+        ``access`` (``access_two_phase`` when ``two_phase``) with its TTLs
+        when given.  Same contract as ``CacheBackend.replay`` and the same
+        results at B = 1; the flat replay only, without admission."""
+        if tinylfu is not None:
+            raise ValueError("TinyLFU replay is not wired for the ref backend")
+        if _check_replay(None, hierarchy, ttls, two_phase) is not None:
+            raise ValueError(
+                "hierarchical replay needs a traceable backend "
+                "('jnp' or 'pallas'); the ref oracle is flat-only")
+        if ttls is not None:
+            state = kway.ensure_expiry(state)
+            ttls = np.asarray(ttls, np.int32)
+        access = self.access_two_phase if two_phase else self.access
+        chunks = np.asarray(chunks, np.uint32)
+        enabled = np.asarray(enabled, bool)
+        hits, evs = [], []
+        for i in range(chunks.shape[0]):
+            keys = jnp.asarray(chunks[i])
+            ttl_kw = {} if ttls is None else {"ttls": ttls[i]}
+            state, hit, _, _, ev = access(
+                state, keys, keys.astype(jnp.int32), None,
+                jnp.asarray(enabled[i]), **ttl_kw)
+            hits.append(int(np.asarray(hit).sum()))
+            evs.append(int(np.asarray(ev).sum()))
+        return (jnp.asarray(hits, jnp.int32), jnp.asarray(evs, jnp.int32),
+                state, None)
+
+    replay_scan = replay          # the oracle's one replay is its host loop

@@ -1,37 +1,39 @@
-"""Jitted trace replay — the hit-ratio study engine (paper §5.2).
+"""Trace replay -> hit ratio, the hit-ratio study engine (paper §5.2).
 
-Replays a request trace through any (policy × associativity × admission ×
-backend) configuration and reports the hit ratio.  The replay is a
-``lax.scan`` over the trace with batch size 1 (exact sequential semantics,
-matching the paper's single-threaded hit-ratio measurements), jit-compiled
-once per cache shape — million-request traces replay in seconds on CPU and
-would be trivially fast on TPU.
+A front end over ``CacheBackend.replay``, which owns the chunked replay
+loop.  ``replay_batched`` cuts a trace into B-request chunks (the tail
+chunk padded with disabled lanes, so the ratio covers every request) and
+makes one ``replay`` call on the backend ``SimConfig.backend`` names;
+``replay`` is the same at B = 1, the exact sequential semantics of the
+paper's single-threaded hit-ratio measurements.
 
-A batched variant (``replay_batched``) replays B requests per step with the
-deterministic conflict-resolution semantics of ``kway.access`` — this is the
-throughput path and also demonstrates that batching barely perturbs the hit
-ratio (the vectorized analogue of the paper's observation that racy metadata
-updates do not hurt policy quality).
+Where each choice is made:
 
-Both entry points accept ``SimConfig.backend`` ("jnp" | "pallas" | "ref");
-``replay_batched`` additionally takes ``shards`` to run the set-sharded
-execution layer (core/sharded.py) — since PR 4 a single jitted ``lax.scan``
-with device-resident routing that composes with TinyLFU (per-shard
-sketches) and ``two_phase``.  The ``ref`` backend replays in plain Python
-(it is the differential-testing oracle, not a throughput path).
+  * ``jnp``: one jitted scan over the chunks
+    (``CacheBackend.replay``), with TinyLFU admission, per-request TTLs or
+    the unfused ``two_phase`` oracle as options of that one scan.
+  * ``pallas``: ``PallasBackend.replay`` picks from the VMEM fit — the
+    trace-resident megakernel, or the same scan through the probe kernels
+    (and the scan for ``two_phase``).
+  * ``ref``: ``RefBackend.replay``, the host loop of the sequential oracle.
+  * ``hierarchy``: the L1-over-L2 replay of the backend (DESIGN.md §14).
+  * ``shards > 1``: the set-sharded layer (core/sharded.py) instead.
+
+The backend module's ``_check_replay`` refuses the options that do not
+compose, in one place; ``replay_batched`` calls it before it picks the
+sharded path, so every path refuses with the same message.
 """
 from __future__ import annotations
 
 import dataclasses
-from functools import lru_cache, partial
+from functools import lru_cache
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core import admission, router
-from repro.core.backend import make_backend
+from repro.core.backend import _check_replay, make_backend
 from repro.core.kway import KWayConfig
 
 
@@ -46,10 +48,6 @@ class SimConfig:
     two_phase: bool = False
 
 
-def _access_fn(sim: SimConfig, be):
-    return be.access_two_phase if sim.two_phase else be.access
-
-
 @lru_cache(maxsize=None)
 def _cached_backend(name: str, cache: KWayConfig):
     """Backend instances memoized per config so their per-instance jit
@@ -58,93 +56,9 @@ def _cached_backend(name: str, cache: KWayConfig):
     return make_backend(name, cache)
 
 
-@partial(jax.jit, static_argnums=0)
-def _replay_scan(sim: SimConfig, trace: jnp.ndarray):
-    be = make_backend(sim.backend, sim.cache)
-    access = _access_fn(sim, be)
-    cache = be.init()
-    sketch = admission.make_sketch(sim.tinylfu) if sim.tinylfu else None
-
-    def step(carry, key):
-        cache, sketch, hits = carry
-        kb = key[None]
-        if sim.tinylfu is None:
-            cache, hit, _, _, _ = access(cache, kb, kb.astype(jnp.int32))
-        else:
-            sketch = admission.record(sim.tinylfu, sketch, kb)
-            vkeys, vvalid = be.peek_victims(cache, kb)
-            ok = admission.admit(sim.tinylfu, sketch, kb, vkeys, vvalid)
-            cache, hit, _, _, _ = access(
-                cache, kb, kb.astype(jnp.int32), admit_on_miss=ok
-            )
-        return (cache, sketch, hits + hit[0]), ()
-
-    (cache, _, hits), _ = jax.lax.scan(
-        step, (cache, sketch, jnp.zeros((), jnp.int32)), trace
-    )
-    return hits, cache
-
-
-def _replay_python(sim: SimConfig, trace: np.ndarray):
-    """Sequential replay for backends that cannot live inside lax.scan."""
-    if sim.tinylfu is not None:
-        raise ValueError("TinyLFU replay is not wired for the ref backend")
-    be = make_backend(sim.backend, sim.cache)
-    access = _access_fn(sim, be)
-    cache = be.init()
-    hits = 0
-    for t in trace:
-        kb = jnp.asarray([t], jnp.uint32)
-        cache, hit, _, _, _ = access(cache, kb, kb.astype(jnp.int32))
-        hits += int(hit[0])
-    return hits, cache
-
-
 def replay(sim: SimConfig, trace: np.ndarray) -> float:
-    """Exact sequential replay -> hit ratio."""
-    trace = np.asarray(trace, np.uint32)
-    if sim.backend == "ref":
-        hits, _ = _replay_python(sim, trace)
-        return float(hits) / trace.shape[0]
-    hits, _ = _replay_scan(sim, jnp.asarray(trace))
-    return float(hits) / trace.shape[0]
-
-
-@partial(jax.jit, static_argnums=0)
-def _replay_batched_scan(sim: SimConfig, chunks: jnp.ndarray,
-                         enabled: jnp.ndarray):
-    """Scan over pre-chunked trace [steps, B] with an enabled mask — the
-    tail chunk is padded with disabled lanes, so hit ratios cover the whole
-    trace (padding lanes touch neither the cache nor the sketch)."""
-    be = make_backend(sim.backend, sim.cache)
-    access = _access_fn(sim, be)
-    cache = be.init()
-    sketch = admission.make_sketch(sim.tinylfu) if sim.tinylfu else None
-
-    def step(carry, xs):
-        cache, sketch, hits = carry
-        keys, en = xs
-        if sim.tinylfu is None:
-            cache, hit, _, _, _ = access(
-                cache, keys, keys.astype(jnp.int32), None, en)
-        else:
-            # Same phase order as the sequential path, per chunk: record the
-            # accesses, peek each request's prospective victim, gate admission.
-            # Duplicate keys within a chunk coalesce in the sketch (documented
-            # record() approximation), so batched+TinyLFU tracks — not equals —
-            # sequential+TinyLFU; tests bound the hit-ratio gap.
-            sketch = admission.record(sim.tinylfu, sketch, keys, enabled=en)
-            vkeys, vvalid = be.peek_victims(cache, keys)
-            ok = admission.admit(sim.tinylfu, sketch, keys, vkeys, vvalid)
-            cache, hit, _, _, _ = access(
-                cache, keys, keys.astype(jnp.int32), ok, en
-            )
-        return (cache, sketch, hits + jnp.sum(hit.astype(jnp.int32))), ()
-
-    (cache, _, hits), _ = jax.lax.scan(
-        step, (cache, sketch, jnp.zeros((), jnp.int32)), (chunks, enabled)
-    )
-    return hits, cache
+    """Exact sequential replay (B = 1) -> hit ratio."""
+    return replay_batched(sim, trace, batch=1)
 
 
 def _pad_ttl_chunks(ttls: np.ndarray, batch: int) -> np.ndarray:
@@ -161,77 +75,32 @@ def _pad_ttl_chunks(ttls: np.ndarray, batch: int) -> np.ndarray:
 
 def replay_batched(
     sim: SimConfig, trace: np.ndarray, batch: int = 64, shards: int = 1,
-    resident: bool = False, hierarchy=None, ttls=None,
+    hierarchy=None, ttls=None,
 ) -> float:
     """Batched replay -> hit ratio over the WHOLE trace (the tail chunk is
     padded with disabled lanes on every path).
 
-    ``shards`` > 1 replays through the set-sharded layer as a single jitted
-    ``lax.scan`` — device-resident routing (core/router.py), per-shard
-    TinyLFU sketches, and ``two_phase`` all compose with sharding; only the
-    sequential-Python ``ref`` oracle cannot be sharded.
-
-    ``resident=True`` replays through ``CacheBackend.replay`` — on the
-    pallas backend the trace-resident megakernel (kernels/replay.py): the
-    whole trace in ONE launch with the cache state pinned in VMEM,
-    bit-identical to the chunked scan.  Sharded resident replay runs one
-    megakernel per shard (D launches total).  The resident path IS the
-    fused access composition, so it excludes ``two_phase``.
+    ``shards`` > 1 replays through the set-sharded layer
+    (``ShardedCache.replay``); only the sequential-Python ``ref`` oracle
+    cannot be sharded.
 
     ``hierarchy`` (a ``HierarchyConfig`` with ``l1_sets > 0``) selects the
-    L1-over-L2 replay mode (DESIGN.md §14): on the pallas backend the
-    hierarchical megakernel (VMEM L1, HBM L2), on the jnp backend the
-    bit-exact chunked-scan twin.  ``l1_sets == 0`` is the flat path
-    unchanged.  The hierarchy has sequential per-lane semantics and no
-    TinyLFU/two_phase composition yet.
+    L1-over-L2 replay mode (DESIGN.md §14); ``l1_sets == 0`` is the flat
+    path unchanged.
 
     ``ttls`` (int32 [n], optional, aligned with ``trace``) gives each
     request a time-to-live on the logical replay clock (DESIGN.md §15):
     a request that misses inserts with deadline ``clock + 2B + ttl``
     (``ttl <= 0`` = never expires), and an entry whose deadline has passed
-    is never served as a hit on any path.  TTLs exclude ``two_phase`` and
-    TinyLFU (the unfused composition and the sketch have no expiry
-    semantics)."""
+    is never served as a hit on any path."""
     trace = np.asarray(trace, np.uint32)
     n = trace.shape[0]
-    if sim.tinylfu is not None and sim.backend == "ref":
-        raise ValueError("TinyLFU replay is not wired for the ref backend")
     if ttls is not None:
         ttls = np.asarray(ttls, np.int32)
         if ttls.shape[0] != n:
             raise ValueError(
                 f"ttls length {ttls.shape[0]} != trace length {n}")
-        if sim.two_phase:
-            raise ValueError(
-                "per-request TTLs require the fused access path; "
-                "two_phase has no expiry semantics")
-        if sim.tinylfu is not None:
-            raise ValueError(
-                "per-request TTLs and TinyLFU admission are mutually "
-                "exclusive (the sketch has no expiry-aware semantics)")
-    if hierarchy is not None and not hierarchy.enabled:
-        hierarchy = None          # l1_sets == 0: the flat path, verbatim
-    if hierarchy is not None:
-        if sim.backend == "ref":
-            raise ValueError(
-                "hierarchical replay needs a traceable backend "
-                "('jnp' or 'pallas'); the ref oracle is flat-only")
-        if sim.two_phase:
-            raise ValueError(
-                "hierarchical replay is the fused sequential-lane path; "
-                "two_phase does not compose with it")
-        if sim.tinylfu is not None:
-            raise ValueError(
-                "hierarchical replay does not support TinyLFU admission")
-    if resident:
-        if sim.backend == "ref":
-            raise ValueError(
-                "the ref backend is sequential host Python; the resident "
-                "replay needs a traceable backend ('jnp' or 'pallas')")
-        if sim.two_phase:
-            raise ValueError(
-                "resident replay is the fused access path; two_phase is the "
-                "chunked-scan oracle — replay with resident=False")
+    hierarchy = _check_replay(sim.tinylfu, hierarchy, ttls, sim.two_phase)
     if shards > 1:
         if sim.backend == "ref":
             raise ValueError(
@@ -241,55 +110,16 @@ def replay_batched(
 
         sc = ShardedCache(ShardedConfig(
             cache=sim.cache, num_shards=shards, backend=sim.backend))
-        if hierarchy is not None:
-            hits, _, _ = sc.replay(trace, batch, resident=True,
-                                   hierarchy=hierarchy, ttls=ttls)
-            return hits / n
         hits, _, _ = sc.replay(trace, batch, tinylfu=sim.tinylfu,
-                               two_phase=sim.two_phase, resident=resident,
-                               ttls=ttls)
+                               two_phase=sim.two_phase,
+                               resident=hierarchy is not None,
+                               hierarchy=hierarchy, ttls=ttls)
         return hits / n
-    tchunks = None if ttls is None else _pad_ttl_chunks(ttls, batch)
-    if hierarchy is not None:
-        # hierarchical mode always runs the routed-chunk replay: the kernel
-        # on pallas (with its own budget/fallback ladder inside
-        # PallasBackend.replay), the jitted jnp twin otherwise.
-        be = _cached_backend(sim.backend, sim.cache)
-        chunks, enabled = router.pad_chunks(trace, batch)
-        hits, _, _, _ = be.replay(be.init(ttl=tchunks is not None),
-                                  chunks, enabled,
-                                  hierarchy=hierarchy, ttls=tchunks)
-        return float(jnp.sum(hits)) / n
-    if resident:
-        be = _cached_backend(sim.backend, sim.cache)
-        chunks, enabled = router.pad_chunks(trace, batch)
-        hits, _, _, _ = be.replay(be.init(ttl=tchunks is not None),
-                                  chunks, enabled,
-                                  tinylfu=sim.tinylfu, ttls=tchunks)
-        return float(jnp.sum(hits)) / n
-    if sim.backend == "ref":
-        be = make_backend(sim.backend, sim.cache)
-        access = _access_fn(sim, be)
-        cache = be.init(ttl=ttls is not None)
-        chunks, enabled = router.pad_chunks(trace, batch)
-        hits = 0
-        for step, (chunk, en) in enumerate(zip(chunks, enabled)):
-            tt = None if tchunks is None else jnp.asarray(tchunks[step])
-            cache, hit, _, _, _ = access(
-                cache, jnp.asarray(chunk), jnp.asarray(chunk, jnp.int32),
-                None, jnp.asarray(en),
-                **({} if tt is None else {"ttls": tt}))
-            hits += int(np.asarray(hit).sum())
-        return hits / n
+    be = _cached_backend(sim.backend, sim.cache)
     chunks, enabled = router.pad_chunks(trace, batch)
-    if tchunks is not None:
-        # the TTL chunked scan lives behind CacheBackend.replay (it carries
-        # the expiry lane through the scan); _cached_backend keeps its jit
-        # cache warm across calls just like _replay_batched_scan's.
-        be = _cached_backend(sim.backend, sim.cache)
-        hits, _, _, _ = be.replay(be.init(ttl=True), chunks, enabled,
-                                  ttls=tchunks)
-        return float(jnp.sum(hits)) / n
-    hits, _ = _replay_batched_scan(
-        sim, jnp.asarray(chunks), jnp.asarray(enabled))
-    return float(hits) / n
+    hits, _, _, _ = be.replay(
+        be.init(ttl=ttls is not None), chunks, enabled, tinylfu=sim.tinylfu,
+        hierarchy=hierarchy,
+        ttls=None if ttls is None else _pad_ttl_chunks(ttls, batch),
+        two_phase=sim.two_phase)
+    return float(jnp.sum(hits)) / n

@@ -123,6 +123,18 @@ def _tp_record(name: str, batch: int, mops: float, **extra) -> dict:
     return rec
 
 
+def _replay_hit_ratio(be, trace, batch, tinylfu=None, scan=False):
+    """Hit ratio of one whole-trace ``be.replay``: on pallas the path it
+    picks from the VMEM fit, the megakernel when the state fits.
+    ``scan=True`` runs ``be.replay_scan``, the chunked scan and the
+    megakernel's oracle (on jnp the same program as ``replay``)."""
+    from repro.core import router
+    chunks, enabled = router.pad_chunks(trace, batch)
+    replay = be.replay_scan if scan else be.replay
+    hits, _, _, _ = replay(be.init(), chunks, enabled, tinylfu=tinylfu)
+    return float(np.asarray(hits).sum()) / len(trace)
+
+
 def throughput_vs_batch(quick: bool = False, progress=None,
                         backends=("jnp", "pallas", "ref"), shards=(1, 4)):
     """Paper Figs. 14-26 analogue: ops/sec vs batch size (thread analogue)
@@ -250,17 +262,17 @@ def throughput_vs_batch(quick: bool = False, progress=None,
     # kernel path (headline rows; the full sweep + bit-identity gate live
     # in throughput_resident / benchmarks.throughput --resident-compare)
     if "pallas" in backends:
-        from repro.core.simulate import SimConfig, replay_batched
+        from repro.core.backend import make_backend
         n_rep, b_rep = 16_384, 256
         tr_rep = tr[:n_rep]
-        sim = SimConfig(cache=cfg, backend="pallas")
+        pb = make_backend("pallas", cfg)
         rp50 = {}
-        for mode, resident in (("scan", False), ("resident", True)):
+        for mode, scan in (("scan", True), ("resident", False)):
             if progress:
                 progress(f"replay {mode} pallas")
             st = time_replay_percentiles(
-                lambda _r=resident: replay_batched(sim, tr_rep, batch=b_rep,
-                                                   resident=_r),
+                lambda _s=scan: _replay_hit_ratio(pb, tr_rep, b_rep,
+                                                  scan=_s),
                 iters=3)
             rp50[mode] = st["p50"]
             records.append(_tp_record(
@@ -300,8 +312,8 @@ def throughput_resident(quick: bool = False, progress=None,
     what the CI ``--resident-compare`` gate enforces.
     """
     from repro.core import admission, traces
+    from repro.core.backend import make_backend
     from repro.core.kway import KWayConfig
-    from repro.core.simulate import SimConfig, replay_batched
 
     policy = Policy.LRU
     batch = 256
@@ -312,13 +324,13 @@ def throughput_resident(quick: bool = False, progress=None,
     records = []
     p50 = {}
     for bname in backends:
-        sim = SimConfig(cache=kcfg, backend=bname)
-        for mode, resident in (("scan", False), ("resident", True)):
+        be = make_backend(bname, kcfg)
+        for mode, scan in (("scan", True), ("resident", False)):
             if progress:
                 progress(f"replay {mode} {bname}")
             st = time_replay_percentiles(
-                lambda _r=resident: replay_batched(sim, tr, batch=batch,
-                                                   resident=_r),
+                lambda _b=be, _s=scan: _replay_hit_ratio(_b, tr, batch,
+                                                         scan=_s),
                 iters=3 if quick else 5)
             p50[(bname, mode)] = st["p50"]
             records.append(_tp_record(
@@ -343,11 +355,11 @@ def throughput_resident(quick: bool = False, progress=None,
                 if progress:
                     progress(f"resident-eq {family}/{pol.name}/{adm}")
                 cfg = KWayConfig(num_sets=128, ways=8, policy=pol)
-                sim = SimConfig(cache=cfg, backend=eq_backend,
-                                tinylfu=tlfu if adm == "tinylfu" else None)
-                hr_res = replay_batched(sim, tre, batch=batch, resident=True)
-                hr_scan = replay_batched(sim, tre, batch=batch,
-                                         resident=False)
+                be = make_backend(eq_backend, cfg)
+                tl = tlfu if adm == "tinylfu" else None
+                hr_res = _replay_hit_ratio(be, tre, batch, tinylfu=tl)
+                hr_scan = _replay_hit_ratio(be, tre, batch, tinylfu=tl,
+                                            scan=True)
                 records.append({
                     "id": f"resident-eq/{family}/{pol.name}/{adm}",
                     "family": family, "policy": pol.name,
@@ -543,18 +555,15 @@ def showdown(quick: bool = False, progress=None, threads=(1, 2, 4, 8),
             # -- our device paths (same trace, same capacity, k=8) --------
             kcfg = KWayConfig(num_sets=capacity // ways, ways=ways,
                               policy=pol_enum[policy])
-            ours = (("jnp-batched", "jnp", False),
-                    ("pallas-resident", "pallas", True))
+            ours = (("jnp-batched", "jnp"), ("pallas-resident", "pallas"))
             hr_ours = {}
-            for name, backend, resident in ours:
+            for name, backend in ours:
                 if progress:
                     progress(f"{family}/{name}-{policy}")
                 sim = SimConfig(cache=kcfg, backend=backend)
-                hr_ours[name] = replay_batched(sim, tr, batch=batch,
-                                               resident=resident)  # + warm
+                hr_ours[name] = replay_batched(sim, tr, batch=batch)  # + warm
                 st = time_replay_percentiles(
-                    lambda sim=sim, r=resident: replay_batched(
-                        sim, tr, batch=batch, resident=r),
+                    lambda sim=sim: replay_batched(sim, tr, batch=batch),
                     iters=iters, warmup=1)
                 rec(f"showdown/{family}/{name}-{policy}/batch{batch}",
                     n / st["p50"], family=family, lib=name, policy=policy,

@@ -243,7 +243,7 @@ def _step_jnp(num_sets, ways, sample, hash_seed, tinylfu,
 
     ok = jnp.bool_(True)
     if tinylfu is not None:
-        # Phase order of simulate._replay_scan: record, peek victim at time
+        # Phase order of CacheBackend.replay: record, peek victim at time
         # `clock` (pre-get), admission-gate the miss insert.
         sketch = admission.record(tinylfu, sketch, qkey[None])
         vway0 = _victim_way(num_sets, ways, sample, pidx1, row, ma[s], mb[s],

@@ -31,7 +31,7 @@ This kernel retires that split for the replay workload (DESIGN.md §10):
 Equivalence contract: for any trace, ``replay_resident`` produces the same
 per-chunk hit counts, eviction counts and final state as scanning the same
 chunks through ``CacheBackend.access`` (the fused path) with the TinyLFU
-phases of ``simulate._replay_batched_scan``.  tests/test_resident.py pins
+phases of ``CacheBackend.replay``'s scan.  tests/test_resident.py pins
 this across all pallas-supported policies × ±TinyLFU.
 
 Payload convention: the replay workload stores ``val == key`` (as int32),

@@ -239,20 +239,46 @@ def test_fused_access_equals_two_phase_sampled(policy, rng):
 @pytest.mark.parametrize("policy", [Policy.LRU, Policy.LFU])
 def test_fused_access_equals_two_phase_tinylfu(backend, policy):
     """±TinyLFU: the fused path under admission gating replays to the same
-    hit count and final state as the two-phase path."""
-    import jax.numpy as _jnp
-
-    from repro.core import admission, traces
-    from repro.core.simulate import SimConfig, _replay_scan
+    hit count and final state as the two-phase path, through the B=1 replay
+    ``simulate.replay`` runs."""
+    from repro.core import admission, router
     cfg = KWayConfig(num_sets=8, ways=4, policy=policy)
     tl = admission.for_capacity(32)
-    tr = _jnp.asarray(np.asarray(
-        traces.generate("zipf", 250, seed=3, catalog=64), np.uint32))
-    hf, sf = _replay_scan(SimConfig(cfg, tl, backend=backend), tr)
-    ht, st = _replay_scan(
-        SimConfig(cfg, tl, backend=backend, two_phase=True), tr)
-    assert int(hf) == int(ht)
+    chunks, en = router.pad_chunks(
+        traces.generate("zipf", 250, seed=3, catalog=64), 1)
+    be = make_backend(backend, cfg)
+    hf, ef, sf, _ = be.replay(be.init(), chunks, en, tinylfu=tl)
+    ht, et, st, _ = be.replay(be.init(), chunks, en, tinylfu=tl,
+                              two_phase=True)
+    np.testing.assert_array_equal(np.asarray(hf), np.asarray(ht))
+    np.testing.assert_array_equal(np.asarray(ef), np.asarray(et))
     _assert_states_equal(sf, st, f"{backend}/{policy}/tinylfu")
+
+
+@pytest.mark.parametrize("with_ttls", [False, True])
+def test_ref_replay_matches_jnp_replay(with_ttls):
+    """The ref oracle's host-loop replay equals the jnp scan at B=1: per
+    chunk hits and evictions and the final state, ±per-request TTLs."""
+    from repro.core import router
+    cfg = KWayConfig(num_sets=8, ways=4, policy=Policy.LRU)
+    tr = _zipf(200, seed=5, catalog=64)
+    chunks, en = router.pad_chunks(tr, 1)
+    tt = None
+    if with_ttls:
+        rng = np.random.default_rng(5)
+        tt = rng.integers(0, 40, tr.shape[0]).astype(np.int32)[:, None]
+    got = {}
+    for name in ("ref", "jnp"):
+        be = make_backend(name, cfg)
+        got[name] = be.replay(be.init(ttl=with_ttls), chunks, en, ttls=tt)
+    (hr, er, sr, _), (hj, ej, sj, _) = got["ref"], got["jnp"]
+    np.testing.assert_array_equal(np.asarray(hr), np.asarray(hj))
+    np.testing.assert_array_equal(np.asarray(er), np.asarray(ej))
+    _assert_states_equal(sr, sj, f"ref vs jnp, ttls={with_ttls}")
+    if with_ttls:
+        np.testing.assert_array_equal(np.asarray(sr.expiry),
+                                      np.asarray(sj.expiry))
+        assert int(np.asarray(hj).sum()) > 0
 
 
 def test_ref_access_is_two_phase_and_matches_fused(rng):

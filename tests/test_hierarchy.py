@@ -354,3 +354,24 @@ def test_fixture_trace_registered_and_deterministic():
     tr2 = traces.generate("lirs_two_pools", 12_000)
     np.testing.assert_array_equal(tr2[:10_000], tr)
     np.testing.assert_array_equal(tr2[10_000:], tr[:2_000])
+
+
+def test_hierarchy_figure_quick_gate():
+    """``figures.hierarchy(quick=True)``, the run behind the CI hierarchy
+    gate, runs end to end, and its hit-ratio rows pass that gate against
+    the checked-in baseline: ``l1-0`` equal to the flat replay, the
+    enabled rows in the 0.02 band.  The gate's over-budget speedup
+    headline is a claim about chip timings, which the CPU interpreter
+    cannot show, so only the rows' presence is checked here."""
+    from benchmarks.throughput import hierarchy_gate
+    from repro.eval import figures
+
+    _, records, _ = figures.hierarchy(quick=True)
+    ids = {r["id"] for r in records}
+    assert {"hier-tp/speedup/s512/batch256",
+            "hier-tp/speedup/s4096/batch256"} <= ids
+    checked, breaches = hierarchy_gate(
+        "benchmarks/baselines/BENCH_throughput_hierarchy_quick.json",
+        records)
+    assert checked > len([i for i in ids if i.startswith("hier-hr/")])
+    assert [b for b in breaches if "speedup" not in b] == []

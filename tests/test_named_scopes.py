@@ -60,8 +60,8 @@ def test_jnp_replay_program_carries_every_phase():
     chunks = jnp.arange(2 * B, dtype=jnp.uint32).reshape(2, B)
     en = jnp.ones((2, B), jnp.bool_)
     be.replay(be.init(), chunks, en)
-    lo = be._replay_fns[None].lower(be.init(), chunks, en,
-                                    jnp.zeros((), jnp.int32))
+    lo = be._replay_fns[(None, False, False)].lower(
+        be.init(), chunks, en, jnp.zeros((), jnp.int32), None)
     assert _scopes(lo) == PHASES
 
 

@@ -128,19 +128,24 @@ def test_resident_single_compile_single_launch():
 
 
 def test_resident_simulate_entry_point():
-    """simulate.replay_batched(resident=True) == resident=False, both
-    backends, ±TinyLFU — the harness-facing equality the CI gate enforces."""
+    """simulate.replay_batched on pallas (the megakernel, picked from the
+    VMEM fit) == the pallas chunked scan == the jnp replay, ±TinyLFU — the
+    harness-facing equality the CI gate enforces."""
     from repro.core.simulate import SimConfig, replay_batched
 
     tr = traces.generate("zipf", 2000, seed=3, catalog=2048)
     cfg = KWayConfig(num_sets=64, ways=8, policy=Policy.LRU)
     tl = admission.for_capacity(cfg.capacity)
-    for backend in ("jnp", "pallas"):
-        for tlc in (None, tl):
-            sim = SimConfig(cache=cfg, backend=backend, tinylfu=tlc)
-            a = replay_batched(sim, tr, batch=128, resident=False)
-            b = replay_batched(sim, tr, batch=128, resident=True)
-            assert a == b, (backend, tlc is not None, a, b)
+    pb = make_backend("pallas", cfg)
+    assert pb.resident_fits()
+    chunks, en = router.pad_chunks(tr, 128)
+    for tlc in (None, tl):
+        a = replay_batched(SimConfig(cache=cfg, backend="pallas",
+                                     tinylfu=tlc), tr, batch=128)
+        h, _, _, _ = pb.replay_scan(pb.init(), chunks, en, tinylfu=tlc)
+        b = float(jnp.sum(h)) / len(tr)
+        c = replay_batched(SimConfig(cache=cfg, tinylfu=tlc), tr, batch=128)
+        assert a == b == c, (tlc is not None, a, b, c)
 
 
 def test_resident_sharded_is_d_launches():
@@ -207,22 +212,50 @@ def test_resident_vmem_fallback():
 
 
 def test_resident_excludes_two_phase_and_ref():
-    """Loud guards: the resident path is the fused access composition and
-    needs a traceable backend."""
+    """The replay contract: the ref oracle's host loop has no admission,
+    TTLs and TinyLFU do not compose (refused by the backend itself), and a
+    ``two_phase`` replay on pallas runs the chunked scan — the megakernel is
+    the fused access path only — with the scan's results."""
     from repro.core.simulate import SimConfig, replay_batched
 
     tr = traces.generate("zipf", 256, seed=1, catalog=96)
     cfg = KWayConfig(policy=Policy.LRU, **CONFIG)
-    with pytest.raises(ValueError, match="two_phase"):
-        replay_batched(SimConfig(cache=cfg, two_phase=True), tr,
-                       batch=32, resident=True)
-    with pytest.raises(ValueError, match="ref"):
-        replay_batched(SimConfig(cache=cfg, backend="ref"), tr,
-                       batch=32, resident=True)
-    with pytest.raises(ValueError, match="host Python"):
-        be = make_backend("ref", cfg)
-        chunks, en = router.pad_chunks(tr, 32)
-        be.replay(be.init(), chunks, en)
+    tl = admission.for_capacity(cfg.capacity)
+    chunks, en = router.pad_chunks(tr, 32)
+    with pytest.raises(ValueError, match="ref backend"):
+        rb = make_backend("ref", cfg)
+        rb.replay(rb.init(), chunks, en, tinylfu=tl)
+    with pytest.raises(ValueError, match="TinyLFU"):
+        jb = make_backend("jnp", cfg)
+        jb.replay(jb.init(ttl=True), chunks, en, tinylfu=tl,
+                  ttls=np.ones(chunks.shape, np.int32))
+    pb = make_backend("pallas", cfg)
+    assert pb.resident_fits()
+    kreplay.reset_trace_counts()
+    h1, e1, st1, _ = pb.replay(pb.init(), chunks, en, two_phase=True)
+    assert sum(kreplay.trace_counts().values()) == 0  # no megakernel ran
+    h2, e2, st2, _ = pb.replay_scan(pb.init(), chunks, en, two_phase=True)
+    np.testing.assert_array_equal(np.asarray(h1), np.asarray(h2))
+    np.testing.assert_array_equal(np.asarray(e1), np.asarray(e2))
+    _assert_state_equal(st1, st2, "pallas two_phase")
+    assert replay_batched(SimConfig(cache=cfg, backend="pallas",
+                                    two_phase=True), tr, batch=32) == \
+        float(np.asarray(h1).sum()) / len(tr)
+
+
+@pytest.mark.parametrize("shards", [1, 2])
+def test_hierarchy_two_phase_refused_alike(shards):
+    """Hierarchy × ``two_phase`` is refused with the one message of the
+    backend's check, sharded or not."""
+    from repro.core.hierarchy import HierarchyConfig
+    from repro.core.simulate import SimConfig, replay_batched
+
+    tr = traces.generate("zipf", 256, seed=1, catalog=96)
+    cfg = KWayConfig(policy=Policy.LRU, **CONFIG)
+    with pytest.raises(ValueError, match="fused sequential-lane path"):
+        replay_batched(SimConfig(cache=cfg, two_phase=True), tr, batch=32,
+                       shards=shards,
+                       hierarchy=HierarchyConfig(l1_sets=4, l1_ways=4))
 
 
 def test_resident_state_carry_midstream():
