@@ -398,27 +398,82 @@ def _resolve_inserts(cfg: KWayConfig, qkeys, sets, eligible, order):
 # contents) are split so alternative probe substrates — the pure-jnp path
 # below, the Pallas kernel in kernels/kway_probe.py — feed one common apply
 # and stay bit-identical (DESIGN.md §3).  The applies write the lanes by two
-# helpers on slot views, one per phase, so the three cannot drift.
+# helpers on slot views, one per phase, so the three cannot drift; each runs
+# its phase's scatters under one guard (DESIGN.md §8).
 # ---------------------------------------------------------------------------
+
+def _scatter_when(pred, lanes, slot, updates, how="set"):
+    """Scatter ``updates[i]`` into ``lanes[i]`` at ``slot`` for each lane
+    (``.at[slot].<how>(..., mode="drop")``), or return the lanes as they
+    are when ``pred`` is false: a phase's lane scatters under one guard.
+
+    On the TPU a scatter costs per update whether or not the update writes
+    anything, so a phase in which no lane writes skips its scatters rather
+    than running them on dropped or no-op updates.  Callers guarantee that
+    a false ``pred`` means every update is a no-op, so the guard changes no
+    output.
+
+    The guard is a loop of zero or one trips, not a ``lax.cond``: compiled
+    for a v5e, a ``cond`` over the lanes made memory-space assignment keep
+    every lane of a replay scan in HBM (and copy lanes in and out of the
+    branch), while the loop keeps the lanes where the unguarded scan keeps
+    them.  Under ``vmap`` (``ShardedCache``'s one-device fallback) a batched
+    predicate would make the loop run every element's scatters and select
+    over whole lanes; the batching rule instead reduces ``pred`` over the
+    mapped axis and runs the vmapped scatters under one unbatched guard,
+    which is exact for the same reason: an element whose ``pred`` is false
+    writes nothing when the scatters run.
+    """
+    def write(lanes, slot, updates):
+        return tuple(getattr(lane.at[slot], how)(x, mode="drop")
+                     for lane, x in zip(lanes, updates))
+
+    def once_if(pred, write, lanes, slot, updates):
+        def body(carry):
+            return jnp.zeros_like(carry[0]), write(carry[1], slot, updates)
+        return jax.lax.while_loop(lambda c: c[0], body, (pred, lanes))[1]
+
+    @jax.custom_batching.custom_vmap
+    def guarded(pred, lanes, slot, updates):
+        return once_if(pred, write, lanes, slot, updates)
+
+    @guarded.def_vmap
+    def _rule(axis_size, in_batched, pred, lanes, slot, updates):
+        pred_b, lanes_b, slot_b, updates_b = in_batched
+        lanes = tuple(x if b else jnp.broadcast_to(x, (axis_size,) + x.shape)
+                      for x, b in zip(lanes, lanes_b))
+        axes = (0, 0 if slot_b else None,
+                tuple(0 if b else None for b in updates_b))
+        out = once_if(jnp.any(pred) if pred_b else pred,
+                      jax.vmap(write, in_axes=axes), lanes, slot, updates)
+        return out, (True,) * len(out)
+
+    return guarded(pred, tuple(lanes), slot, tuple(updates))
+
 
 def _write_hits(cfg: KWayConfig, meta_a, meta_b, slot, hit, times):
     """The hit phase's lane write: each hit's ``on_hit`` transition of
-    ``meta_a`` at its ``slot`` (slot views in, ``meta_a``'s view out).
+    ``meta_a`` at its ``slot`` (slot views in, ``meta_a``'s view out),
+    skipped when no lane hits (``_scatter_when``).
 
     Duplicate slots in one batch: LFU/Hyperbolic counts accumulate (two
     hits = +2, a scatter-add of the deltas), LRU takes the latest stamp (a
     scatter-max).  ``on_hit`` is the identity for FIFO/RANDOM and keeps
-    ``meta_b`` for every policy, so neither is ever written.
+    ``meta_b`` for every policy, so neither is ever written.  A lane that
+    does not hit adds 0 or takes the max with ``-(2**31 - 1)``, so a batch
+    with no hit writes nothing (an LRU stamp of exactly ``-2**31``, which
+    the int32 clock reaches only after it wraps, is the one value such a
+    max would raise).
     """
     if cfg.policy in (Policy.FIFO, Policy.RANDOM):
         return meta_a
     ma_hit = meta_a[slot]
     new_a, _ = on_hit(cfg.policy, ma_hit, meta_b[slot], times)
     if cfg.policy in (Policy.LFU, Policy.HYPERBOLIC):
-        return meta_a.at[slot].add(jnp.where(hit, new_a - ma_hit, 0),
-                                   mode="drop")
-    return meta_a.at[slot].max(jnp.where(hit, new_a, -(2**31 - 1)),
-                               mode="drop")
+        update, how = jnp.where(hit, new_a - ma_hit, 0), "add"
+    else:
+        update, how = jnp.where(hit, new_a, -(2**31 - 1)), "max"
+    return _scatter_when(jnp.any(hit), (meta_a,), slot, (update,), how)[0]
 
 
 def _write_entries(v: _Slots, state: KWayState, meta_a, slot_w, qkeys, qvals,
@@ -428,21 +483,20 @@ def _write_entries(v: _Slots, state: KWayState, meta_a, slot_w, qkeys, qvals,
     given as the slot view the hit phase left).  Requests that land nowhere
     carry ``v.drop``, past the last slot, where the write is dropped — a
     parking slot such as 0 is not a no-op, since a duplicate index lets the
-    parked write clobber an active request's insert there.  Returns the
-    state with its lanes back in ``[S, k]``."""
-    def put(flat, x):
-        return v.lane(flat.at[slot_w].set(x, mode="drop"))
-
-    return KWayState(
-        keys=put(v.flat(state.keys), qkeys),
-        fprint=put(v.flat(state.fprint), hashing.fingerprint(qkeys)),
-        vals=put(v.flat(state.vals), qvals),
-        meta_a=put(meta_a, new_a),
-        meta_b=put(v.flat(state.meta_b), new_b),
-        clock=clock,
-        expiry=(None if state.expiry is None
-                else put(v.flat(state.expiry), deadline)),
-    )
+    parked write clobber an active request's insert there.  When every
+    request carries ``v.drop`` the scatters are skipped (``_scatter_when``).
+    Returns the state with its lanes back in ``[S, k]``."""
+    lanes = (v.flat(state.keys), v.flat(state.fprint), v.flat(state.vals),
+             meta_a, v.flat(state.meta_b))
+    xs = (qkeys, hashing.fingerprint(qkeys), qvals, new_a, new_b)
+    if state.expiry is not None:
+        lanes += (v.flat(state.expiry),)
+        xs += (deadline,)
+    keys, fprint, vals, meta_a, meta_b, *expiry = map(v.lane, _scatter_when(
+        jnp.any(slot_w != v.drop), lanes, slot_w, xs))
+    return KWayState(keys=keys, fprint=fprint, vals=vals, meta_a=meta_a,
+                     meta_b=meta_b, clock=clock,
+                     expiry=expiry[0] if expiry else None)
 
 
 @partial(jax.jit, static_argnums=0)
@@ -636,7 +690,10 @@ def apply_access(
     lanes (six with the expiry lane).  Every gather and scatter reads or
     writes a lane through its flat slot view (``_Slots``), in the chip's
     tile order where the geometry allows, so on the TPU a lane is written
-    in place rather than laid out anew after each scatter.
+    in place rather than laid out anew after each scatter.  Each phase's
+    scatters run only when some lane writes in it (``_scatter_when``): a
+    batch that hits everywhere skips the insert pass, one that hits nowhere
+    the hit scatter.
 
     ``slot_value`` is the cache-as-allocator mode (the paged-KV engine's
     page-id convention): inserts store ``set * ways + way`` — the landing

@@ -150,13 +150,9 @@ def _shapes(line: str) -> list[str]:
             for m in re.findall(r"\w+\[[\d,]*\]\{[^}]*\}", result)]
 
 
-def test_jnp_replay_writes_lanes_in_place(sds):
-    """A 64-chunk scan over ``JnpBackend.access`` at 2^20 sets x 8 ways, the
-    node-scale geometry: every lane scatter goes through the lanes' slot
-    view, in the order of the lanes' tiles, so the program is
-    the scan's one loop, no ``dynamic-update-slice`` lays out a lane, and
-    no copy changes a lane's layout (the view is a bitcast)."""
-    sets, ways, steps, b = 1 << 20, 8, 64, 4096
+def _replay_hlo(sds, sets, ways=8, steps=64, b=4096):
+    """A ``steps``-chunk scan over ``JnpBackend.access`` at ``sets`` x
+    ``ways``, compiled for a v5e: the program's text."""
     be = make_backend("jnp", KWayConfig(num_sets=sets, ways=ways))
 
     def replay(state, chunks, enabled):
@@ -166,19 +162,56 @@ def test_jnp_replay_writes_lanes_in_place(sds):
             return st, (jnp.sum(hit), jnp.sum(ev))
         return jax.lax.scan(step, state, (chunks, enabled))
 
-    lanes = _lanes(sds, sets, ways)
-    state = KWayState(*lanes, clock=sds(()))
-    hlo = jax.jit(replay).lower(
+    state = KWayState(*_lanes(sds, sets, ways), clock=sds(()))
+    return jax.jit(replay).lower(
         state, sds((steps, b), jnp.uint32), sds((steps, b), jnp.bool_)
     ).compile().as_text()
 
+
+def _lane_sized(line: str, slots: int) -> bool:
+    return slots in [math.prod(int(d) for d in dims.split(",") if d)
+                     for dims in re.findall(r"\[([\d,]*)\]",
+                                            " ".join(_shapes(line)))]
+
+
+def _computations(hlo: str):
+    """({computation: its instruction lines}, the entry's name)."""
+    comps, cur, entry = {}, None, None
+    for ln in hlo.splitlines():
+        head = re.match(r"^(ENTRY )?%([\w.\-]+) ", ln)
+        if head and ln.rstrip().endswith("{"):
+            cur = comps.setdefault(head.group(2), [])
+            entry = head.group(2) if head.group(1) else entry
+        elif ln.startswith("}"):
+            cur = None
+        elif cur is not None and " = " in ln:
+            cur.append(ln)
+    return comps, entry
+
+
+def _body(loop: str) -> str:
+    return re.search(r"body=%([\w.\-]+)", loop).group(1)
+
+
+def test_jnp_replay_writes_lanes_in_place(sds):
+    """A 64-chunk scan over ``JnpBackend.access`` at 2^20 sets x 8 ways, the
+    node-scale geometry: every lane scatter goes through the lanes' slot
+    view, in the order of the lanes' tiles, so the program's loops are the
+    scan and, in its body, the two write guards (``kway._scatter_when``),
+    no ``dynamic-update-slice`` lays out a lane, and no copy changes a
+    lane's layout (the view is a bitcast)."""
+    sets, ways = 1 << 20, 8
+    hlo = _replay_hlo(sds, sets, ways)
+
+    comps, entry = _computations(hlo)
+    (scan,) = [ln for ln in comps[entry] if " while(" in ln]
+    guards = [ln for ln in comps[_body(scan)] if " while(" in ln]
+    assert len(guards) == 2, len(guards)
     loops = re.findall(r"\bwhile\(", hlo)
-    assert len(loops) == 1, len(loops)
+    assert len(loops) == 3, len(loops)
 
     def lane_sized(line):
-        return sets * ways in [math.prod(int(d) for d in dims.split(",") if d)
-                               for dims in re.findall(r"\[([\d,]*)\]",
-                                                      " ".join(_shapes(line)))]
+        return _lane_sized(line, sets * ways)
 
     assert not [ln for ln in hlo.splitlines()
                 if "dynamic-update-slice(" in ln and lane_sized(ln)]
@@ -192,3 +225,47 @@ def test_jnp_replay_writes_lanes_in_place(sds):
             else:                       # (destination, source, context)
                 dst, src = _shapes(ln)[:2]
             assert dst == src, ln
+
+
+#: Lane-sized copies in the body of the unguarded scan (compiled for a
+#: v5e): at 8192 sets the two row-gather layout copies of
+#: ``keys`` and ``fprint`` and the ``meta_a`` transpose; none at 2^20.  The
+#: guarded body adds at 8192 sets four 256 KB copies between HBM and ``S(1)``
+#: around the insert guard (``vals`` and ``meta_b`` in and out, which
+#: memory-space assignment keeps in HBM across the scan there), and none at
+#: 2^20, where a lane is 32 MB.
+GUARDED_BODY_COPIES = {8192: 3 + 4, 1 << 20: 0}
+
+
+@pytest.mark.parametrize("sets", sorted(GUARDED_BODY_COPIES))
+def test_jnp_replay_guards_lane_scatters(sds, sets):
+    """The scan's body holds the two write guards, loops of zero or one
+    trips (``kway._scatter_when``): hit's under ``kway.hit`` with the
+    ``meta_a`` scatter-max, insert's under ``kway.insert`` with the five
+    lane scatters, and no lane scatter outside them.  No lane-sized
+    ``dynamic-update-slice`` appears, neither guard copies a lane, and the
+    body copies no more lanes than ``GUARDED_BODY_COPIES`` allows."""
+    ways = 8
+    hlo = _replay_hlo(sds, sets, ways)
+    slots = sets * ways
+    comps, entry = _computations(hlo)
+    (scan,) = [ln for ln in comps[entry] if " while(" in ln]
+    body = _body(scan)
+    guards = {re.search(r"kway\.(hit|insert)/while", ln).group(1): _body(ln)
+              for ln in comps[body] if " while(" in ln}
+    assert sorted(guards) == ["hit", "insert"]
+
+    def lane_ops(comp, pattern):
+        return [ln for ln in comps[comp]
+                if _lane_sized(ln, slots) and re.search(pattern, ln)]
+
+    scatter = r'op_name="[^"]*/scatter(-max|-add)?"'
+    assert len(lane_ops(guards["hit"], scatter)) == 1
+    assert len(lane_ops(guards["insert"], scatter)) == 5
+    assert not lane_ops(body, scatter)
+    assert not [ln for ln in hlo.splitlines()
+                if "dynamic-update-slice(" in ln and _lane_sized(ln, slots)]
+    copy = r" (copy|copy-start)\("
+    assert not lane_ops(guards["hit"], copy)
+    assert not lane_ops(guards["insert"], copy)
+    assert len(lane_ops(body, copy)) <= GUARDED_BODY_COPIES[sets]
