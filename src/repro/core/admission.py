@@ -88,16 +88,30 @@ def estimate(cfg: TinyLFUConfig, st: TinyLFUState, keys: jnp.ndarray) -> jnp.nda
 @partial(jax.jit, static_argnums=0)
 def record(cfg: TinyLFUConfig, st: TinyLFUState, keys: jnp.ndarray,
            enabled: Optional[jnp.ndarray] = None) -> TinyLFUState:
-    """Record one access per key (batched).
+    """Record one access per key (batched), under the ``tinylfu.record``
+    scope.
 
     First access goes to the doorkeeper; repeat offenders increment the
-    sketch.  Saturating 4-bit adds; duplicate batch keys coalesce into a
-    single increment per step (an accepted approximation — the serial
-    oracle in tests uses B=1 where semantics are exact).  ``enabled``
-    (bool[B], optional) masks whole lanes: a disabled lane touches neither
-    the doorkeeper, the counters, nor the aging tally — used for the tail
-    padding of batched replays and the padding lanes of the sharded router.
+    sketch.  Both read the sketch as the batch began: a key enters the
+    doorkeeper in one batch and counts from the next.  Saturating 4-bit
+    adds, merged per counter *word* by a scatter-max of (word + the lane's
+    one-nibble increment): duplicate batch keys coalesce into a single
+    increment, and so do distinct keys whose counters share one 32-bit word
+    of a row (8 nibbles) in the same batch — the larger of their two
+    written words wins, so one of the two increments is lost.  An accepted
+    approximation; the serial oracle in tests uses B=1 where semantics are
+    exact.  ``enabled`` (bool[B], optional) masks whole lanes: a disabled
+    lane touches neither the doorkeeper, the counters, nor the aging tally
+    — used for the tail padding of batched replays and the padding lanes
+    of the sharded router.  Once ``additions`` reaches ``cfg.sample``
+    every counter is halved and the doorkeeper cleared (``_age``).
     """
+    with jax.named_scope("tinylfu.record"):
+        return _record(cfg, st, keys, enabled)
+
+
+def _record(cfg: TinyLFUConfig, st: TinyLFUState, keys: jnp.ndarray,
+            enabled: Optional[jnp.ndarray]) -> TinyLFUState:
     keys = hashing.sanitize_keys(keys)
     if enabled is None:
         enabled = jnp.ones(keys.shape, jnp.bool_)

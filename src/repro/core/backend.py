@@ -12,6 +12,8 @@ This module gives them one contract (DESIGN.md §3):
     state, ek, ev, slot_sets, slot_ways = backend.put(state, keys, vals)
     state, hit, vals, ek, ev = backend.access(state, keys, vals)
     vkeys, vvalid = backend.peek_victims(state, keys)
+    state, sketch, hit, vals, ek, ev = backend.access_admitted(
+        state, sketch, keys, vals, tinylfu)
     hits, evs, state, sketch = backend.replay(
         state, chunks, enabled, tinylfu=None, sketch=None, hierarchy=None,
         ttls=None, two_phase=False)
@@ -234,6 +236,39 @@ class CacheBackend:
                                      admit_on_miss=admit_on_miss,
                                      enabled=enabled, slot_value=slot_value)
 
+    def access_admitted(self, state, sketch, qkeys, qvals, tinylfu,
+                        enabled=None):
+        """``access`` behind TinyLFU admission (``core/admission.py``): the
+        batch's one admitted step, the phase order every admitted replay
+        runs.
+
+        1. ``admission.record`` counts every enabled lane into ``sketch``
+           (doorkeeper, count-min counters, aging), under the
+           ``tinylfu.record`` scope;
+        2. ``peek_victims`` finds, on the batch-start ``state``, the key each
+           request would evict (way 0 of its set's victim order);
+        3. ``admission.admit`` compares the two estimates on the recorded
+           sketch — 2 and 3 under the ``tinylfu.admit`` scope;
+        4. ``access`` with that admit mask: a missed key that is not
+           admitted changes nothing.
+
+        ``tinylfu`` is the sketch's ``TinyLFUConfig``.  -> (state', sketch',
+        hit[B], vals[B], evicted_keys[B], evicted_valid[B]).
+        """
+        return self._admitted(self.access, state, sketch, qkeys, qvals,
+                              tinylfu, enabled)
+
+    def _admitted(self, access, state, sketch, qkeys, qvals, tinylfu,
+                  enabled):
+        """``access_admitted`` through ``access`` (the fused path or the
+        two-phase oracle)."""
+        sketch = admission.record(tinylfu, sketch, qkeys, enabled=enabled)
+        with jax.named_scope("tinylfu.admit"):
+            vkeys, vvalid = self.peek_victims(state, qkeys)
+            admit = admission.admit(tinylfu, sketch, qkeys, vkeys, vvalid)
+        state, hit, vals, ek, ev = access(state, qkeys, qvals, admit, enabled)
+        return state, sketch, hit, vals, ek, ev
+
     def _replay_hier(self, state, chunks, enabled, hierarchy, ttls=None):
         """Hierarchical replay through the pure-XLA twin
         (core/hierarchy.replay_l1_over_l2).  ``state`` may be a
@@ -270,10 +305,10 @@ class CacheBackend:
         unfused get-then-put oracle, in place of the fused ``access``.
 
         Default implementation, and the one chunked replay loop on one
-        device: a jitted scan over the chunks through ``access``
-        with the TinyLFU record → peek → admit phase order, the TTL stream
-        riding in the scan's ``xs``.  It is the oracle the trace-resident
-        megakernel (PallasBackend) is pinned against.
+        device: a jitted scan over the chunks through ``access`` (through
+        ``access_admitted`` with ``tinylfu``), the TTL stream riding in the
+        scan's ``xs``.  It is the oracle the trace-resident megakernel
+        (PallasBackend) is pinned against.
         """
         hierarchy = _check_replay(tinylfu, hierarchy, ttls, two_phase)
         if hierarchy is not None:
@@ -294,15 +329,14 @@ class CacheBackend:
                 def step(carry, xs):
                     cache, sk = carry
                     keys, en, tt = xs
-                    admit = None
+                    vals = keys.astype(jnp.int32)
                     if _tl is not None:
-                        sk = admission.record(_tl, sk, keys, enabled=en)
-                        vk, vv = self.peek_victims(cache, keys)
-                        admit = admission.admit(_tl, sk, keys, vk, vv)
-                    ttl_kw = {} if tt is None else {"ttls": tt}
-                    cache, hit, _, _, ev = access(
-                        cache, keys, keys.astype(jnp.int32), admit, en,
-                        **ttl_kw)
+                        cache, sk, hit, _, _, ev = self._admitted(
+                            access, cache, sk, keys, vals, _tl, en)
+                    else:
+                        ttl_kw = {} if tt is None else {"ttls": tt}
+                        cache, hit, _, _, ev = access(cache, keys, vals,
+                                                      None, en, **ttl_kw)
                     return (cache, sk), (jnp.sum(hit.astype(jnp.int32)),
                                          jnp.sum(ev.astype(jnp.int32)))
 

@@ -1,8 +1,11 @@
 """TinyLFU admission + trace-replay behaviour (paper §5.2 machinery)."""
+import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import admission, traces
+from repro.core.backend import make_backend
 from repro.core.kway import KWayConfig, fully_associative
 from repro.core.policies import Policy
 from repro.core.simulate import SimConfig, replay, replay_batched
@@ -129,3 +132,60 @@ def test_all_trace_families_generate():
     for fam in traces.FAMILIES:
         t = traces.generate(fam, 2000, seed=1)
         assert t.shape == (2000,) and t.dtype == np.uint32
+
+
+def _parent_inline_replay(be, tl, access, state, chunks, enabled, sketch):
+    """The replay scan's TinyLFU step as it was written inline before
+    ``access_admitted``: record, peek on the batch-start state, admit on
+    the recorded sketch, then ``access`` with that mask."""
+    def step(carry, xs):
+        cache, sk = carry
+        keys, en = xs
+        sk = admission.record(tl, sk, keys, enabled=en)
+        vk, vv = be.peek_victims(cache, keys)
+        admit = admission.admit(tl, sk, keys, vk, vv)
+        cache, hit, _, _, ev = access(cache, keys, keys.astype(jnp.int32),
+                                      admit, en)
+        return (cache, sk), (jnp.sum(hit.astype(jnp.int32)),
+                             jnp.sum(ev.astype(jnp.int32)))
+    (state, sk), (hits, evs) = jax.lax.scan(step, (state, sketch),
+                                            (chunks, enabled))
+    return hits, evs, state, sk
+
+
+@pytest.mark.parametrize("two_phase", [False, True])
+def test_replay_tinylfu_branch_runs_access_admitted(two_phase):
+    """The scan's TinyLFU branch (through ``CacheBackend.access_admitted``)
+    gives, bit for bit, the per-chunk counts, cache and sketch of the
+    inline step it replaced, over chunks that mix hits and misses, fill
+    and overflow sets, carry a padded tail, and age the sketch twice; and
+    ``access_admitted`` called chunk by chunk gives the same."""
+    cfg = KWayConfig(num_sets=64, ways=8, policy=Policy.LRU)
+    tl = admission.TinyLFUConfig(width=512, door_bits=1024, sample=1500)
+    be = make_backend("jnp", cfg)
+    rng = np.random.default_rng(20)
+    chunks = (rng.zipf(1.2, (24, 128)) % 1500).astype(np.uint32)
+    enabled = np.ones(chunks.shape, bool)
+    enabled[-1, 100:] = False
+    access = be.access_two_phase if two_phase else be.access
+    want = jax.jit(lambda *a: _parent_inline_replay(be, tl, access, *a))(
+        be.init(), jnp.asarray(chunks), jnp.asarray(enabled),
+        admission.make_sketch(tl))
+    got = be.replay(be.init(), chunks, enabled, tinylfu=tl,
+                    two_phase=two_phase)
+    for g, w in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    hits, evs = np.asarray(want[0]), np.asarray(want[1])
+    assert (hits[1:] > 0).all() and (evs > 0).sum() > 12   # mixed chunks
+    assert int(want[3].additions) < 24 * 128 - 2 * 1500    # aged twice
+
+    state, sk = be.init(), admission.make_sketch(tl)
+    for i in range(chunks.shape[0]):
+        k = jnp.asarray(chunks[i])
+        state, sk, hit, _, _, ev = be.access_admitted(
+            state, sk, k, k.astype(jnp.int32), tl, jnp.asarray(enabled[i]))
+        assert int(jnp.sum(hit)) == hits[i] and int(jnp.sum(ev)) == evs[i]
+    if not two_phase:
+        for g, w in zip(jax.tree_util.tree_leaves((state, sk)),
+                        jax.tree_util.tree_leaves(want[2:])):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))

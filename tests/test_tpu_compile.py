@@ -19,7 +19,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core import hierarchy as H
-from repro.core.admission import for_capacity
+from repro.core.admission import TinyLFUState, for_capacity
 from repro.core.backend import make_backend
 from repro.core.kway import KWayConfig, KWayState
 from repro.core.policies import Policy
@@ -269,3 +269,111 @@ def test_jnp_replay_guards_lane_scatters(sds, sets):
     assert not lane_ops(guards["hit"], copy)
     assert not lane_ops(guards["insert"], copy)
     assert len(lane_ops(body, copy)) <= GUARDED_BODY_COPIES[sets]
+
+
+def _replay_program(sds, sets, tinylfu, steps=64, b=4096):
+    """``JnpBackend.replay``'s own scan (the program ``jit_fn``) at ``sets``
+    x 8 ways, with or without TinyLFU admission, compiled for a v5e."""
+    ways = 8
+    be = make_backend("jnp", KWayConfig(num_sets=sets, ways=ways))
+    state = KWayState(*_lanes(sds, sets, ways), clock=sds(()))
+    if tinylfu is None:
+        sketch = sds(())
+    else:
+        sketch = TinyLFUState(packed=sds((4, tinylfu.width // 8), jnp.uint32),
+                              door=sds((tinylfu.door_bits // 32,), jnp.uint32),
+                              additions=sds(()))
+    args = (state, sds((steps, b), jnp.uint32), sds((steps, b), jnp.bool_))
+    jax.eval_shape(lambda st, ch, en, sk: be.replay(st, ch, en, tinylfu=tinylfu,
+                                                    sketch=sk),
+                   *args, sketch)                      # builds the scan
+    fn = be._replay_fns[(tinylfu, False, False)]
+    return fn, fn.lower(*args, sketch, None).compile()
+
+
+def _carried_in_s1(hlo: str, sets: int) -> list:
+    """Which of the scan's ``[sets, 8]`` carried lanes lie in ``S(1)``, in
+    carry order."""
+    comps, entry = _computations(hlo)
+    (scan,) = [ln for ln in comps[entry] if " while(" in ln]
+    carry = scan.split(" = ", 1)[1].split(" while(", 1)[0]
+    return ["S(1)" in s for s in re.findall(rf"\w+\[{sets},8\]\{{[^}}]*\}}", carry)]
+
+
+def test_jnp_tinylfu_replay_compiles_with_its_names(sds):
+    """The 64-chunk replay behind TinyLFU admission at 2^17 sets x 8 ways
+    with the sketch ``for_capacity`` sizes (``ycsbc_tinylfu``'s geometry)
+    compiles for a v5e.  The doorkeeper's scatter keeps its
+    ``tinylfu.record`` name; the counters' scatter-max, which the compiler
+    rebuilds as a nameless 1-D scatter, is booked to ``record`` by data
+    flow (``bench/trace_tinylfu.py``); ops under ``tinylfu.admit`` are
+    there.  The aging ``cond`` moves none of the cache's lanes out of
+    ``S(1)``: the scan carries them where the scan without admission
+    does."""
+    from bench import trace_tinylfu
+    sets = 1 << 17
+    _, compiled = _replay_program(sds, sets, for_capacity(sets * 8))
+    hlo = compiled.as_text()
+    names = re.findall(r'op_name="([^"]*)"', hlo)
+    scatters = [ln for ln in hlo.splitlines() if re.search(r" scatter(-max)?\(", ln)]
+    assert any("tinylfu.record/scatter" in ln for ln in scatters)
+    assert any("tinylfu.admit" in n for n in names)
+    assert any("tinylfu.record/cond" in ln for ln in hlo.splitlines()
+               if " conditional(" in ln)
+
+    (module,) = compiled.runtime_executable().hlo_modules()
+    body = module.as_serialized_hlo_module_proto()
+    proto = bytes([1 << 3 | 2]) + _varint(len(body)) + body     # HloProto.hlo_module
+    booked = trace_tinylfu.program_scopes(memoryview(proto))
+    packed = [ln.split(" = ")[0].split()[-1].lstrip("%") for ln in scatters
+              if "u32[524288]" in ln.split(" = ", 1)[1].split(" scatter", 1)[0]]
+    assert packed and all(booked.get(n) == "record" for n in packed), packed
+
+    _, plain = _replay_program(sds, sets, None)
+    assert (_carried_in_s1(hlo, sets) == _carried_in_s1(plain.as_text(), sets)
+            == [True, True, False, True, False])
+
+
+def _varint(n: int) -> bytes:
+    out = bytearray()
+    while True:
+        b, n = n & 0x7F, n >> 7
+        out.append(b | (0x80 if n else 0))
+        if not n:
+            return bytes(out)
+
+
+def _program(hlo: str) -> dict:
+    """A compiled program's computations without metadata: what runs, not
+    where in the source it came from."""
+    comps, _ = _computations(hlo)
+    return {c: [re.sub(r", metadata=\{[^}]*\}", "", ln) for ln in lines]
+            for c, lines in comps.items()}
+
+
+@pytest.mark.parametrize("sets,steps", [(8192, 512), (1 << 17, 64), (1 << 20, 64)])
+def test_jnp_replay_without_admission_is_the_access_scan(sds, sets, steps):
+    """``CacheBackend.replay`` without TinyLFU compiles, for a v5e, to the
+    program of a plain scan of ``access`` over the chunks (the form it had
+    before the admitted step was made public): at the geometries of
+    ``getput``, ``ycsbc_tinylfu`` and ``evict``."""
+    be = make_backend("jnp", KWayConfig(num_sets=sets, ways=8))
+
+    def fn(state, chunks, enabled, sketch, ttls):
+        def step(carry, xs):
+            cache, sk = carry
+            keys, en = xs
+            cache, hit, _, _, ev = be.access(cache, keys, keys.astype(jnp.int32),
+                                             None, en)
+            return (cache, sk), (jnp.sum(hit.astype(jnp.int32)),
+                                 jnp.sum(ev.astype(jnp.int32)))
+        (state, sk), (hits, evs) = jax.lax.scan(step, (state, sketch),
+                                                (chunks, enabled))
+        return hits, evs, state, sk
+
+    state = KWayState(*_lanes(sds, sets, 8), clock=sds(()))
+    args = (state, sds((steps, 4096), jnp.uint32), sds((steps, 4096), jnp.bool_),
+            sds(()), None)
+    want = jax.jit(fn).lower(*args).compile().as_text()
+    _, got = _replay_program(sds, sets, None, steps=steps)
+    assert _program(got.as_text()) == _program(want)
